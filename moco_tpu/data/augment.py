@@ -30,8 +30,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from moco_tpu.utils.compat import optimization_barrier, shard_map
 import numpy as np
 
 # numpy (not jnp): module-level device arrays would initialize the JAX
@@ -319,7 +317,7 @@ def _gaussian_blur(img, key, cfg: AugConfig):
     # grouped `conv_general_dilated` autotunes nondeterministically (12 ms or
     # 180 ms depending on compilation). Shifted-adds behind an
     # optimization_barrier are deterministic ALU/bandwidth work.
-    img_b = optimization_barrier(img)
+    img_b = jax.lax.optimization_barrier(img)
 
     def conv1d(x, axis):
         pad = [(0, 0)] * 3
@@ -603,7 +601,7 @@ def build_two_crops_sharded(cfg, mesh):
         return crop(kq, cfg_q), crop(kk, cfg_k)
 
     sharded = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P()),

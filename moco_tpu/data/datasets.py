@@ -275,11 +275,17 @@ class ImageFolder:
                 self._native = NativeStagingLoader(
                     self.stage_h, self.stage_w, self._native_workers
                 )
-            except (RuntimeError, OSError):
+            except (RuntimeError, OSError) as e:
                 if self._backend == "native":
                     raise
+                from moco_tpu.utils.logging import log_event
+
+                log_event("data", "native staging loader unavailable — "
+                          f"decoding with PIL (several times slower): {e}")
         elif self._backend == "native" and not has_jpeg:
             raise RuntimeError("backend='native' requires JPEG images")
+        # the decode path actually in use (run telemetry records it)
+        self.backend = "native" if self._native is not None else "pil"
 
     def __len__(self):
         return len(self.entries)
@@ -377,6 +383,35 @@ class ImageFolder:
             out_imgs[j] = s[0]
             out_extents[j] = s[1]
         return self.labels[np.asarray(idx)]
+
+
+def write_jpeg_tree(root: str, n_images: int = 256, classes: int = 4,
+                    size: tuple[int, int] = (500, 375), seed: int = 0) -> list[str]:
+    """Write a seeded class-per-subdir tree of ImageNet-shaped synthetic
+    JPEGs (`size` is (width, height); 4:3, quality 85, ~30-60 KB each) and
+    return the paths — the stand-in real-feed input of `chip_smoke.py` and
+    `bench.py`, generated so neither needs a dataset or a network."""
+    from PIL import Image
+
+    rng = np.random.RandomState(seed)
+    paths = []
+    for c in range(classes):
+        d = os.path.join(root, f"class{c}")
+        os.makedirs(d, exist_ok=True)
+        for i in range(n_images // classes):
+            # low-frequency content + noise: realistic JPEG entropy, cheap
+            base = rng.randint(0, 256, (6, 8, 3)).astype(np.uint8)
+            img = np.asarray(
+                Image.fromarray(base).resize(size, Image.BILINEAR), np.uint8
+            )
+            img = np.clip(
+                img.astype(np.int16) + rng.randint(-25, 25, img.shape[:2] + (1,)),
+                0, 255,
+            ).astype(np.uint8)
+            p = os.path.join(d, f"{i}.jpg")
+            Image.fromarray(img).save(p, quality=85)
+            paths.append(p)
+    return paths
 
 
 def build_dataset(

@@ -75,10 +75,12 @@ class _CloseRequested(Exception):
 # jax on CPU may return a zero-copy ALIAS of a numpy array from device_put
 # (device memory is host memory); recycling a pooled canvas that a live
 # jax.Array aliases would corrupt staged batches. Whether a given put
-# aliases depends on the allocation's alignment (measured on jax 0.4.37: a
-# [16,3] int32 aliased while a [16,] int32 did not), so it cannot be probed
+# aliases depends on the allocation's alignment, so it cannot be probed
 # reliably — on CPU backends every pooled buffer is COPIED before the put.
-# Real accelerators always DMA a copy, so the hot path never pays this.
+# Real accelerators DMA a copy, and the canvas is recycled only after
+# block_until_ready on the staged arrays (`_assemble_device`), so the hot
+# path never pays this (checked on the chip: chip_smoke's staged-batch ==
+# host-source phase).
 _HOST_IS_DEVICE: bool | None = None
 
 
@@ -415,12 +417,7 @@ class Prefetcher:
         if n_dev <= 1 or self.batch % n_dev != 0:
             return None
         shard_rows = self.batch // n_dev
-        try:
-            idx_map = self.sharding.addressable_devices_indices_map(
-                (self.batch,)
-            )
-        except Exception:  # conservative: any API surprise → whole-batch put
-            return None
+        idx_map = self.sharding.addressable_devices_indices_map((self.batch,))
         plan = []
         for dev, index in idx_map.items():
             sl = index[0] if isinstance(index, tuple) else index

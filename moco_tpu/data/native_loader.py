@@ -10,13 +10,16 @@ the WHOLE image + edge-replicated padding, with a per-image
 DEVICE (data/augment.py) over the true image area.
 
 The shared library is compiled on first use (g++ + libjpeg, both in the
-image); if the toolchain is unavailable, `ImageFolder` silently falls back
-to the PIL path.
+image) from THIS checkout's source: the file name carries the source's
+hash, because in a copied tree an mtime says nothing about which source a
+binary came from. A failed build raises with the compiler's output;
+`ImageFolder(backend="auto")` then says so and decodes with PIL.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,37 +28,40 @@ import numpy as np
 
 from moco_tpu.utils.logging import log_event
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-_LIB_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libstaging_loader.so"))
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native"))
 _build_lock = threading.Lock()
 
 
-def _ensure_built() -> str | None:
-    """Compile the library if needed; None if the build is impossible."""
+def _ensure_built() -> str:
+    """Path of the library built from the current `staging_loader.cc`,
+    compiling it if this source has not been built here yet."""
+    with open(os.path.join(_NATIVE_DIR, "staging_loader.cc"), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    lib = os.path.join(_NATIVE_DIR, f"libstaging_loader-{digest}.so")
     with _build_lock:
-        src = os.path.join(_NATIVE_DIR, "staging_loader.cc")
-        if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(src):
-            return _LIB_PATH
-        try:
-            subprocess.run(
-                ["make", "-C", os.path.abspath(_NATIVE_DIR), "libstaging_loader.so"],
-                check=True,
-                capture_output=True,
+        if not os.path.exists(lib):
+            # per-pid temp + atomic rename: concurrent first users
+            # (staging-server workers) never load a half-written file
+            tmp = f"{lib}.{os.getpid()}.tmp"
+            proc = subprocess.run(
+                ["make", "-C", _NATIVE_DIR, f"OUT={os.path.basename(tmp)}"],
+                capture_output=True, text=True,
             )
-            return _LIB_PATH
-        except (subprocess.CalledProcessError, FileNotFoundError):
-            return None
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    "native staging loader build failed "
+                    f"(make rc={proc.returncode}):\n{proc.stderr.strip()}")
+            os.replace(tmp, lib)
+    return lib
 
 
 class NativeStagingLoader:
     """Threaded JPEG→staging-canvas batch loader. Raises RuntimeError if the
-    native library cannot be built (callers fall back to PIL)."""
+    native library cannot be built."""
 
     def __init__(self, stage_h: int, stage_w: int, num_threads: int | None = None):
-        path = _ensure_built()
-        if path is None:
-            raise RuntimeError("native staging loader unavailable (build failed)")
-        self._lib = ctypes.CDLL(path)
+        self._lib = ctypes.CDLL(_ensure_built())
         self._lib.sl_create.restype = ctypes.c_void_p
         self._lib.sl_create.argtypes = [ctypes.c_int, ctypes.c_int, ctypes.c_int]
         self._lib.sl_load_batch.restype = ctypes.c_int
@@ -67,11 +73,8 @@ class NativeStagingLoader:
             ctypes.POINTER(ctypes.c_int32),
         ]
         self._lib.sl_destroy.argtypes = [ctypes.c_void_p]
-        try:
-            self._lib.sl_version.restype = ctypes.c_int
-            self.version = int(self._lib.sl_version())
-        except AttributeError:  # pre-v2 .so without the symbol
-            self.version = 1
+        self._lib.sl_version.restype = ctypes.c_int
+        self.version = int(self._lib.sl_version())
         if num_threads is None:
             num_threads = max(os.cpu_count() or 1, 1)
         self.num_threads = num_threads

@@ -1,6 +1,6 @@
 """LocalServerPool: N staging servers on this host (ISSUE 14).
 
-The multi-server deployments tests, bench.py's e2e child and the chaos
+The multi-server deployments tests, bench.py's e2e mode and the chaos
 drills need, without asking anyone to run N `tools/staging_server.py`
 terminals: each pool member is one full `StagingServer` (stdlib
 supervisor + decode-worker subprocess), so everything the drills exercise

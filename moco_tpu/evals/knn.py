@@ -136,6 +136,9 @@ def main(argv=None):
         from moco_tpu.parallel.mesh import force_cpu_devices
 
         force_cpu_devices(args.fake_devices)
+    from moco_tpu.utils.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     run_knn(get_preset(args.preset).replace(**collect_overrides(args, EvalConfig)))
 
 

@@ -314,9 +314,7 @@ def _val_split(config: EvalConfig, train_set=None):
     head against labels from a different generator — the first on-chip
     probe of the gate-passing horizon encoder showed the signature
     (train Acc 99.7%, val Acc 0.39%, BELOW the 6.25% chance) that
-    exposed it; that failing log lives in git history (the committed
-    runs/lincls_tpu_r5.log is the post-fix 100% run — see
-    runs/README.md)."""
+    exposed it."""
     if config.dataset == "imagefolder":
         import os
 
@@ -362,6 +360,9 @@ def main(argv=None):
         from moco_tpu.parallel.mesh import force_cpu_devices
 
         force_cpu_devices(args.fake_devices)
+    from moco_tpu.utils.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     config = get_preset(args.preset).replace(**collect_overrides(args, EvalConfig))
     info(f"config: {config}")
     _, best = train_lincls(config, max_steps=args.max_steps)

@@ -47,7 +47,7 @@ def _use_pallas() -> bool:
     (MOCO_TPU_PALLAS_BN): the r5 A/B that turned the stats kernels off by
     default must not silently disable the separately-validated fused
     family's documented config switch (review, r5). The global
-    MOCO_TPU_DISABLE_PALLAS kill-switch (bench retry) still applies; off
+    MOCO_TPU_DISABLE_PALLAS kill-switch (tools/_perf_ab.py) still applies; off
     TPU the blocks fall back to `_plain_apply`."""
     from moco_tpu.utils.envflags import env_flag
 

@@ -28,8 +28,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from moco_tpu.utils.compat import shape_dtype_struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -76,7 +74,7 @@ def gaussian_blur_batch(
         vma = getattr(getattr(img_padded, "aval", None), "vma", frozenset())
         return pl.pallas_call(
             _blur_kernel,
-            out_shape=shape_dtype_struct((3, h, w), images.dtype, vma=vma),
+            out_shape=jax.ShapeDtypeStruct((3, h, w), images.dtype, vma=vma),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),

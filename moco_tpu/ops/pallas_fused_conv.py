@@ -27,8 +27,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from moco_tpu.utils.compat import shape_dtype_struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -108,7 +106,7 @@ def bn_relu_matmul_dw(
             pl.BlockSpec((bm, bn), lambda kk, j, i: (i, j)),
         ],
         out_specs=pl.BlockSpec((bk, bn), lambda kk, j, i: (kk, j)),
-        out_shape=shape_dtype_struct((k, n), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         interpret=interpret,
     )(x, a.reshape(1, k).astype(jnp.float32),
@@ -144,7 +142,7 @@ def bn_relu_matmul(
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=shape_dtype_struct((m, n), out_dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, a.reshape(1, k).astype(jnp.float32),

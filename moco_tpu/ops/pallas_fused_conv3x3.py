@@ -38,8 +38,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from moco_tpu.utils.compat import shape_dtype_struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -246,7 +244,7 @@ def bn_relu_conv3x3(
             pl.BlockSpec((9, k, n), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bh, wd, n), idx_cur),
-        out_shape=shape_dtype_struct((bsz * h, wd, n), out_dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((bsz * h, wd, n), out_dtype, vma=vma),
         interpret=interpret,
     )(xr, xr, xr, a.reshape(1, 1, k).astype(jnp.float32),
       b.reshape(1, 1, k).astype(jnp.float32), w9)
@@ -359,7 +357,7 @@ def bn_relu_conv3x3_s2(
             pl.BlockSpec((9, k, n), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bho, wd // 2, n), lambda i: (i, 0, 0)),
-        out_shape=shape_dtype_struct((bsz * ho, wd // 2, n), out_dtype,
+        out_shape=jax.ShapeDtypeStruct((bsz * ho, wd // 2, n), out_dtype,
                                        vma=vma),
         interpret=interpret,
     )(xr, xr, a.reshape(1, 1, k).astype(jnp.float32),
@@ -421,7 +419,7 @@ def conv3x3_dw(
             pl.BlockSpec((bh, wd, bn), lambda j, i: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((9, k, bn), lambda j, i: (0, 0, j)),
-        out_shape=shape_dtype_struct((9, k, n), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((9, k, n), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((9, k, bn), jnp.float32)],
         interpret=interpret,
     )(xr, xr, xr, a.reshape(1, 1, k).astype(jnp.float32),

@@ -27,8 +27,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-
-from moco_tpu.utils.compat import shape_dtype_struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -77,7 +75,7 @@ def _tile_rows(n: int, c: int, kib: int | None = None) -> int:
     product, plus the cast of x); at the old 2 MB bf16 tile (t=16384,
     c=64) that stack plus the double-buffered input windows totalled
     19.87 MB against the 16 MB scoped-VMEM limit and the R50 step failed
-    to compile on the v5e (runs/tpu_validate_tpu.log, 2026-07-31). The
+    to compile on the v5e (builder run, 2026-07-31). The
     forward microbench only ever passed because its row count happened to
     be indivisible by 16384. 1 MB tiles put the worst case ~10 MB. The
     floor is 8 (the f32 sublane count), NOT a round 512: a 512-row floor
@@ -127,8 +125,8 @@ def channel_sums(x: jax.Array, interpret: bool = False):
             pl.BlockSpec((1, c), lambda i: (0, 0)),
         ],
         out_shape=[
-            shape_dtype_struct((1, c), jnp.float32, vma=vma),
-            shape_dtype_struct((1, c), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(xr)
@@ -166,8 +164,8 @@ def channel_grad_sums(
             pl.BlockSpec((1, c), lambda i: (0, 0)),
         ],
         out_shape=[
-            shape_dtype_struct((1, c), jnp.float32, vma=vma),
-            shape_dtype_struct((1, c), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((1, c), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(dyr, xr, mean.reshape(1, c).astype(jnp.float32),

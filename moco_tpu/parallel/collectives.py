@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from moco_tpu.utils.compat import axis_size, optimization_barrier
 
 # Every collective here accepts either one axis name or a TUPLE of names
 # (ISSUE 15: the 2-D data×fsdp mesh) — jax's collectives treat a tuple as
@@ -50,9 +49,9 @@ def batch_axis_size(axis_name) -> jax.Array | int:
     if isinstance(axis_name, (tuple, list)):
         n = 1
         for ax in axis_name:
-            n = n * axis_size(ax)
+            n = n * lax.axis_size(ax)
         return n
-    return axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def batch_axis_index(axis_name) -> jax.Array:
@@ -62,9 +61,29 @@ def batch_axis_index(axis_name) -> jax.Array:
     if isinstance(axis_name, (tuple, list)):
         idx = jnp.int32(0)
         for ax in axis_name:
-            idx = idx * axis_size(ax) + lax.axis_index(ax)
+            idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
         return idx
     return lax.axis_index(axis_name)
+
+
+def device_local(tree, axis_name):
+    """Retype replicated leaves as device-varying over `axis_name` (a
+    no-op at run time). Differentiating w.r.t. a REPLICATED input inside
+    shard_map makes autodiff psum the cotangent over the axis by itself
+    (the transpose of the implicit replicated→varying cast), so the
+    "local" grads would arrive already summed and GradSync's pmean over
+    them would be the identity: N× the DDP gradient on N devices
+    (measured on jax 0.9.0, PR 21). Differentiating w.r.t. this view
+    yields truly per-device grads — the reduce in `parallel/gradsync.py`
+    is then the ONLY one. Leaves already varying over an axis (fsdp
+    gathers) keep it."""
+    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+
+    def cast(x):
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree)
 
 
 def all_gather_batch(x: jax.Array, axis_name, chunks: int = 1) -> jax.Array:
@@ -91,7 +110,7 @@ def all_gather_batch(x: jax.Array, axis_name, chunks: int = 1) -> jax.Array:
     for c in range(chunks):
         part = lax.slice_in_dim(x, c * rows, (c + 1) * rows, axis=0)
         if prev is not None:
-            part, prev = optimization_barrier((part, prev))
+            part, prev = lax.optimization_barrier((part, prev))
         g = lax.all_gather(part, axis_name, axis=0)  # [n, rows, ...]
         gathered.append(g)
         prev = g
@@ -152,13 +171,13 @@ def chained_psum(flats: list[jax.Array], axis_name: str) -> list[jax.Array]:
     barrier ties bucket i+1's INPUT to bucket i's OUTPUT, so the reduces
     issue as a deterministic pipeline: bucket i can be on the wire while
     the backward that produces bucket i+1 is still running (DeAR,
-    PAPERS.md). On builds whose barrier is identity (utils/compat.py) the
-    numerics are unchanged — only the scheduling hint is lost."""
+    PAPERS.md). The barrier is a scheduling hint: numerics are those of
+    the plain loop."""
     out = []
     prev = None
     for flat in flats:
         if prev is not None:
-            flat, prev = optimization_barrier((flat, prev))
+            flat, prev = lax.optimization_barrier((flat, prev))
         summed = lax.psum(flat, axis_name)
         out.append(summed)
         prev = summed

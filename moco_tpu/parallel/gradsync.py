@@ -75,11 +75,11 @@ from jax import lax
 
 from moco_tpu.parallel.collectives import (
     chained_psum,
+    device_local,
     multihop_quantized_psum_mean,
     quantized_psum_mean,
 )
 from moco_tpu.parallel.mesh import DATA_AXIS
-from moco_tpu.utils.compat import optimization_barrier
 
 GRAD_SYNC_MODES = ("fused", "bucketed", "quantized", "demo")
 STATE_KEY = "acc"  # the one gradsync accumulator leaf-tree in TrainState
@@ -459,7 +459,7 @@ class GradSync:
             if prev is not None:
                 # sequence the buckets like the bucketed mode: a
                 # deterministic issue order the scheduler can pipeline
-                segs, prev = optimization_barrier((segs, prev))
+                segs, prev = lax.optimization_barrier((segs, prev))
             if self.multihop:
                 # DynamiQ topology-aware path (2-D mesh, both axes > 1):
                 # exact on the fast inner axis, compressed on the slow
@@ -504,9 +504,13 @@ class GradSync:
             return vals, idxs, residue
 
         def skip_branch(ms):
+            # device-local like the sync branch's outputs: cond demands
+            # equal types, varying axes included
             return (
-                [jnp.zeros((p.k,), jnp.float32) for p in fplans],
-                [jnp.zeros((p.k,), jnp.int32) for p in fplans],
+                device_local([jnp.zeros((p.k,), jnp.float32) for p in fplans],
+                             axis_name),
+                device_local([jnp.zeros((p.k,), jnp.int32) for p in fplans],
+                             axis_name),
                 ms,
             )
 
@@ -552,8 +556,6 @@ class GradSync:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from moco_tpu.utils.compat import shard_map
-
         self.plan(params)
 
         def region(grads, gs_state, step):
@@ -563,7 +565,7 @@ class GradSync:
             return payload, new_state
 
         state_spec = P(self.reduce_axis) if self.needs_state else P()
-        fn = shard_map(
+        fn = jax.shard_map(
             region, mesh=mesh,
             in_specs=(P(), state_spec, P()),
             out_specs=(self.payload_specs(P), state_spec),

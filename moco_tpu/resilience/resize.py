@@ -20,9 +20,7 @@ that turns those pieces into ONE automatic loop:
     the child, and on the child's 49 rewrites the relaunch argv — the new
     device count (argparse last-wins append), an optional
     `--grad-sync-cadence` override when the new mesh is flagged
-    slow-linked, and a FRESH per-resize compile cache dir so the resized
-    relaunch never touches a cache a killed predecessor may have poisoned
-    (the PR 4 finding). `--resume auto` + the dialect shim then restore
+    slow-linked. `--resume auto` + the dialect shim then restore
     the state onto the new mesh with fresh-zero gradsync accumulators.
   - `read_recorded_devices` / `argv_device_count`: the relaunch-preflight
     membership check — every checkpoint's position sidecar records the
@@ -351,25 +349,19 @@ class ResizeController:
         else a last-chance file claim (the chaos drill's child writes the
         file and exits faster than the poll cadence), else an empty
         request (resize to whatever the hardware shows);
-      - `apply(argv, env)` before the relaunch — mutates argv/env in
-        place: device-count append (argparse last-wins), the cadence
-        override, and a fresh per-resize compile cache dir.
+      - `apply(req, argv)` before the relaunch — mutates argv in
+        place: device-count append (argparse last-wins) and the cadence
+        override.
     """
 
     def __init__(self, telemetry_dir: str, *,
                  device_flag: str = "",
                  slow_cadence: int = 0,
-                 poll_gate_secs: float = 0.5,
-                 rotate_cache: bool = True):
+                 poll_gate_secs: float = 0.5):
         self.telemetry_dir = telemetry_dir
         self.device_flag = device_flag  # "" = pick from the argv itself
         self.slow_cadence = int(slow_cadence)
         self.poll_gate_secs = float(poll_gate_secs)
-        # False when the operator pinned the cache themselves
-        # (--shared-compile-cache, or an explicit MOCO_TPU_CACHE_DIR in
-        # the environment before the supervisor derived its own): a
-        # resize must not silently override that choice
-        self.rotate_cache = bool(rotate_cache)
         self.armed: ResizeRequest | None = None
         self.armed_at_wall: float = 0.0
         self.resizes_applied = 0
@@ -437,17 +429,13 @@ class ResizeController:
             return self.slow_cadence
         return None
 
-    def apply(self, req: ResizeRequest, argv: list[str],
-              env: dict) -> dict:
-        """Rewrite the relaunch argv/env IN PLACE for the resize; returns
-        a summary dict for the `resize_relaunch` incident record.
+    def apply(self, req: ResizeRequest, argv: list[str]) -> dict:
+        """Rewrite the relaunch argv IN PLACE for the resize; returns a
+        summary dict for the `resize_relaunch` incident record.
 
         Appends (argparse last-wins) rather than edits: the original
         operator argv stays visible in the launch record, and repeated
-        resizes stack correctly. The compile cache rotates to a fresh
-        per-resize dir unless the operator disabled caching outright —
-        the resized shapes compile fresh either way, and a cache a
-        SIGKILL-grade predecessor poisoned must never brick the relaunch."""
+        resizes stack correctly."""
         old_devices = argv_device_count(argv)
         summary: dict = {"source": req.source, "devices_from": old_devices}
         if req.devices is not None:
@@ -468,12 +456,6 @@ class ResizeController:
             # request flips it for the relaunch
             argv += ["--sharding", req.sharding]
             summary["sharding"] = req.sharding
-        if self.rotate_cache and not env.get("MOCO_TPU_NO_CACHE"):
-            from moco_tpu.utils.cache import per_run_cache_dir  # stdlib-only
-
-            env["MOCO_TPU_CACHE_DIR"] = per_run_cache_dir(
-                tag=f"resize{self.resizes_applied}")
-            summary["cache_dir"] = env["MOCO_TPU_CACHE_DIR"]
         try:
             # honored payload applied: a stale copy must not leak into a
             # later payload-less resize's take() fallback

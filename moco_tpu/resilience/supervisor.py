@@ -431,7 +431,6 @@ class Supervisor:
         time_fn=time.monotonic,
         resize_device_flag: str = "",
         resize_slow_cadence: int = 0,
-        resize_rotate_cache: bool = True,
     ):
         self.child_argv = list(child_argv)
         self.telemetry_dir = telemetry_dir
@@ -473,11 +472,7 @@ class Supervisor:
         self.resize = ResizeController(
             telemetry_dir, device_flag=resize_device_flag,
             slow_cadence=resize_slow_cadence,
-            rotate_cache=resize_rotate_cache,
         )
-        # per-launch extra env (the resize rewrite's fresh compile-cache
-        # dir lands here; _launch overlays it on the base env)
-        self._launch_env: dict = {}
         self._last_mesh_change: tuple | None = None
         self._resize_signaled = False
         self._resize_request_emitted = False
@@ -566,7 +561,6 @@ class Supervisor:
         # span (the per-launch `child` span run() holds open) — one
         # trace_id from supervisor through driver to staging worker
         env = dict(os.environ if self.env is None else self.env)
-        env.update(self._launch_env)  # resize: fresh per-resize cache dir
         env.update(self.tracer.child_env())
         log_file = open(self.child_log_path, "ab")
         try:
@@ -781,15 +775,8 @@ class Supervisor:
                 grad_sync_cadence=req.grad_sync_cadence, slow=req.slow,
             )
         self._resize_request_emitted = False
-        # the rewrite sees the EFFECTIVE child env (base + overlay): a
-        # MOCO_TPU_NO_CACHE in the base env must suppress the cache
-        # rotation, not be shadowed by the empty overlay
-        env = dict(os.environ if self.env is None else self.env)
-        env.update(self._launch_env)
         self._resize_fallback = len(self.child_argv)
-        summary = self.resize.apply(req, self.child_argv, env)
-        if "cache_dir" in summary:
-            self._launch_env["MOCO_TPU_CACHE_DIR"] = summary["cache_dir"]
+        summary = self.resize.apply(req, self.child_argv)
         summary["step"] = step
         self.tracer.record_span(
             "resize", t_armed, max(time.time() - t_armed, 0.0),

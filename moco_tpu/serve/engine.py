@@ -97,14 +97,10 @@ class EmbeddingEngine:
         return self.feat_dim
 
     def compiled_programs(self) -> int | None:
-        """How many distinct programs the jit cache holds (None when this
-        jax build doesn't expose the introspection). After `warmup()` this
-        must STAY at `len(buckets)` under any load — the no-recompile
-        guarantee the tests pin."""
-        try:
-            return int(self._jitted._cache_size())
-        except (AttributeError, TypeError):
-            return None
+        """How many distinct programs the jit cache holds. After
+        `warmup()` this must STAY at `len(buckets)` under any load — the
+        no-recompile guarantee the tests pin."""
+        return int(self._jitted._cache_size())
 
     # -- the hot path --------------------------------------------------------
     def embed(self, images_u8: np.ndarray) -> np.ndarray:

@@ -146,7 +146,14 @@ def _gated(step: jax.Array, stride: int, compute) -> dict[str, jax.Array]:
     shapes = jax.eval_shape(compute)
 
     def zeros():
-        return {k: jnp.zeros(v.shape, v.dtype) for k, v in shapes.items()}
+        # typed like the real branch: inside the shard_map region its
+        # outputs vary over the mesh axes, and cond demands equal types
+        return {
+            k: lax.pcast(jnp.zeros(v.shape, v.dtype), tuple(v.vma),
+                         to="varying") if v.vma
+            else jnp.zeros(v.shape, v.dtype)
+            for k, v in shapes.items()
+        }
 
     return lax.cond(step % stride == 0, compute, zeros)
 

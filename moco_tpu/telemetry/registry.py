@@ -148,7 +148,7 @@ def _json_safe(value):
 def percentiles_ms(values, qs=(50, 95, 99)) -> dict:
     """{"p50": ..., ...} of `values` (seconds) in milliseconds — the
     free-function form of `Histogram.percentiles_ms` for callers holding
-    a plain list (bench.py's BENCH_*.json folds)."""
+    a plain list (bench.py's rows)."""
     h = Histogram("tmp")
     for v in values:
         h.observe(float(v))

@@ -94,9 +94,7 @@ class RunTelemetry:
         # and CachedDataset of the run by the driver; snapshots ride the
         # step records at the device-sampling stride
         self.input_stats = InputPipelineStats()
-        self.mfu = MFUEstimator.for_config(
-            config, n_chips, getattr(device, "device_kind", "")
-        )
+        self.mfu = MFUEstimator.for_config(config, n_chips, device.device_kind)
         self.devices = DeviceMonitor(device)
         self.pod = PodAggregator(self.registry, n_procs, process_index)
         self.n_chips = n_chips
@@ -119,7 +117,8 @@ class RunTelemetry:
             n_chips=n_chips,
             n_procs=n_procs,
             sharding=getattr(config, "sharding", "dp"),
-            device_kind=getattr(device, "device_kind", ""),
+            platform=device.platform,
+            device_kind=device.device_kind,
             peak_flops_per_chip=self.mfu.peak_flops_per_chip,
             flops_per_step=self.mfu.flops_per_step,
             flops_per_image=self.mfu.flops_per_step / max(config.batch_size, 1),

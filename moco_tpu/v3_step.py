@@ -30,11 +30,10 @@ from moco_tpu.config import PretrainConfig
 from moco_tpu.models.heads import V3Predictor, V3Projector
 from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import l2_normalize, v3_contrastive_loss
-from moco_tpu.parallel.collectives import all_gather_batch
+from moco_tpu.parallel.collectives import all_gather_batch, device_local
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.telemetry import health
 from moco_tpu.train_state import TrainState
-from moco_tpu.utils.compat import shard_map
 
 PREDICTOR_KEY = "predictor"
 
@@ -174,11 +173,12 @@ def build_v3_grad_probe(config: PretrainConfig, model: V3Model, mesh):
             loss, _aux = query_loss(pq, stats_q, x1, x2, k1, k2)
             return loss
 
-        grads = jax.grad(loss_of, argnums=(0, 1))(params_q, params_k)
+        grads = jax.grad(loss_of, argnums=(0, 1))(
+            *device_local((params_q, params_k), DATA_AXIS))
         reduced, _, _probe = gradsync.region_reduce(grads, {}, jnp.int32(0))
         return reduced
 
-    return shard_map(
+    return jax.shard_map(
         probe,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(DATA_AXIS), P(DATA_AXIS)),
@@ -241,9 +241,11 @@ def build_v3_train_step(
         def loss_fn(pq):
             return query_loss(pq, stats_q, x1, x2, k1, k2)
 
+        # w.r.t. the device-local view: the grads come out per-device and
+        # gradsync's reduce below is the only one (collectives.device_local)
         (loss, (new_stats_q, q1)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
-        )(params_q)
+        )(device_local(params_q, batch_axis))
         payload, gs_new, gs_probe = gradsync.region_reduce(grads, gs_state, step)
         if plan is not None and gradsync.mode != "demo":
             # reduce-scatter: the reduced full grads leave the region as
@@ -285,7 +287,7 @@ def build_v3_train_step(
         in_specs = (q_specs, k_specs, P(), P(), batch_spec, batch_spec,
                     batch_spec, P())
         out_specs = (payload_spec, batch_spec, P(), P(), P(), P())
-    region = shard_map(
+    region = jax.shard_map(
         spmd_region,
         mesh=mesh,
         in_specs=in_specs,

@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from moco_tpu.parallel import DATA_AXIS, batch_shuffle, batch_unshuffle
 from moco_tpu.parallel.collectives import all_gather_batch, ring_shuffle
-from moco_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):

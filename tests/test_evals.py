@@ -144,8 +144,8 @@ def test_val_split_preserves_synthetic_texture_kind():
     """The synthetic val split must be the SAME dataset kind as training:
     a synthetic_texture probe validated on SyntheticDataset images scores
     the head against labels from a different generator (below-chance val
-    with near-perfect train — the on-chip r5 signature,
-    runs/lincls_tpu_r5.log). Class tiles are fixed across seeds, so a
+    with near-perfect train — the r5 signature). Class tiles are fixed
+    across seeds, so a
     held-out texture instance shares the train classes."""
     import numpy as np
 

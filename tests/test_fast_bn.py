@@ -10,7 +10,7 @@ import pytest
 
 from moco_tpu.models.fast_bn import FastBatchNorm
 from moco_tpu.ops.pallas_stats import channel_grad_sums, channel_sums
-from moco_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _pair(dtype):
@@ -239,21 +239,21 @@ def test_pallas_gates_are_decoupled(monkeypatch):
 
 
 def test_custom_vjp_gate(monkeypatch):
-    """_use_custom_vjp: ON for TPU (measured win, closed-form dx), OFF
-    elsewhere (CPU goldens pin plain autodiff), MOCO_TPU_BN_VJP forces
-    either way and "0" means off."""
+    """_use_custom_vjp never asks which backend it is on: OFF unless
+    MOCO_TPU_BN_VJP opts in ("0" means off), so the backward the CPU
+    tests pin is the backward the chip runs."""
     import unittest.mock as mock
 
     import moco_tpu.models.fast_bn as fbn
 
     monkeypatch.delenv("MOCO_TPU_BN_VJP", raising=False)
-    assert not fbn._use_custom_vjp()  # cpu backend here
+    assert not fbn._use_custom_vjp()
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        assert fbn._use_custom_vjp()
+        assert not fbn._use_custom_vjp()  # same default on the chip
         monkeypatch.setenv("MOCO_TPU_BN_VJP", "0")
         assert not fbn._use_custom_vjp()
     monkeypatch.setenv("MOCO_TPU_BN_VJP", "1")
-    assert fbn._use_custom_vjp()      # forced on even off-TPU
+    assert fbn._use_custom_vjp()
 
 
 def test_env_flag_zero_means_off_everywhere(monkeypatch):

@@ -273,7 +273,7 @@ def test_multihop_reduce_means_match_single_hop(mesh8):
         multihop_quantized_psum_mean,
         quantized_psum_mean,
     )
-    from moco_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh2d = create_mesh_2d(4, devices=list(mesh8.devices.flat))
     x = jax.random.normal(jax.random.key(0), (8, 64))
@@ -526,7 +526,6 @@ def test_supervised_resize_drill_4_to_2_with_fsdp(tmp_path):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["MOCO_TPU_NO_CACHE"] = "1"
-    env.pop("MOCO_TPU_CACHE_DIR", None)
     env.pop("MOCO_TPU_CHAOS", None)
     env.pop("MOCO_TPU_CHAOS_STATE", None)
 

@@ -11,7 +11,6 @@ Three layers of proof, all CPU-runnable:
 
 import flax.linen as nn
 import jax
-import jax.export  # noqa: F401  (binds the lazy submodule on 0.4.x)
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -189,8 +188,8 @@ def test_kernel_lowers_for_tpu_at_r50_shapes():
     """Cross-platform export compiles the Pallas kernel to Mosaic IR (the
     stage where block/tile errors surface) for every R50 bottleneck-tail
     shape at per-chip batch 128 — hardware-free assurance that the TPU path
-    will build. (The bench orchestrator's retry still covers the residual
-    Mosaic→binary stage.)"""
+    will build (the Mosaic→binary stage only a chip run covers:
+    `chip_smoke.py`)."""
     shapes = [
         (128 * 56 * 56, 64, 256),
         (128 * 28 * 28, 128, 512),
@@ -208,20 +207,23 @@ def test_kernel_lowers_for_tpu_at_r50_shapes():
         assert "tpu_custom_call" in mod or "mosaic" in mod.lower(), (m, k, n)
 
 
-@pytest.mark.slow
 def test_full_benchmark_step_lowers_for_tpu():
-    """The ENTIRE benchmark program — uint8 staging input → two-crop bf16
-    augmentation (Pallas blur) → both R50 forwards (Pallas BN stats, fused
-    bn→relu→conv3 tails) → backward → SGD → donated queue update — exports
-    for the TPU platform from CPU. Every Pallas kernel reaches Mosaic IR
-    (33 custom calls), so the driver's benchmark chip meets a program that
-    is known to lower."""
+    """The SHIPPING-DEFAULT benchmark program — `imagenet-moco-v2` at
+    ResNet-50 / 224² / K=65536 / bf16 / batch 128 on a 1-device mesh: uint8
+    ImageFolder staging canvas → two-crop bf16 augmentation (Pallas blur)
+    → both R50 forwards → backward → SGD → donated queue update — exports
+    for the TPU platform from CPU. This is the program the first command
+    sent to a chip runs; a tracing or typing break in it (the BN custom-VJP
+    vma mismatch of PR 21) fails here, in tier-1, not on the chip. The one
+    Mosaic kernel on the default path must be there, not interpreted."""
+    import re
     import unittest.mock as mock
+    from collections import Counter
 
-    import moco_tpu.models.fast_bn as fbn
-    import moco_tpu.models.fused_block as fb
     from moco_tpu.config import get_preset
-    from moco_tpu.data.augment import build_two_crops_sharded, v2_aug_config, with_dtype
+    from moco_tpu.data.augment import (
+        aug_config_for, build_two_crops_sharded, with_dtype,
+    )
     from moco_tpu.parallel.mesh import create_mesh
     from moco_tpu.train_state import create_train_state
     from moco_tpu.train_step import (
@@ -229,45 +231,31 @@ def test_full_benchmark_step_lowers_for_tpu():
     )
 
     B = 128
-    # fused ON explicitly: the census pins the CANDIDATE fused program's
-    # lowering (the shipping default is OFF until _fused_validate proves it
-    # on a chip — config.py::fused_bn_conv)
-    config = get_preset("imagenet-moco-v2").replace(
-        batch_size=B, fused_bn_conv=True)
+    config = get_preset("imagenet-moco-v2").replace(batch_size=B)
+    assert not config.fused_bn_conv  # the default program, not a candidate
     mesh = create_mesh(1)
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
-         mock.patch.object(fbn, "_use_pallas", lambda: True), \
-         mock.patch.object(fb, "_use_pallas", lambda: True):
+    # the blur gate and its interpret flag key on the backend; everything
+    # else in the step is the same traced program on CPU and TPU
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         model = build_encoder(config)
         tx, sched = build_optimizer(config, 1000)
         state = jax.eval_shape(lambda: create_train_state(
             jax.random.key(0), model, tx, (B, 224, 224, 3),
             config.num_negatives, config.embed_dim))
         step_fn = build_train_step(config, model, tx, mesh, 1000, sched)
-        two = build_two_crops_sharded(with_dtype(v2_aug_config(224), "bfloat16"), mesh)
+        two = build_two_crops_sharded(
+            with_dtype(aug_config_for(config), config.compute_dtype), mesh)
         fused = build_fused_step(step_fn, two, jax.random.key(1))
-        imgs = jax.ShapeDtypeStruct((B, 252, 252, 3), jnp.uint8)
-        ext = jax.ShapeDtypeStruct((B, 3), jnp.int32)
         exp = jax.export.export(fused, platforms=["tpu"])(
-            state, imgs, ext, jax.ShapeDtypeStruct((), jnp.int32)
+            state,
+            jax.ShapeDtypeStruct((B, 512, 1024, 3), jnp.uint8),
+            jax.ShapeDtypeStruct((B, 3), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
         )
-        # per-kernel-name census (post-CSE unique call sites): a drop in any
-        # row means a kernel gate silently fell back to jnp and a perf lever
-        # quietly disappeared from the benchmark
-        import re
-        from collections import Counter
-
-        mod = exp.mlir_module()
-        names = Counter(re.findall(r'kernel_name = "([^"]+)"', mod))
-        assert names["_blur_kernel"] >= 1, names          # Pallas blur
-        assert names["_sums_kernel"] >= 12, names         # BN fwd stats
-        assert names["_grad_sums_kernel"] >= 12, names    # BN bwd reductions
-        assert names["_kernel"] >= 4, names               # fused conv3 tails
-        assert names["_conv3x3_kernel"] >= 4, names       # fused conv2 mids
-        assert names["_conv3x3s2_kernel"] >= 3, names     # stride-2 conv2s
-        assert names["_dw_kernel"] >= 4, names            # fused-tail dW bwd
-        assert names["_dw3x3_kernel"] >= 4, names         # fused-mid dW bwd
-        assert mod.count("tpu_custom_call") >= 44
+    mod = exp.mlir_module()
+    names = Counter(re.findall(r'kernel_name = "([^"]+)"', mod))
+    assert names == {"_blur_kernel": 1}, names
+    assert mod.count("tpu_custom_call") == 1
 
 
 def test_dw_kernel_matches_reference_interpret():

@@ -11,7 +11,6 @@ which exercise BOTH fusions on stride-1 blocks.
 """
 
 import jax
-import jax.export  # noqa: F401  (binds the lazy submodule on 0.4.x)
 import jax.numpy as jnp
 import numpy as np
 import pytest

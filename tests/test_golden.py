@@ -50,7 +50,13 @@ def test_golden_losses_8dev(config, mesh8):
     # pinned 2026-07-29 (jax 0.9.0, CPU): update deliberately, never casually
     # re-pinned same day: stride-2 3x3 convs moved from SAME (0,1) padding to
     # torchvision's symmetric (1,1) — the torch-consumer parity fix
-    golden = [0.016187, 2.8706696, 3.7958486]
+    # re-pinned PR 21 (jax 0.9.0, CPU): the region differentiated w.r.t.
+    # REPLICATED params, so autodiff psum'd the grads itself and gradsync's
+    # pmean was the identity — every update was 8x the DDP mean (old pin
+    # [0.016187, 2.8706696, 3.7958486]; step 0 is untouched, it precedes
+    # any update). With collectives.device_local the trajectory now tracks
+    # the 1-device one below, as per-device BN alone predicts
+    golden = [0.016187, 2.8786652, 3.4944372]
     np.testing.assert_allclose(losses, golden, rtol=2e-4, err_msg=str(losses))
     assert int(state.queue_ptr) == (3 * GLOBAL_B) % K
 

@@ -30,7 +30,7 @@ from moco_tpu.parallel.gradsync import GradSync, leaf_wire_dtype
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.train_state import create_train_state
 from moco_tpu.train_step import build_encoder, build_optimizer, build_train_step
-from moco_tpu.utils.compat import shard_map
+from jax import shard_map
 
 B, IMG, DIM, K = 16, 16, 16, 64
 

@@ -20,7 +20,7 @@ TOOL = os.path.join(REPO, "tools", "_horizon_run.py")
 
 
 def _run_tool(ckpt_dir, extra=()):
-    env = dict(os.environ, MOCO_TPU_FORCE_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", MOCO_TPU_NO_CACHE="1")
     return subprocess.run(
         [sys.executable, TOOL, "--steps", "4", "--batch", "16",
          "--samples", "16", "--ckpt-dir", ckpt_dir, *extra],
@@ -38,7 +38,7 @@ def _fake_ckpt(tmp_path, run_args=None):
 
 # the tool's own fingerprint for --steps 4 --batch 16 --samples 16:
 # samples=16, steps_per_epoch=1, epochs=4, total=4 (cpu: the subprocess
-# runs under MOCO_TPU_FORCE_CPU=1)
+# runs under JAX_PLATFORMS=cpu)
 ARGS_4_16 = {"steps": 4, "batch": 16, "samples": 16,
              "arch": "resnet18", "image_size": 32, "lr": 0.03,
              "momentum_ema": 0.99, "backend": "cpu",

@@ -13,7 +13,7 @@ from moco_tpu.ops.losses import (
     v3_contrastive_loss,
 )
 from moco_tpu.parallel import DATA_AXIS
-from moco_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _rand_unit(key, shape):

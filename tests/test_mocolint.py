@@ -122,7 +122,7 @@ def build_step(cfg, arrs):
 
 def test_r8_sees_through_shard_map_and_nesting(tmp_path):
     body = """\
-from moco_tpu.utils.compat import shard_map
+from jax import shard_map
 
 def build(mesh):
     def region(x):

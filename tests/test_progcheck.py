@@ -32,7 +32,7 @@ from jax import lax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from moco_tpu.parallel.mesh import DATA_AXIS  # noqa: E402
-from moco_tpu.utils.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from tools.progcheck.engine import Engine  # noqa: E402
 from tools.progcheck.inventory import (  # noqa: E402
     golden_json,
@@ -281,7 +281,7 @@ def test_p6_flags_debug_print_in_step():
 
     closed = jax.make_jaxpr(step)(jnp.zeros((4,)))
     findings = _run(_record("fix/callback", closed), "P6")
-    assert findings and "debug_callback" in findings[0].message
+    assert findings and "debug_print" in findings[0].message
 
 
 # ---------------------------------------------------------------------------

@@ -11,9 +11,10 @@ Layers:
     restores onto a 2-device mesh with fresh-zero [2, ...] accumulators —
     the restore every elastic relaunch performs;
   - stub-child e2e: the REAL Supervisor loop resizing stub children
-    (request file consumed, SIGUSR2 delivered, argv rewritten, fresh
-    compile-cache dir, mesh_change preflight incident, `resize` span
-    under the child span, report fold) in a couple of seconds;
+    (request file consumed, SIGUSR2 delivered, argv rewritten, one
+    compile cache across the relaunch, mesh_change preflight incident,
+    `resize` span under the child span, report fold) in a couple of
+    seconds;
   - the slow soak: a supervised real-CPU 1→2→1 device drill with zero
     manual steps, loss-curve continuity pinned against an uninterrupted
     run at the gradsync dialect-shim tolerance.
@@ -35,7 +36,6 @@ from moco_tpu.resilience.exitcodes import EXIT_RESIZE
 from moco_tpu.resilience.resize import (
     ResizeController,
     ResizeListener,
-    ResizeRequest,
     argv_device_count,
     consume_resize_request,
     parse_resize_request,
@@ -92,8 +92,7 @@ def test_resize_apply_carries_sharding_mode(tmp_path):
     assert req is not None and req.sharding == "fsdp"
     req = ctl.take()  # the child exited EXIT_RESIZE; claim + disarm
     argv = ["python", "-m", "moco_tpu.train", "--fake-devices", "1"]
-    env = {}
-    summary = ctl.apply(req, argv, env)
+    summary = ctl.apply(req, argv)
     assert argv[-4:] == ["--fake-devices", "8", "--sharding", "fsdp"]
     assert summary["sharding"] == "fsdp"
     # a mode-less request appends nothing: the original argv's own
@@ -104,7 +103,7 @@ def test_resize_apply_carries_sharding_mode(tmp_path):
     req2 = ctl.take()
     argv2 = ["python", "-m", "moco_tpu.train", "--sharding", "fsdp",
              "--fake-devices", "8"]
-    ctl.apply(req2, argv2, env)
+    ctl.apply(req2, argv2)
     assert "--sharding" not in argv2[-2:]
     assert argv2.count("--sharding") == 1
 
@@ -195,8 +194,7 @@ def test_read_recorded_devices_newest_stamped_step(tmp_path):
     assert read_recorded_devices(str(tmp_path / "missing")) is None
 
 
-def test_controller_arms_once_and_applies(tmp_path, monkeypatch):
-    monkeypatch.setenv("MOCO_TPU_CACHE_ROOT", str(tmp_path / "cache"))
+def test_controller_arms_once_and_applies(tmp_path):
     d = str(tmp_path)
     ctl = ResizeController(d, slow_cadence=8)
     assert ctl.poll() is None  # nothing pending
@@ -208,20 +206,14 @@ def test_controller_arms_once_and_applies(tmp_path, monkeypatch):
     taken = ctl.take()
     assert taken is req
     argv = ["python", "-m", "moco_tpu.train", "--fake-devices", "1"]
-    env: dict = {}
-    summary = ctl.apply(taken, argv, env)
+    summary = ctl.apply(taken, argv)
     # appended, not edited (argparse last-wins): the operator argv stays
     # visible, the new count + the slow-link cadence override ride behind
     assert argv[-4:] == ["--fake-devices", "2", "--grad-sync-cadence", "8"]
     assert summary["devices_from"] == 1 and summary["devices_to"] == 2
-    assert "per_run" in env["MOCO_TPU_CACHE_DIR"]
     # honored payload deleted after apply: a later payload-less resize
     # must not inherit this one's device count
     assert read_honored_request(d) is None
-    # NO_CACHE suppresses the rotation
-    env2: dict = {"MOCO_TPU_NO_CACHE": "1"}
-    ctl.apply(ResizeRequest(), ["x"], env2)
-    assert "MOCO_TPU_CACHE_DIR" not in env2
 
 
 def test_sigusr2_to_controller_arms_empty_request(tmp_path):
@@ -243,17 +235,6 @@ def test_sigusr2_recovers_payload_the_child_already_claimed(tmp_path):
     ctl.signal_resize()
     req = ctl.poll()
     assert req is not None and req.devices == 3 and req.source == "sigusr2"
-
-
-def test_rotate_cache_opt_out_preserves_operator_cache(tmp_path):
-    """--shared-compile-cache / operator-pinned MOCO_TPU_CACHE_DIR map to
-    rotate_cache=False: a resize must not silently override an explicit
-    cache choice."""
-    ctl = ResizeController(str(tmp_path), rotate_cache=False)
-    env = {"MOCO_TPU_CACHE_DIR": "/operator/pinned"}
-    summary = ctl.apply(ResizeRequest(devices=2), ["x"], env)
-    assert env["MOCO_TPU_CACHE_DIR"] == "/operator/pinned"
-    assert "cache_dir" not in summary
 
 
 def test_listener_file_trigger_and_sigusr2(tmp_path):
@@ -462,7 +443,7 @@ _STUB = textwrap.dedent("""\
     with open(os.path.join(tdir, "argv_%d.json" % n), "w") as f:
         json.dump(extra, f)
     with open(os.path.join(tdir, "env_%d.json" % n), "w") as f:
-        json.dump({"cache": os.environ.get("MOCO_TPU_CACHE_DIR", "")}, f)
+        json.dump({"cache": os.environ.get("JAX_COMPILATION_CACHE_DIR", "")}, f)
     def beat(step, phase="step"):
         p = os.path.join(tdir, "heartbeat.json")
         with open(p + ".tmp", "w") as f:
@@ -531,11 +512,13 @@ def _stub_supervisor(tmp_path, plan, argv_extra=(), **sup_kw):
 def test_e2e_request_file_resize_rewrites_relaunch(tmp_path, monkeypatch):
     """The whole supervisor-side loop on a stub child: a pending
     resize.request is armed and consumed, the child's 49 relaunches with
-    the device flag appended + a fresh per-resize cache dir, the
+    the device flag appended and the SAME compile cache (placed from
+    outside; a relaunch must find what its predecessor compiled), the
     mesh_change preflight fires (sidecar says 1, argv now says 2), and
     the incident lands as resize events + a `resize` span under the
     child span."""
-    monkeypatch.setenv("MOCO_TPU_CACHE_ROOT", str(tmp_path / "cacheroot"))
+    cache = tmp_path / "cacheroot"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
     sup, tdir = _stub_supervisor(
         tmp_path, "resize49:4/1,ok:8", argv_extra=("--fake-devices", "1"),
     )
@@ -561,12 +544,11 @@ def test_e2e_request_file_resize_rewrites_relaunch(tmp_path, monkeypatch):
     with open(tdir / "argv_1.json") as f:
         argv1 = json.load(f)
     assert argv1[-4:] == ["--fake-devices", "2", "--resume", "auto"]
-    # fresh per-resize compile cache, distinct from launch 0's
-    with open(tdir / "env_1.json") as f:
-        env1 = json.load(f)
-    assert "resize0" in env1["cache"]
-    with open(tdir / "env_0.json") as f:
-        assert json.load(f)["cache"] != env1["cache"]
+    # one cache across the resize: no per-run or per-resize directory
+    for n in (0, 1):
+        with open(tdir / f"env_{n}.json") as f:
+            assert json.load(f)["cache"] == str(cache)
+    assert not cache.exists() or os.listdir(cache) == []
     # one traced incident: a `resize` span parented under a child span
     spans = read_events_tail(os.path.join(str(tdir), "spans.jsonl"))
     child_ids = {s["span"] for s in spans if s.get("name") == "child"}
@@ -583,12 +565,10 @@ def test_e2e_request_file_resize_rewrites_relaunch(tmp_path, monkeypatch):
     assert summary["supervisor"]["classifications"] == ["resize", "clean"]
 
 
-def test_e2e_sigusr2_resize_without_payload(tmp_path, monkeypatch):
+def test_e2e_sigusr2_resize_without_payload(tmp_path):
     """SIGUSR2 to the SUPERVISOR with no request file: the child is
     signaled (the stub exits 49 from its handler, like the driver's
-    listener), and the relaunch keeps the argv's own device flags — only
-    the compile cache rotates."""
-    monkeypatch.setenv("MOCO_TPU_CACHE_ROOT", str(tmp_path / "cacheroot"))
+    listener), and the relaunch keeps the argv's own device flags."""
     sup, tdir = _stub_supervisor(
         tmp_path, "usr2exit:2,ok:9", argv_extra=("--fake-devices", "1"),
     )
@@ -609,18 +589,14 @@ def test_e2e_sigusr2_resize_without_payload(tmp_path, monkeypatch):
     with open(tdir / "argv_1.json") as f:
         argv1 = json.load(f)
     assert argv1.count("--fake-devices") == 1  # untouched: no target count
-    with open(tdir / "env_1.json") as f:
-        assert "resize0" in json.load(f)["cache"]
 
 
-def test_e2e_unbootable_resize_reverts_instead_of_dying(tmp_path,
-                                                        monkeypatch):
+def test_e2e_unbootable_resize_reverts_instead_of_dying(tmp_path):
     """A typo'd device count (more devices than the hardware has) makes
     the resized argv exit config_error at boot. The supervisor must
     REVERT the appended flags and finish the run on the old mesh — a bad
     resize request must not take a healthy run down (and must not grind
     the restart budget on a fatal class either)."""
-    monkeypatch.setenv("MOCO_TPU_CACHE_ROOT", str(tmp_path / "cacheroot"))
     # launch 0 resizes; launch 1 (the resized argv) dies 45; launch 2
     # (reverted argv) finishes clean
     sup, tdir = _stub_supervisor(
@@ -647,12 +623,11 @@ def test_e2e_unbootable_resize_reverts_instead_of_dying(tmp_path,
     assert "1 reverted (unbootable argv)" in render(summary)
 
 
-def test_take_path_still_records_the_request(tmp_path, monkeypatch):
+def test_take_path_still_records_the_request(tmp_path):
     """A resize the child honored before the supervisor's poll armed it
     (the chaos drill shape: request written + exit 49 within one poll
     cycle) must still land a resize_request record — a report reading
     'relaunches from 0 requests' looks like resizes nobody asked for."""
-    monkeypatch.setenv("MOCO_TPU_CACHE_ROOT", str(tmp_path / "cacheroot"))
     sup, tdir = _stub_supervisor(
         tmp_path, "exit:49,ok:8", argv_extra=("--fake-devices", "1"),
     )
@@ -700,8 +675,7 @@ def _drill_argv(tdir, ckpt_dir):
 def _drill_env(chaos="", chaos_state=""):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MOCO_TPU_NO_CACHE"] = "1"  # PR 4 finding: kill-risk runs + cache
-    env.pop("MOCO_TPU_CACHE_DIR", None)
+    env["MOCO_TPU_NO_CACHE"] = "1"  # throwaway drill children
     if chaos:
         env["MOCO_TPU_CHAOS"] = chaos
         env["MOCO_TPU_CHAOS_STATE"] = chaos_state

@@ -515,7 +515,6 @@ def _soak_env(tmp_path, chaos="", chaos_state=""):
     # designed (give_up after max_restarts no-progress deaths), but the
     # soak needs the run to COMPLETE. See README "Run supervision".
     env["MOCO_TPU_NO_CACHE"] = "1"
-    env.pop("MOCO_TPU_CACHE_DIR", None)
     if chaos:
         env["MOCO_TPU_CHAOS"] = chaos
         env["MOCO_TPU_CHAOS_STATE"] = chaos_state

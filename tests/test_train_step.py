@@ -131,6 +131,42 @@ def test_single_device_mesh_same_program(setup):
     assert np.isfinite(float(metrics["loss"]))
 
 
+def test_update_is_the_ddp_mean_not_the_device_sum():
+    """With SyncBN the 1-device and N-device programs compute the same
+    function of the global batch, so one SGD step (no momentum, no wd) must
+    move the query params identically. It did not before PR 21: the region
+    differentiated w.r.t. REPLICATED params, autodiff psum'd the grads
+    itself, GradSync's pmean over the already-reduced value was the
+    identity, and every update was N× the DDP mean
+    (collectives.device_local)."""
+    from moco_tpu.parallel.mesh import create_mesh
+
+    config = PretrainConfig(
+        variant="v1", num_negatives=K, embed_dim=DIM, lr=0.1, sync_bn=True,
+        batch_size=GLOBAL_B, weight_decay=0.0, sgd_momentum=0.0,
+    )
+    im_q = jax.random.normal(jax.random.key(1), (GLOBAL_B, IMG, IMG, 3))
+    im_k = jax.random.normal(jax.random.key(2), (GLOBAL_B, IMG, IMG, 3))
+    moved = {}
+    for n in (1, 4):
+        from moco_tpu.parallel.mesh import DATA_AXIS
+
+        model = ResNet(stage_sizes=(1, 1), block_cls=BasicBlock, width=8,
+                       cifar_stem=True, num_classes=DIM,
+                       bn_cross_replica_axis=DATA_AXIS)
+        tx, sched = build_optimizer(config, 4)
+        state = create_train_state(
+            jax.random.key(0), model, tx, (GLOBAL_B // n, IMG, IMG, 3), K, DIM)
+        before = jax.tree.map(np.asarray, state.params_q)
+        step = build_train_step(config, model, tx, create_mesh(n), 4, sched)
+        state, _ = step(state, im_q, im_k)
+        moved[n] = jax.tree.map(lambda a, b: np.asarray(a) - b,
+                                state.params_q, before)
+    for d1, d4 in zip(jax.tree.leaves(moved[1]), jax.tree.leaves(moved[4]),
+                      strict=True):
+        np.testing.assert_allclose(d4, d1, rtol=2e-3, atol=1e-6)
+
+
 def test_ring_shuffle_mode(setup, mesh8):
     """shuffle_mode='ring' (SURVEY §2.11 ppermute variant) must run the full
     step with finite loss and keep the queue semantics identical."""

@@ -2,7 +2,6 @@
 8-device mesh (BASELINE config 5; SURVEY §2.9/§3.5)."""
 
 import jax
-import jax.export  # noqa: F401  (binds the lazy submodule on 0.4.x)
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -3,6 +3,9 @@ _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)
 import time
 import jax, jax.numpy as jnp, numpy as np
 import flax.linen as nn
+from moco_tpu.utils.cache import enable_persistent_cache
+
+enable_persistent_cache()
 
 def timeit(fn, args, n=30, warm=8):
     for _ in range(warm): out = fn(*args)

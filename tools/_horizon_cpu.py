@@ -15,6 +15,9 @@ force_cpu_devices(8)
 import jax
 from moco_tpu.config import get_preset
 from moco_tpu.train import train
+from moco_tpu.utils.cache import enable_persistent_cache
+
+enable_persistent_cache()
 
 lr = float(sys.argv[1]) if len(sys.argv) > 1 else 0.03
 cfg = get_preset("cifar10-moco-v1").replace(

@@ -1,6 +1,6 @@
-"""On-chip step-time A/B for the r5-vs-r2 gap (README "open measurement
-question"): times the SAME fused MoCo-v2 R50 program as bench.py's step
-child under one knob setting per invocation, so the knob is applied before
+"""On-chip step-time A/B: times the SAME fused MoCo-v2 R50 program as
+`bench.py --mode step` under one knob setting per invocation, so the knob
+is applied before
 any moco_tpu import (fast_bn / augment read MOCO_TPU_DISABLE_PALLAS at
 trace time).
 
@@ -103,7 +103,7 @@ print(json.dumps({"ab": label, "backend": jax.default_backend(),
 
 for B in (int(b) for b in args.batches.split(",")):
     mesh = create_mesh(1)
-    # IDENTICAL program to bench.py's step child: the assembly and timing
+    # IDENTICAL program to bench.py's step mode: the assembly and timing
     # live in moco_tpu.utils.benchkit, shared with bench.py and
     # tools/_tpu_validate.py, so the A/B cannot drift from what the bench
     # publishes (review, r5)

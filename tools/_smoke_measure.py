@@ -5,6 +5,9 @@ from moco_tpu.parallel.mesh import force_cpu_devices
 force_cpu_devices(8)
 from moco_tpu.config import get_preset
 from moco_tpu.train import train
+from moco_tpu.utils.cache import enable_persistent_cache
+
+enable_persistent_cache()
 res = []
 for seed in (0, 1, 2):
     cfg = get_preset("cifar10-moco-v1").replace(

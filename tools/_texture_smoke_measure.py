@@ -24,6 +24,9 @@ force_cpu_devices(8)  # mirror the CI conftest topology
 from moco_tpu.config import get_preset
 from moco_tpu.data.datasets import SyntheticTextureDataset
 from moco_tpu.train import train
+from moco_tpu.utils.cache import enable_persistent_cache
+
+enable_persistent_cache()
 
 steps = int(sys.argv[1]) if len(sys.argv) > 1 else 256
 lr = float(sys.argv[2]) if len(sys.argv) > 2 else 0.12

@@ -9,6 +9,9 @@ import os as _os, sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 import time, sys
 import jax, jax.numpy as jnp, numpy as np
+from moco_tpu.utils.cache import enable_persistent_cache
+
+enable_persistent_cache()
 
 print("backend:", jax.default_backend())
 
@@ -40,7 +43,7 @@ t2 = timeit(xla_sums, (x,))
 print(f"xla    sums        [{x.shape}]: {t2:.2f} ms = {nbytes/t2/1e6:.0f} GB/s")
 
 # --- 3) fused step timing (assembly + timing shared via benchkit with
-#        bench.py's step child and tools/_perf_ab.py — review, r5) ---
+#        bench.py's step mode and tools/_perf_ab.py — review, r5) ---
 from moco_tpu.config import get_preset
 from moco_tpu.parallel.mesh import create_mesh
 from moco_tpu.utils.benchkit import build_v2_fused_bench, time_fused_step

@@ -137,7 +137,9 @@ def main(argv=None) -> int:
             info(f"config error: bad --buckets {args.buckets!r}")
             return EXIT_CONFIG_ERROR
         from moco_tpu.serve import EmbeddingEngine
+        from moco_tpu.utils.cache import enable_persistent_cache
 
+        enable_persistent_cache()
         try:
             engine = EmbeddingEngine.from_checkpoint(
                 args.checkpoint, args.arch, image_size=args.image_size,

@@ -15,8 +15,7 @@ hash AND an engine fingerprint covering the mocolint SOURCE itself plus
 the active config/rule selection — editing any rule, the config, or the
 engine silently invalidates every entry; no version constant to forget
 to bump. Entries are one JSON file per source path under
-`<cache_dir>/mocolint/` (the per-run cache dir convention:
-utils/cache.per_run_cache_dir or any directory the caller owns).
+`<cache_dir>/mocolint/` (any directory the caller owns).
 """
 
 from __future__ import annotations

@@ -21,7 +21,7 @@ from tools.progcheck.registry import Check, register
 _SUFFIX = "+health"
 # prims whose payload the health variant may legitimately grow: the
 # metrics reduction the diagnostics ride
-_REDUCE_PRIMS = ("psum", "psum2", "pmean")
+_REDUCE_PRIMS = ("psum", "psum_invariant", "pmean")
 
 
 @register

@@ -21,11 +21,8 @@ Wire conventions (mirroring the analytic side):
 
 from __future__ import annotations
 
-import warnings
 
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from tools.progcheck.jaxpr_utils import (
     SUM_REDUCE_PRIMS,

@@ -27,24 +27,22 @@ i's OUTPUT purely as a scheduling hint.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax import core as jax_core
+from jax import core as jax_core
+from jax.extend.core import Literal
 
 # collectives whose payload crosses the interconnect (named-axis prims at
-# the jaxpr level; psum appears as psum2 inside shard_map regions on this
-# jax version)
-SUM_REDUCE_PRIMS = frozenset({"psum", "psum2"})
+# the jaxpr level; inside shard_map regions jax 0.9 spells psum
+# `psum_invariant`)
+SUM_REDUCE_PRIMS = frozenset({"psum", "psum_invariant"})
 COLLECTIVE_PRIMS = SUM_REDUCE_PRIMS | frozenset({
     "pmax", "pmin", "all_gather", "ppermute", "all_to_all",
     "reduce_scatter",
 })
 # host-boundary primitives that must never appear in a step program
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "outside_call", "host_callback_call",
 })
 # outputs depend only on the same-position input. The collectives matter:
 # a tree-wide pmean is ONE multi-operand psum equation, and treating it
@@ -52,8 +50,8 @@ CALLBACK_PRIMS = frozenset({
 # tree — a structurally-zero key-encoder grad would inherit the query
 # grads' inputs through the shared reduce.
 POSITIONAL_PRIMS = frozenset({
-    "optimization_barrier", "psum", "psum2", "pmax", "pmin", "all_gather",
-    "ppermute", "pbroadcast", "pvary",
+    "optimization_barrier", "psum", "psum_invariant", "pmax", "pmin",
+    "all_gather", "ppermute", "pbroadcast", "pvary",
 })
 # ops through which a value stays "the same quantity" for taint purposes:
 # elementwise arithmetic, dtype casts, and layout moves. A dot_general or
@@ -134,7 +132,7 @@ def collect_collectives(closed_jaxpr) -> list[CollectiveOp]:
         if eqn.primitive.name not in COLLECTIVE_PRIMS:
             continue
         avals = [v.aval for v in eqn.invars
-                 if not isinstance(v, jax_core.Literal)]
+                 if not isinstance(v, Literal)]
         elems = sum(int(_size(a)) for a in avals)
         nbytes = sum(int(_size(a)) * _itemsize(a) for a in avals)
         out.append(CollectiveOp(
@@ -191,7 +189,7 @@ def input_dependence(closed_jaxpr) -> list[set[int]]:
             env[v] = set()
 
         def read(v) -> set[int]:
-            if isinstance(v, jax_core.Literal):
+            if isinstance(v, Literal):
                 return set()
             return env.get(v, set())
 
@@ -266,7 +264,7 @@ def double_sum_reduces(closed_jaxpr) -> list[tuple[str, str]]:
             env[v] = frozenset()
 
         def read(v) -> frozenset:
-            if isinstance(v, jax_core.Literal):
+            if isinstance(v, Literal):
                 return frozenset()
             return env.get(v, frozenset())
 
@@ -347,7 +345,7 @@ def trace_back(var, producers, through=("reshape", "concatenate",
             return None
         if eqn.primitive.name in through:
             nonlit = [v for v in eqn.invars
-                      if not isinstance(v, jax_core.Literal)]
+                      if not isinstance(v, Literal)]
             if len(nonlit) != 1:
                 return eqn  # concat of several: stop here, caller inspects
             var = nonlit[0]

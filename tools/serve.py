@@ -15,11 +15,8 @@ SIGTERM/SIGINT drains gracefully — in-flight requests complete, new work
 gets a structured 503 `draining` — via the resilience/preemption.py
 handler (second signal: immediate exit, exactly like the train driver).
 
-By default the process compiles into a PER-RUN XLA cache dir
-(utils/cache.per_run_cache_dir): a served process lives under external
-orchestrators that SIGKILL on eviction, and a kill mid-write must not
-poison the shared compile cache (PR 4 finding). An explicit
-MOCO_TPU_CACHE_DIR or MOCO_TPU_NO_CACHE=1 wins.
+One process per chip: a replica claims the accelerator JAX shows it, so
+on a TPU host give each replica its own device (README "Running").
 
 Exit codes (README table): 0 clean drain · 45 bad config/checkpoint ·
 47 could not bind host:port (see resilience/exitcodes.py).
@@ -155,12 +152,9 @@ def main(argv=None) -> int:
         info(f"config error: {e}")
         return EXIT_CONFIG_ERROR
 
-    from moco_tpu.utils.cache import enable_persistent_cache, per_run_cache_dir
+    from moco_tpu.utils.cache import enable_persistent_cache
 
-    if os.environ.get("MOCO_TPU_CACHE_DIR") or os.environ.get("MOCO_TPU_NO_CACHE"):
-        enable_persistent_cache()  # explicit operator choice wins
-    else:
-        enable_persistent_cache(per_run_cache_dir(tag="serve"))
+    enable_persistent_cache()
 
     try:
         service, registry = build_service(config)

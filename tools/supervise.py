@@ -82,14 +82,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="grad_sync_cadence override appended when a resize "
                         "request flags the new mesh slow-linked (`slow=1` "
                         "in resize.request); 0 = never override")
-    p.add_argument("--shared-compile-cache", action="store_true",
-                   help="let the child use the SHARED persistent XLA "
-                        "compile cache. Default is a per-run "
-                        "MOCO_TPU_CACHE_DIR (utils/cache.per_run_cache_dir)"
-                        ": a SIGKILL'd child can poison a shared cache "
-                        "into a native-crash loop for every later process "
-                        "(PR 4 finding). An explicit MOCO_TPU_CACHE_DIR / "
-                        "MOCO_TPU_NO_CACHE in the environment also wins")
     p.add_argument("--child-log", default="",
                    help="child stdout/stderr log path (default "
                         "<telemetry-dir>/child.log)")
@@ -106,20 +98,6 @@ def main(argv=None) -> int:
     if not child:
         build_parser().error("no child command given (append `-- python -m "
                              "moco_tpu.train ...`)")
-    owns_cache_dir = (not args.shared_compile_cache
-                      and not os.environ.get("MOCO_TPU_CACHE_DIR")
-                      and not os.environ.get("MOCO_TPU_NO_CACHE"))
-    if owns_cache_dir:
-        # supervised runs are kill-risk BY DESIGN (hang-kill escalation,
-        # chaos drills): isolate their compile cache so a SIGKILL mid-write
-        # can't poison the shared one for every later process on this host.
-        # Set once for the whole supervision (children inherit the env):
-        # a poisoned per-run dir is contained by the restart budget.
-        from moco_tpu.utils.cache import per_run_cache_dir  # stdlib-only
-
-        os.environ["MOCO_TPU_CACHE_DIR"] = per_run_cache_dir(tag="supervised")
-        info(f"per-run compile cache: {os.environ['MOCO_TPU_CACHE_DIR']} "
-             "(--shared-compile-cache opts out)")
     policy = RestartPolicy(
         max_restarts=args.max_restarts,
         heartbeat_stale_secs=args.heartbeat_stale_secs,
@@ -140,11 +118,6 @@ def main(argv=None) -> int:
         child_log_path=args.child_log,
         resize_device_flag=args.resize_device_flag,
         resize_slow_cadence=args.resize_slow_cadence,
-        # rotate the compile cache per resize only when the supervisor
-        # derived the cache dir itself: --shared-compile-cache and an
-        # operator-pinned MOCO_TPU_CACHE_DIR are explicit choices a
-        # resize must not silently override
-        resize_rotate_cache=owns_cache_dir,
     )
     # SIGUSR2 to the SUPERVISOR requests an elastic resize (ISSUE 11): the
     # next monitor cycle claims any pending resize.request payload (or an
