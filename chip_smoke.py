@@ -6,7 +6,10 @@ K=65536, MLP head, bf16) at per-chip batch 128 over ALL local devices, fed
 by a generated JPEG tree through the real feed (native decode → 512²
 staging canvas → H2D → on-device two-crop augmentation → step), for 8
 steps with a checkpoint save mid-run and one at the end. Then it checks
-what came out (see `run_smoke`) and prints ONE JSON line, last on stdout.
+what came out (see `run_smoke`) and prints two JSON lines on stdout: the full
+record (versions, compile cache, smoke readings, every check), then — LAST —
+the verdict `{"ok": ..., "device": {"platform", "kind", "count"}}` with exactly
+those keys, which is what a driver reads.
 
 One process: nothing here spawns, because a chip belongs to one process.
 No CPU mode: without a TPU (or outside the repo) it exits non-zero before
@@ -236,6 +239,17 @@ def run_smoke(workdir: str, *, platform: str, per_device_batch: int,
     }
 
 
+def verdict_line(record: dict) -> dict:
+    """The last stdout line: exactly `ok` and `device` (platform, kind,
+    count), the device as JAX reports it. Everything else is in the record
+    line before it."""
+    device = record["device"]
+    return {"ok": bool(record["ok"]),
+            "device": {"platform": str(device["platform"]),
+                       "kind": str(device["kind"]),
+                       "count": int(device["count"])}}
+
+
 def main() -> int:
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     # checkpoints, telemetry and the JPEG tree stay out of the checkout (and
@@ -246,7 +260,8 @@ def main() -> int:
                            per_device_batch=PER_CHIP_BATCH, min_mosaic_calls=1)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
-    print(json.dumps(record), flush=True)
+    print(json.dumps(record))
+    print(json.dumps(verdict_line(record)), flush=True)
     return 0 if record["ok"] else 1
 
 

@@ -2,6 +2,7 @@
 driver sends to the chip is exercised in tier-1), and the script itself has
 no CPU mode."""
 
+import json
 import os
 import subprocess
 import sys
@@ -27,6 +28,9 @@ def test_smoke_body_tiny_on_two_cpu_devices(tmp_path):
     )
     assert record["ok"], record["checks"]
     assert record["device"] == {"platform": "cpu", "kind": "cpu", "count": 2}
+    # the last stdout line a driver reads: exactly these keys, nothing else
+    assert json.loads(json.dumps(chip_smoke.verdict_line(record))) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 2}}
     assert record["steps"] == 8 and len(record["losses"]) == 8
     assert record["queue_ptr"] == (8 * 16) % 256
     assert record["per_device_batch_rows"] == {0: 8, 1: 8}
