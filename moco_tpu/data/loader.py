@@ -224,7 +224,11 @@ class Prefetcher:
                         idx, canvas.imgs[lo:hi], canvas.extents[lo:hi]
                     )
                 else:
-                    imgs, labels, extents = self.dataset.get_batch(idx)
+                    # the in-memory path (a dataset without canvases)
+                    with self._tracer.span("gather", cat="input",
+                                           detail=True, parent=trace_ctx,
+                                           batch=b, lo=lo, hi=hi):
+                        imgs, labels, extents = self.dataset.get_batch(idx)
                     canvas.imgs[lo:hi] = imgs
                     canvas.labels[lo:hi] = labels
                     canvas.extents[lo:hi] = extents
@@ -271,7 +275,8 @@ class Prefetcher:
                         getattr(a, "nbytes", 0) for a in item
                     )
                     self._stats.note_staged(
-                        time.perf_counter() - t0, self._q.qsize(), nbytes
+                        time.perf_counter() - t0, self._q.qsize(), nbytes,
+                        images=self.batch,
                     )
         except _CloseRequested:
             # consumer closed while a read was in retry backoff: the read

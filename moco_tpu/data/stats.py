@@ -19,6 +19,11 @@ Tracked:
     workers × wall seconds — low busy + starved queue means the workers
     are blocked on something other than decode (lock, storage)
   - decode-once canvas-cache hits/misses (CachedDataset)
+
+`snapshot()` carries, beside the rounded fields the reports read, the
+EXACT cumulative `staged_bytes`, `staged_images` and `worker_busy_s`: a
+reader takes the delta between two snapshots for a window's H2D bytes a
+step, decode rate or worker busy share (ISSUE 25).
 """
 
 from __future__ import annotations
@@ -50,6 +55,7 @@ class InputPipelineStats:
         self._created = time.perf_counter()
         self.staged_batches = 0
         self.staged_bytes = 0
+        self.staged_images = 0
         self._staged_s: list[float] = []
         self.queue_depth_last = 0
         self._queue_depth_sum = 0
@@ -66,11 +72,13 @@ class InputPipelineStats:
         with self._lock:
             self.workers = max(self.workers, int(n))
 
-    def note_staged(self, seconds: float, queue_depth: int, nbytes: int) -> None:
+    def note_staged(self, seconds: float, queue_depth: int, nbytes: int,
+                    images: int = 0) -> None:
         """One batch fully staged (decoded + transferred + enqueued)."""
         with self._lock:
             self.staged_batches += 1
             self.staged_bytes += int(nbytes)
+            self.staged_images += int(images)
             self._staged_s.append(float(seconds))
             if len(self._staged_s) > 2 * _LATENCY_WINDOW:
                 del self._staged_s[:-_LATENCY_WINDOW]
@@ -106,6 +114,11 @@ class InputPipelineStats:
             snap = {
                 "staged_batches": self.staged_batches,
                 "staged_mb": round(self.staged_bytes / 2**20, 1),
+                # exact cumulative counters, for a window's delta between
+                # two snapshots (the rounded fields above are the reports')
+                "staged_bytes": self.staged_bytes,
+                "staged_images": self.staged_images,
+                "worker_busy_s": self._worker_busy_s,
                 "staged_batch_s_p50": round(_percentile(ordered, 50), 6),
                 "staged_batch_s_p95": round(_percentile(ordered, 95), 6),
                 "queue_depth": self.queue_depth_last,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from moco_tpu.parallel.collectives import all_gather_batch, batch_axis_index
+from moco_tpu.telemetry.scopes import KEY_GATHER
 
 
 def l2_normalize(x: jax.Array, eps: float = 1e-12) -> jax.Array:
@@ -100,7 +101,8 @@ def v3_contrastive_loss(
     """
     k = lax.stop_gradient(k)
     if axis_name is not None:
-        k_all = all_gather_batch(k, axis_name, chunks)
+        with jax.named_scope(KEY_GATHER):
+            k_all = all_gather_batch(k, axis_name, chunks)
         offset = batch_axis_index(axis_name) * q.shape[0]
     else:
         k_all, offset = k, 0

@@ -19,6 +19,8 @@ everything else is host-side arithmetic and buffered writes.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
 import time
 
@@ -32,9 +34,11 @@ from moco_tpu.telemetry.registry import (
     MetricsRegistry,
 )
 from moco_tpu.data.stats import InputPipelineStats
+from moco_tpu.telemetry import scopes
 from moco_tpu.telemetry.timing import StepPhaseTimer
 from moco_tpu.telemetry.trace import SlowSampleDetector, Tracer, null_tracer
 from moco_tpu.utils import logging as mlog
+from moco_tpu.utils.cache import CompileCounters
 
 
 class RunTelemetry:
@@ -62,6 +66,11 @@ class RunTelemetry:
             if is_main else null_tracer()
         )
         self.tracer.install_signal()
+        if is_main:
+            # every span of this process also enters the profiler's trace
+            # (ISSUE 25): a no-op while no profiler session is open
+            self.tracer.annotation_factory = functools.partial(
+                _annotation, jax.profiler)
         if is_main and getattr(config, "trace_device_profile", False):
             self.tracer.profiler_hooks = (_profiler_start, _profiler_stop)
         # anomaly detectors arming the capture window (budgeted in the
@@ -94,6 +103,13 @@ class RunTelemetry:
         # and CachedDataset of the run by the driver; snapshots ride the
         # step records at the device-sampling stride
         self.input_stats = InputPipelineStats()
+        # compile counters (ISSUE 25): cumulative on every step record; the
+        # listeners are unregistered in close()
+        self.compiles = CompileCounters()
+        self._compiles_seen = 0
+        # set-up spans' seconds by name, written once as the `setup` event
+        self._setup_s: dict = {}
+        self._setup_emitted = False
         self.mfu = MFUEstimator.for_config(config, n_chips, device.device_kind)
         self.devices = DeviceMonitor(device)
         self.pod = PodAggregator(self.registry, n_procs, process_index)
@@ -163,6 +179,29 @@ class RunTelemetry:
         if self.heartbeat is not None:
             self.heartbeat.beat(step, phase=phase)
 
+    # -- set-up ---------------------------------------------------------------
+    @contextlib.contextmanager
+    def setup_span(self, name: str):
+        """One of the run's set-up phases (`scopes.SETUP_SPANS`): a tracer
+        span, so it reaches `spans.jsonl` and a profiler trace like any
+        other, whose seconds are also kept for the `setup` event — the
+        same two clock reads for both."""
+        with self.tracer.span(name, cat="setup"):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self._setup_s[name] = (self._setup_s.get(name, 0.0)
+                                       + time.perf_counter() - t0)
+
+    def _emit_setup(self) -> None:
+        """Once, with the first step record: every set-up span's seconds."""
+        self._setup_emitted = True
+        self.registry.emit(
+            "event", event="setup",
+            spans={k: round(v, 6) for k, v in self._setup_s.items()},
+        )
+
     # -- per-step ------------------------------------------------------------
     def on_step(self, step: int, phases: dict, throughput, loss=None,
                 health: dict | None = None) -> bool:
@@ -207,7 +246,21 @@ class RunTelemetry:
         capture_evt = self.tracer.tick(step)
         if capture_evt is not None:
             self.registry.emit("event", event="trace_capture", **capture_evt)
+        if not self._setup_emitted:
+            self._emit_setup()
+        compiles = self.compiles.snapshot()
+        if compiles["n"] > self._compiles_seen:
+            recent = self.compiles.drain_recent()
+            if step > 2:
+                # steps 1 and 2 each load one step program by design; any
+                # later compile names the step it fell in
+                self.registry.emit(
+                    "event", event="compile", step=int(step),
+                    n=compiles["n"] - self._compiles_seen,
+                    fun_names=recent[:16])
+            self._compiles_seen = compiles["n"]
         record = dict(step=int(step))
+        record["compile"] = compiles
         for key, value in phases.items():
             record[key] = round(value, 6)
         if phases.get("step_s"):
@@ -266,7 +319,6 @@ class RunTelemetry:
         # span flushes on the STAGING threads are concurrent with the
         # step and deliberately not booked — they are not main-thread
         # time) into the explicit telemetry sub-phase
-        self.tracer.consume_self_time()  # drop: contained in the window
         self.timer.note_telemetry(time.perf_counter() - t_tel0)
         return flushed
 
@@ -286,6 +338,7 @@ class RunTelemetry:
             return
         self._closed = True
         mlog.remove_event_sink(self._on_event)
+        self.compiles.close()
         summary = dict(
             steps=self._step_hist.count,
             incidents=self._incidents.value,
@@ -302,6 +355,7 @@ class RunTelemetry:
             summary["hbm_peak_bytes"] = int(self._hbm_gauge.high_water)
         if self.input_stats.staged_batches:
             summary["input"] = self.input_stats.snapshot()
+        summary["compile"] = self.compiles.snapshot()
         if self.tracer.captures_used or self.tracer.spans_recorded:
             summary["trace"] = dict(
                 self.tracer.capture_state(),
@@ -329,6 +383,18 @@ class RunTelemetry:
             )
         self.registry.close()
         self.tracer.close()
+
+
+def _annotation(profiler, name: str, attrs: dict):
+    """The tracer's annotation factory (bound to `jax.profiler`, which
+    trace.py may not import): a span named `step` enters the profiler as
+    `StepTraceAnnotation("train", step_num=...)`, which gives the device
+    plane its `Steps` line; every other span as a `TraceAnnotation` of
+    its own name."""
+    if name == scopes.STEP_SPAN:
+        return profiler.StepTraceAnnotation(
+            scopes.STEP_ANNOTATION, step_num=int(attrs.get("step", 0)))
+    return profiler.TraceAnnotation(name)
 
 
 def _profiler_start(trace_dir: str) -> None:

@@ -1,0 +1,44 @@
+"""The names the program gives its own work, in ONE place (ISSUE 25).
+
+Two kinds, both read from a `jax.profiler` trace by name:
+
+  - device SCOPES: `jax.named_scope` names inside the fused step program
+    (both builders, `train_step.py` and `v3_step.py`, take them from here,
+    so a refactor cannot rename one silently). Every operation of
+    `jit_fused_step` lies under exactly one of `STEP_SCOPES`; the
+    `COLLECTIVE_SCOPES` nest beneath them around the cross-device
+    collectives. A scope is HLO metadata (`op_name`): it costs nothing at
+    run time and does not change the program's values.
+  - host SPANS: `Tracer.span(...)` names of the driver loop, the set-up and
+    the input threads. `RunTelemetry` gives the tracer an annotation
+    factory, so every span also enters a `jax.profiler.TraceAnnotation`
+    and lands in a capture window's (or the benchmark's) device trace, on
+    the profiler's clock, at every `trace_mode`.
+
+Pure stdlib: `telemetry/trace.py`'s import diet (mocolint R12) extends to
+anything it could ever want to import from here.
+"""
+
+from __future__ import annotations
+
+# -- device scopes --------------------------------------------------------------
+AUG = "aug"                # two-crop augmentation, incl. the blur kernel and fold_in
+K_FWD = "k_fwd"            # key / momentum encoder forward (v2: shuffle, unshuffle)
+Q_FWD_BWD = "q_fwd_bwd"    # query forward + backward, minus what is under loss_queue
+LOSS_QUEUE = "loss_queue"  # logits, contrastive loss, accuracy / health scalars, enqueue
+OPT_EMA = "opt_ema"        # EMA, gradient sync, optimizer update, schedule
+STEP_SCOPES = (AUG, K_FWD, Q_FWD_BWD, LOSS_QUEUE, OPT_EMA)
+
+SHUFFLE_BN = "shuffle_bn"  # v2: the all-gather + permutation before the key forward
+KEY_GATHER = "key_gather"  # the all-gather of the keys (v2: unshuffle; v3: in-batch negatives)
+GRAD_SYNC = "grad_sync"    # GradSync's reduce of the per-device gradients
+COLLECTIVE_SCOPES = (SHUFFLE_BN, KEY_GATHER, GRAD_SYNC)
+
+# -- host spans -----------------------------------------------------------------
+STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler as
+STEP_ANNOTATION = "train"  # StepTraceAnnotation(STEP_ANNOTATION, step_num=<global step>)
+LOOP_SPANS = ("data_wait", "dispatch", "fence", "sentinel", "loss_readback",
+              "telemetry", "checkpoint")
+SETUP_SPANS = ("create_train_state", "model_init", "opt_init", "place_state",
+               "build_step", "first_batch", "restore")
+INPUT_SPANS = ("stage_batch", "decode_slice", "gather", "h2d_shard")

@@ -47,6 +47,13 @@ Usage per iteration (driver order):
     timer.mark_dispatch()
     timer.maybe_fence(step, sync_obj)    # stride-gated block_until_ready
     phases = timer.finish_step()         # {"data_s", "host_s", ...}
+
+The driver makes each mark as the LAST statement inside the tracer span
+of the same phase (`data_wait` around the loader's `next`, `dispatch`
+around the step call; ISSUE 25), so the step record's `data_s` / `host_s`
+and the spans a profiler trace holds end on the same clock read and
+cannot disagree; `fence_due` lets it open the `fence` span on fenced steps
+only.
 """
 
 from __future__ import annotations
@@ -89,6 +96,11 @@ class StepPhaseTimer:
     def mark_dispatch(self) -> None:
         self._t_dispatch = time.perf_counter()
 
+    def fence_due(self, step: int) -> bool:
+        """Whether `maybe_fence(step, ...)` would block on the device."""
+        return (self.stride > 0 and step % self.stride == 0
+                and self._t_dispatch is not None)
+
     def maybe_fence(self, step: int, sync_obj, comm_pre=None,
                     comm_post=None) -> float | None:
         """Stride-gated device fence; returns device_s on sampled steps.
@@ -102,9 +114,7 @@ class StepPhaseTimer:
         6): when both are present on a fenced step they are drained FIRST,
         in order, and their gap is recorded as the `comm_s` phase — see the
         module docstring for what that number can and cannot claim."""
-        if self.stride <= 0 or step % self.stride != 0:
-            return None
-        if self._t_dispatch is None:  # fence without a dispatch mark
+        if not self.fence_due(step):  # off-stride, or no dispatch mark
             return None
         if comm_pre is not None and comm_post is not None:
             try:

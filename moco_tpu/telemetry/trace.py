@@ -20,7 +20,14 @@ actually happens. This module is the span layer every process shares:
   - `trace_mode` knob, off by default: `off` records nothing, `steps`
     records the coarse spans (one per step / staged batch / serve flush /
     supervisor launch), `full` additionally records the detail spans
-    (worker decode slices, per-shard H2D puts, engine calls).
+    (the step's data_wait / dispatch / telemetry children, worker decode
+    slices, per-shard H2D puts, engine calls). `trace_mode` governs
+    `spans.jsonl` ONLY: where an `annotation_factory` is installed (the
+    train driver installs `jax.profiler.TraceAnnotation`; the supervisor
+    and the serve fleet install nothing) every span ALSO enters that
+    annotation, at every mode including `off`, so a profiler session —
+    a capture window's device trace, the benchmark's traced steps —
+    holds the program's spans on the profiler's own clock (ISSUE 25).
   - On-demand and anomaly-triggered CAPTURE: SIGUSR1 or a
     `<telemetry_dir>/trace.trigger` file arms a bounded window during
     which the effective mode is `full` (and, when hooks are installed, a
@@ -186,18 +193,50 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _AnnotationSpan:
+    """What `span()` returns where nothing is recorded but an annotation
+    factory is installed: the profiler annotation alone behind the span's
+    interface — no ring, no ids, no `spans.jsonl` line. The annotation is
+    a no-op while no profiler session is open, so with tracing off a span
+    costs this object and the annotation's."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def context(self):
+        return None
+
+
 class Span:
     """One live span (handle of `Tracer.span(...)`). Only ever used as a
     context manager (mocolint R12): __enter__ stamps the start and pushes
     onto the opening thread's span stack (so nested spans parent
-    automatically), __exit__ records the completed span into the ring."""
+    automatically), __exit__ records the completed span into the ring.
+    With an `annotation` (the tracer's factory made one) the span enters
+    and leaves it around its own interval."""
 
     __slots__ = ("_tracer", "name", "cat", "trace_id", "span_id",
-                 "parent_id", "attrs", "_t_wall", "_t0", "_entered")
+                 "parent_id", "attrs", "_t_wall", "_t0", "_entered",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 parent: tuple[str, str] | None, attrs: dict):
+                 parent: tuple[str, str] | None, attrs: dict,
+                 annotation=None):
         self._tracer = tracer
+        self._annotation = annotation
         self.name = name
         self.cat = cat
         if parent is None:
@@ -219,6 +258,8 @@ class Span:
         return (self.trace_id, self.span_id)
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t_wall = time.time()
         self._t0 = time.perf_counter()
         self._entered = True
@@ -226,12 +267,14 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        dur_s = time.perf_counter() - self._t0
         self._tracer._pop(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._record(
-            self.name, self.cat, self._t_wall,
-            time.perf_counter() - self._t0,
+            self.name, self.cat, self._t_wall, dur_s,
             self.trace_id, self.span_id, self.parent_id, self.attrs,
         )
         return False
@@ -250,6 +293,7 @@ class _NullTracer:
     spans_recorded = 0
     spans_written = 0
     profiler_hooks = None
+    annotation_factory = None
 
     def span(self, name, *, cat="span", detail=False, parent=None, **attrs):
         return NULL_SPAN
@@ -280,9 +324,6 @@ class _NullTracer:
 
     def child_env(self):
         return {}
-
-    def consume_self_time(self):
-        return 0.0
 
     def install_signal(self):
         return False
@@ -319,11 +360,15 @@ class Tracer:
 
     Overhead contract: recording one span is a dict build plus a
     `deque.append` (GIL-atomic, lock-free); the ring drains to disk only
-    when `flush_every` spans accumulated (or at capture end / close), and
-    that drain time — plus everything else the span layer does off the
-    hot path (trigger-file polls, capture transitions) — is accumulated
-    into `consume_self_time()` so the step-phase report can book it as
-    the explicit `telemetry` sub-phase instead of skewing data/host."""
+    when `flush_every` spans accumulated (or at capture end / close). The
+    driver's `tick` and the flushes it causes run inside
+    `RunTelemetry.on_step`, whose own clock books them as the step's
+    `telemetry` sub-phase.
+
+    `annotation_factory` (None, or `factory(name, attrs) -> context
+    manager`) is injected like `profiler_hooks`, because this module stays
+    jax-free: when set, every span enters the annotation it makes, whether
+    or not the span is recorded."""
 
     def __init__(self, telemetry_dir: str | None, mode: str = "off", *,
                  proc: str = "proc", run_id: str | None = None,
@@ -359,12 +404,11 @@ class Tracer:
         self._flush_every = max(int(flush_every), 1)
         self._io_lock = threading.Lock()
         self._tls = threading.local()
-        self._self_s = 0.0
-        self._self_lock = threading.Lock()
         self._trigger_poll_secs = float(trigger_poll_secs)
         self._last_trigger_poll = float("-inf")
         self._prev_sigusr1 = None
         self.profiler_hooks: tuple | None = None  # (start(dir), stop())
+        self.annotation_factory = None  # factory(name, attrs) -> ctx manager
         self.profiler_error: str | None = None
         self._profiler_active = False
         self._path = None
@@ -389,11 +433,17 @@ class Tracer:
              parent: tuple[str, str] | None = None, **attrs):
         """Open one span as a context manager. `detail=True` marks a
         fine-grained span recorded only at `full` level (or inside a
-        capture window); coarse spans record from `steps` up."""
+        capture window); coarse spans record from `steps` up. A span that
+        is not recorded still enters the profiler annotation, where a
+        factory is installed."""
+        factory = self.annotation_factory
+        annotation = factory(name, attrs) if factory is not None else None
         lvl = self._level()
         if lvl == 0 or (detail and lvl < 2):
-            return NULL_SPAN
-        return Span(self, name, cat, parent, attrs)
+            if annotation is None:
+                return NULL_SPAN
+            return _AnnotationSpan(annotation)
+        return Span(self, name, cat, parent, attrs, annotation)
 
     def instant(self, name: str, *, cat: str = "instant",
                 parent: tuple[str, str] | None = None, **attrs):
@@ -428,30 +478,25 @@ class Tracer:
         return sid
 
     def record_step(self, step: int, phases: dict, **attrs) -> str | None:
-        """One training step as a span tree, derived from the phase dict
-        (`step_s`/`data_s`/`host_s`/...): the step span at `steps` level,
-        plus sequential data/host/telemetry child segments at `full`
-        level. `device_s`/`comm_s` are drain measurements, not wall
-        segments — they ride as attrs, not child spans."""
-        lvl = self._level()
-        if lvl == 0:
+        """One training step's phase dict (`step_s`/`data_s`/`host_s`/...,
+        the fenced `device_s`/`comm_s` drain samples among them) as the
+        attrs of its `step` span. Where the calling thread holds an open
+        `step` span (the driver loop's: it records itself at its own start
+        with its real children, `data_wait` / `dispatch` / `telemetry`,
+        beneath it) the phases are stamped onto that one; otherwise the
+        step is recorded retroactively from `step_s`, with no children."""
+        if self._level() == 0:
             return None
-        step_s = float(phases.get("step_s", 0.0))
-        t0 = time.time() - step_s
         span_attrs = {k: round(float(v), 6) for k, v in phases.items()}
         span_attrs.update(attrs)
         span_attrs["step"] = int(step)
-        sid = self.record_span("step", t0, step_s, cat="step", **span_attrs)
-        if lvl >= 2 and sid is not None:
-            parent = (self.trace_id, sid)
-            cursor = t0
-            for seg in ("telemetry_s", "data_s", "host_s"):
-                seg_s = float(phases.get(seg, 0.0))
-                if seg_s > 0.0:
-                    self.record_span(seg[:-2], cursor, seg_s, cat="phase",
-                                     parent=parent, step=int(step))
-                    cursor += seg_s
-        return sid
+        for open_span in reversed(self._stack()):
+            if open_span.cat == "step":
+                open_span.set(**span_attrs)
+                return open_span.span_id
+        step_s = float(phases.get("step_s", 0.0))
+        return self.record_span("step", time.time() - step_s, step_s,
+                                cat="step", **span_attrs)
 
     # -- parenting -----------------------------------------------------------
     def _stack(self) -> list:
@@ -522,10 +567,9 @@ class Tracer:
     def flush(self) -> None:
         """Drain the ring to spans.jsonl (one O_APPEND write of all
         pending lines — safe to interleave with other processes appending
-        to the same file). Flush time is booked as span-layer self-time."""
+        to the same file)."""
         if self._path is None:
             return
-        t0 = time.perf_counter()
         with self._io_lock:
             lines = []
             while True:
@@ -538,7 +582,6 @@ class Tracer:
                 with open(self._path, "a", encoding="utf-8") as f:
                     f.write("\n".join(lines) + "\n")
                 self.spans_written += len(lines)
-        self._note_self(time.perf_counter() - t0)
 
     # -- capture windows -----------------------------------------------------
     def request_capture(self, reason: str) -> None:
@@ -567,12 +610,6 @@ class Tracer:
         start / end / budget-denied) for the caller to land in
         events.jsonl, else None. Also polls the trigger file, time-gated
         so the stat() never rides every step."""
-        t0 = time.perf_counter()
-        evt = self._tick_inner(step)
-        self._note_self(time.perf_counter() - t0)
-        return evt
-
-    def _tick_inner(self, step) -> dict | None:
         if self._path is None:
             return None
         now = time.monotonic()
@@ -660,20 +697,6 @@ class Tracer:
         except Exception as e:  # ending the window must never end the run
             self.profiler_error = repr(e)
             self.instant("profiler_error", cat="capture", error=repr(e))
-
-    # -- self-time accounting (the `telemetry` sub-phase) --------------------
-    def _note_self(self, seconds: float) -> None:
-        with self._self_lock:
-            self._self_s += seconds
-
-    def consume_self_time(self) -> float:
-        """Span-layer self-time (flushes, trigger polls, capture
-        transitions) accumulated since the last call — booked by the
-        driver into StepPhaseTimer's `telemetry` sub-phase so a capture
-        window cannot masquerade as a data/host regression."""
-        with self._self_lock:
-            s, self._self_s = self._self_s, 0.0
-        return s
 
     # -- signals -------------------------------------------------------------
     def install_signal(self) -> bool:

@@ -56,7 +56,9 @@ from moco_tpu.resilience import (
     parse_chaos_spec,
     write_resize_request,
 )
-from moco_tpu.train_state import create_train_state
+from moco_tpu.telemetry import scopes
+from moco_tpu.telemetry.trace import null_tracer
+from moco_tpu.train_state import create_train_state, no_span
 from moco_tpu.train_step import build_encoder, build_optimizer, build_train_step
 from moco_tpu.utils.logging import ProfilerWindow, ScalarWriter, info, log_event
 from moco_tpu.utils.meters import AverageMeter, ProgressMeter, RateMeter, Throughput
@@ -412,6 +414,10 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                             n=dataset_len,
                             backend=getattr(dataset, "backend", ""))
     input_stats = telemetry.input_stats if telemetry is not None else None
+    # the program's own spans (ISSUE 25; names in telemetry/scopes.py): with
+    # telemetry on each also enters the profiler's trace, at every trace_mode
+    tracer = telemetry.tracer if telemetry is not None else null_tracer()
+    setup_span = telemetry.setup_span if telemetry is not None else no_span
 
     if (config.input_cache_mb and not config.input_prestage
             and dataset is not None):
@@ -433,40 +439,48 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
     model = build_encoder(config)
     tx, sched = build_optimizer(config, steps_per_epoch)
     init_key = jax.random.key(config.seed)
-    if config.variant == "v3":
-        from moco_tpu.v3_step import create_v3_train_state
-
-        state = create_v3_train_state(
-            init_key, model, tx, (local_b, config.image_size, config.image_size, 3)
-        )
-    else:
-        state = create_train_state(
-            init_key,
-            model,
-            tx,
-            (local_b, config.image_size, config.image_size, 3),
-            config.num_negatives,
-            config.embed_dim,
-        )
-    # gradient-sync accumulators (ISSUE 6): attached BEFORE any resume so
-    # the restore target carries the dialect-2 leaves (quantized/demo);
-    # fused/bucketed attach an empty tree
     from moco_tpu.parallel.gradsync import GradSync
 
-    # bound to the mesh's own axes (for_mesh): on the 2-D fsdp_tp mesh the
-    # quantized reduce is the multihop one, and the telemetry describe()
-    # below must account the same per-hop bytes the program moves
-    gradsync = GradSync.for_mesh(config, mesh)
-    state = gradsync.attach(state, mesh)
-    if config.sharding != "dp":
-        # FSDP placement (ISSUE 15): params/opt leaves land sharded over
-        # the fsdp axis BEFORE the step builds, so jit compiles against
-        # the committed input shardings (the zero_sharding pattern)
-        from moco_tpu.parallel import fsdp
+    with setup_span("create_train_state"):
+        if config.variant == "v3":
+            from moco_tpu.v3_step import create_v3_train_state
 
-        state = fsdp.place_state(state, mesh, config)
-    step_fn = build_train_step(config, model, tx, mesh, steps_per_epoch,
-                               sched, state=state)
+            state = create_v3_train_state(
+                init_key, model, tx,
+                (local_b, config.image_size, config.image_size, 3),
+                span=setup_span,
+            )
+        else:
+            state = create_train_state(
+                init_key,
+                model,
+                tx,
+                (local_b, config.image_size, config.image_size, 3),
+                config.num_negatives,
+                config.embed_dim,
+                span=setup_span,
+            )
+        with setup_span("place_state"):
+            # gradient-sync accumulators (ISSUE 6): attached BEFORE any
+            # resume so the restore target carries the dialect-2 leaves
+            # (quantized/demo); fused/bucketed attach an empty tree.
+            # Bound to the mesh's own axes (for_mesh): on the 2-D fsdp_tp
+            # mesh the quantized reduce is the multihop one, and the
+            # telemetry describe() below must account the same per-hop
+            # bytes the program moves
+            gradsync = GradSync.for_mesh(config, mesh)
+            state = gradsync.attach(state, mesh)
+            if config.sharding != "dp":
+                # FSDP placement (ISSUE 15): params/opt leaves land sharded
+                # over the fsdp axis BEFORE the step builds, so jit
+                # compiles against the committed input shardings (the
+                # zero_sharding pattern)
+                from moco_tpu.parallel import fsdp
+
+                state = fsdp.place_state(state, mesh, config)
+    with setup_span("build_step"):
+        step_fn = build_train_step(config, model, tx, mesh, steps_per_epoch,
+                                   sched, state=state)
     if telemetry is not None:
         # static comm facts for the record stream: mode, knobs, analytic
         # per-device sync payload (bytes/step) — rendered by
@@ -501,8 +515,9 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
             restore_sharding = state_shardings(state, mesh, config)
         else:
             restore_sharding = replicated(mesh)
-        state = maybe_resume(mgr, state, config.resume,
-                             sharding=restore_sharding)
+        with setup_span("restore"):
+            state = maybe_resume(mgr, state, config.resume,
+                                 sharding=restore_sharding)
         if gradsync.needs_state:
             # re-place the per-device accumulators (the replicated-restore
             # path lands them replicated) — mirrors the ZeRO re-shard below
@@ -544,8 +559,9 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
 
     aug_cfg = with_dtype(aug_cfg, config.compute_dtype)
     data_key = jax.random.key(config.seed + 1)
-    two_crops_fn = build_two_crops_sharded(aug_cfg, mesh)
-    fused_step = build_fused_step(step_fn, two_crops_fn, data_key)
+    with setup_span("build_step"):
+        two_crops_fn = build_two_crops_sharded(aug_cfg, mesh)
+        fused_step = build_fused_step(step_fn, two_crops_fn, data_key)
 
     # host-side step counter mirroring state.step: int(state.step) would be a
     # device→host sync serializing every iteration
@@ -676,6 +692,7 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
         )
     preempted = False
     resized = False
+    first_batch_pending = True  # the run's first loader wait is set-up's
     _resilience = contextlib.ExitStack()
     preempt = _resilience.enter_context(PreemptionHandler())
     # elastic resize (ISSUE 11): SIGUSR2 or a <telemetry_dir>/resize.request
@@ -743,239 +760,275 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
             if telemetry is not None:
                 telemetry.timer.epoch_start()
             try:
-                for i, (imgs, _labels, extents) in enumerate(loader, start=skip):
-                    if i >= steps_per_epoch:  # steps_per_epoch may cap the epoch
-                        break
-                    data_time.update(time.perf_counter() - end)
-                    if telemetry is not None:
-                        telemetry.timer.mark_data()
-                    profiler.maybe_toggle(global_step)
-                    state, metrics = fused_step(state, imgs, extents, global_step)
-                    global_step += 1
-                    # comm-phase probes (ISSUE 6): device scalars marking
-                    # grads-ready / grads-reduced, popped so meters and the
-                    # scalar writer never see them
-                    gs_pre = metrics.pop("gs_comm_pre", None)
-                    gs_post = metrics.pop("gs_comm_post", None)
-                    # learning-health scalars (ISSUE 13): popped like the
-                    # gs probes so meters/scalar-writer never see them.
-                    # The h_* block carries cond-selected ZEROS on
-                    # off-stride steps — only on-stride values are real.
-                    neg_sim = metrics.pop("neg_sim", None)
-                    logit_margin = metrics.pop("logit_margin", None)
-                    health_dev = {
-                        k: metrics.pop(k)
-                        for k in [k for k in metrics if k.startswith("h_")]
-                    }
-                    on_health_stride = bool(
-                        config.health_stride
-                        and (global_step - 1) % config.health_stride == 0
-                    )
-                    if telemetry is not None:
-                        telemetry.timer.mark_dispatch()
-                        # stride-gated device fence: off-stride steps stay
-                        # fully async (the overhead contract)
-                        telemetry.timer.maybe_fence(
-                            global_step, metrics["loss"],
-                            comm_pre=gs_pre, comm_post=gs_post,
-                        )
-                    if plan is not None and plan.maybe_nan(global_step):
-                        # emulate a real divergence end-to-end: the NaN flows
-                        # through the same metrics dict the sentinel/meters see
-                        metrics = dict(metrics, loss=float("nan"))
-                    if sentinel is not None:
-                        sentinel.observe(global_step, metrics["loss"],
-                                         pos=(epoch, i))
-                    if collapse is not None:
-                        obs = {"logit_margin": logit_margin,
-                               "acc1": metrics.get("acc1")}
-                        if on_health_stride:
-                            # stride-gated diagnostics are real only on
-                            # stride steps; feeding the off-stride zeros
-                            # would read as instant collapse
-                            obs.update(health_dev)
-                        collapse.observe(global_step, obs, pos=(epoch, i))
-                    if plan is not None:
-                        # slow-step drill (ISSUE 8): the sleep lands inside
-                        # THIS step's timer window, so the anomaly detector
-                        # sees a real step_s blowout end-to-end
-                        plan.maybe_slow(global_step)
-                    watchdog.beat(global_step)
-                    d_fail = getattr(dataset, "decode_failures", 0)
-                    d_total = getattr(dataset, "decode_total", 0)
-                    # per-host fault signals (SIGTERM flag, decode counters)
-                    # must be ACTED on identically everywhere: one host
-                    # raising or breaking alone leaves the rest hung in the
-                    # next collective. Multi-host runs agree on them at a
-                    # fixed step cadence; single-host acts immediately.
-                    # refresh the resize flag from the trigger file (time-
-                    # gated; SIGUSR2 needs no poll) before the pod sync so
-                    # every host folds the same observation
-                    resize.poll()
-                    preempt_agreed = False
-                    resize_agreed = False
-                    abort_fail, abort_total = d_fail, d_total
-                    if n_procs > 1:
-                        abort_fail = abort_total = 0
-                        if (config.resilience_sync_steps > 0 and
-                                global_step % config.resilience_sync_steps == 0):
-                            from jax.experimental import multihost_utils
-
-                            agg = multihost_utils.process_allgather(
-                                np.asarray(
-                                    [int(preempt.triggered), d_fail, d_total,
-                                     int(resize.triggered)],
-                                    np.int64,
-                                )
-                            )
-                            preempt_agreed = bool(agg[:, 0].max())
-                            resize_agreed = bool(agg[:, 3].max())
-                            abort_fail = int(agg[:, 1].sum())
-                            abort_total = int(agg[:, 2].sum())
+                # steps_per_epoch may cap the epoch; the loader's length is
+                # known, so the `step` span opens only around real steps
+                batches = iter(loader)
+                for i in range(skip, min(steps_per_epoch, skip + len(loader))):
+                    # one `step` span per iteration (the profiler's
+                    # StepTraceAnnotation); its children are where the main
+                    # thread's time goes, what is under none of them is the
+                    # loop's own (`loop_unspanned_ms_per_step`)
+                    with tracer.span(scopes.STEP_SPAN, cat="step",
+                                     step=global_step + 1):
+                        with tracer.span("data_wait", detail=True):
+                            if first_batch_pending:
+                                first_batch_pending = False
+                                with setup_span("first_batch"):
+                                    batch = next(batches, None)
+                            else:
+                                batch = next(batches, None)
+                            # the span's end and the record's data_s: one read
                             if telemetry is not None:
-                                # pod telemetry piggybacks on this already-
-                                # synchronizing cadence: one extra small
-                                # allgather, no new sync points; process 0
-                                # folds the matrix into a `pod` record
-                                telemetry.pod_record(
-                                    global_step,
-                                    multihost_utils.process_allgather(
-                                        telemetry.pod_vector()
-                                    ),
-                                )
-                    if (
-                        config.decode_abort_rate
-                        and abort_total >= config.batch_size
-                        and abort_fail / abort_total > config.decode_abort_rate
-                    ):
-                        raise DataQualityError(
-                            f"decode-failure rate {abort_fail}/{abort_total} = "
-                            f"{abort_fail / abort_total:.1%} exceeds "
-                            f"decode_abort_rate={config.decode_abort_rate:.1%}: "
-                            "training on zero canvases would silently waste "
-                            "the run"
-                        )
-                    step_loss = None  # host-synced loss, when printing pulls it
-                    if i % config.print_freq == 0:
-                        # pull metrics (host sync) only when printing
-                        last_metrics = {k: float(v) for k, v in metrics.items()}
-                        step_loss = last_metrics["loss"]
-                        if config.debug_nans and not np.isfinite(last_metrics["loss"]):
-                            raise FloatingPointError(
-                                f"non-finite loss {last_metrics['loss']} at step {global_step}"
-                            )
-                        losses.update(last_metrics["loss"], config.batch_size)
-                        top1.update(last_metrics.get("acc1", 0.0), config.batch_size)
-                        top5.update(last_metrics.get("acc5", 0.0), config.batch_size)
-                        decode_fail.update(d_fail, d_total)
-                        progress.display(i)
-                        writer.write(
-                            global_step,
-                            dict(
-                                last_metrics,
-                                # per-step line reports the ROLLING rate (the
-                                # cumulative one drags the compile stall
-                                # through the whole epoch); the epoch summary
-                                # below stays cumulative
-                                imgs_per_sec=throughput.rolling_imgs_per_sec,
-                                imgs_per_sec_per_chip=(
-                                    throughput.rolling_imgs_per_sec
-                                    / max(n_chips, 1)
-                                ),
-                                decode_failures=d_fail,
-                                decode_failure_rate=decode_fail.rate,
-                            ),
-                        )
-                    throughput.update(config.batch_size)
-                    batch_time.update(time.perf_counter() - end)
-                    end = time.perf_counter()
-                    health_rec = None
-                    if telemetry is not None and on_health_stride:
-                        # health block for the step record (ISSUE 13):
-                        # pulled to host only on health-stride steps, as
-                        # ONE batched transfer — per-scalar float() would
-                        # pay a device→host round trip each × a dozen
-                        # scalars. Keys drop the h_ prefix — obsd rules
-                        # address them as health:<key>.
-                        pull = dict(health_dev)
-                        if logit_margin is not None:
-                            pull["_logit_margin"] = logit_margin
-                            pull["_neg_sim"] = neg_sim
-                            pull["_pos_sim"] = metrics["pos_sim"]
-                            pull["_acc1"] = metrics["acc1"]
-                        host = jax.device_get(pull)
-                        health_rec = {
-                            k[2:]: round(float(v), 6)
-                            for k, v in host.items()
-                            if k.startswith("h_")
+                                telemetry.timer.mark_data()
+                        if batch is None:  # the loader ended early
+                            break
+                        imgs, _labels, extents = batch
+                        data_time.update(time.perf_counter() - end)
+                        profiler.maybe_toggle(global_step)
+                        with tracer.span("dispatch", detail=True):
+                            state, metrics = fused_step(
+                                state, imgs, extents, global_step)
+                            # the span's end and the record's host_s: one read
+                            if telemetry is not None:
+                                telemetry.timer.mark_dispatch()
+                        global_step += 1
+                        # comm-phase probes (ISSUE 6): device scalars marking
+                        # grads-ready / grads-reduced, popped so meters and the
+                        # scalar writer never see them
+                        gs_pre = metrics.pop("gs_comm_pre", None)
+                        gs_post = metrics.pop("gs_comm_post", None)
+                        # learning-health scalars (ISSUE 13): popped like the
+                        # gs probes so meters/scalar-writer never see them.
+                        # The h_* block carries cond-selected ZEROS on
+                        # off-stride steps — only on-stride values are real.
+                        neg_sim = metrics.pop("neg_sim", None)
+                        logit_margin = metrics.pop("logit_margin", None)
+                        health_dev = {
+                            k: metrics.pop(k)
+                            for k in [k for k in metrics if k.startswith("h_")]
                         }
-                        if logit_margin is not None:
-                            health_rec["logit_margin"] = round(
-                                float(host["_logit_margin"]), 6)
-                            health_rec["neg_sim"] = round(
-                                float(host["_neg_sim"]), 6)
-                            health_rec["pos_sim"] = round(
-                                float(host["_pos_sim"]), 6)
-                            health_rec["acc1"] = round(
-                                float(host["_acc1"]), 4)
-                    if telemetry is not None:
-                        phases = telemetry.timer.finish_step()
-                        if telemetry.on_step(global_step, phases, throughput,
-                                             loss=step_loss,
-                                             health=health_rec):
-                            # flushed: land the TensorBoard curves at the
-                            # same cadence (ISSUE 2 satellite)
-                            writer.flush()
-                    if plan is not None:
-                        plan.maybe_sigterm(global_step)
-                        # elastic-resize drill (ISSUE 11): record the target
-                        # device count where the supervisor will look for
-                        # it, then exit through the same path an operator
-                        # request takes
-                        chaos_devices = plan.maybe_resize(global_step)
-                        if chaos_devices is not None:
-                            if config.telemetry_dir:
-                                write_resize_request(
-                                    config.telemetry_dir,
-                                    devices=chaos_devices or None,
+                        on_health_stride = bool(
+                            config.health_stride
+                            and (global_step - 1) % config.health_stride == 0
+                        )
+                        if (telemetry is not None
+                                and telemetry.timer.fence_due(global_step)):
+                            # stride-gated device fence: off-stride steps stay
+                            # fully async (the overhead contract)
+                            with tracer.span("fence", detail=True):
+                                telemetry.timer.maybe_fence(
+                                    global_step, metrics["loss"],
+                                    comm_pre=gs_pre, comm_post=gs_post,
                                 )
-                            resize.trigger()
-                        if plan.maybe_collapse(global_step):
-                            # collapse drill (ISSUE 13): crush the key
-                            # encoder to a constant-feature tree, EVERY
-                            # step from here on — the in-step EMA would
-                            # heal a one-shot crush within one step
-                            from moco_tpu.telemetry.health import (
-                                crush_key_params,
-                            )
+                        if plan is not None and plan.maybe_nan(global_step):
+                            # emulate a real divergence end-to-end: the NaN flows
+                            # through the same metrics dict the sentinel/meters see
+                            metrics = dict(metrics, loss=float("nan"))
+                        if sentinel is not None or collapse is not None:
+                            # both read a device value of the step BEFORE
+                            # (one-step lag): the wait for that step is here
+                            with tracer.span("sentinel", detail=True):
+                                if sentinel is not None:
+                                    sentinel.observe(global_step,
+                                                     metrics["loss"],
+                                                     pos=(epoch, i))
+                                if collapse is not None:
+                                    obs = {"logit_margin": logit_margin,
+                                           "acc1": metrics.get("acc1")}
+                                    if on_health_stride:
+                                        # stride-gated diagnostics are real
+                                        # only on stride steps; feeding the
+                                        # off-stride zeros would read as
+                                        # instant collapse
+                                        obs.update(health_dev)
+                                    collapse.observe(global_step, obs,
+                                                     pos=(epoch, i))
+                        if plan is not None:
+                            # slow-step drill (ISSUE 8): the sleep lands inside
+                            # THIS step's timer window, so the anomaly detector
+                            # sees a real step_s blowout end-to-end
+                            plan.maybe_slow(global_step)
+                        watchdog.beat(global_step)
+                        d_fail = getattr(dataset, "decode_failures", 0)
+                        d_total = getattr(dataset, "decode_total", 0)
+                        # per-host fault signals (SIGTERM flag, decode counters)
+                        # must be ACTED on identically everywhere: one host
+                        # raising or breaking alone leaves the rest hung in the
+                        # next collective. Multi-host runs agree on them at a
+                        # fixed step cadence; single-host acts immediately.
+                        # refresh the resize flag from the trigger file (time-
+                        # gated; SIGUSR2 needs no poll) before the pod sync so
+                        # every host folds the same observation
+                        resize.poll()
+                        preempt_agreed = False
+                        resize_agreed = False
+                        abort_fail, abort_total = d_fail, d_total
+                        if n_procs > 1:
+                            abort_fail = abort_total = 0
+                            if (config.resilience_sync_steps > 0 and
+                                    global_step % config.resilience_sync_steps == 0):
+                                from jax.experimental import multihost_utils
 
-                            state = state.replace(
-                                params_k=crush_key_params(state.params_k))
-                        # process-level faults (ISSUE 4): SIGKILL-grade death
-                        # and wedged-collective freeze — both invisible to
-                        # the in-process handlers, recoverable only by the
-                        # out-of-process supervisor. After on_step, so the
-                        # heartbeat's last beat records this step.
-                        plan.maybe_kill(global_step)
-                        plan.maybe_freeze(global_step)
-                    if preempt_agreed or (n_procs == 1 and preempt.triggered):
-                        # finish-the-step-then-exit: the emergency checkpoint
-                        # (a COLLECTIVE save) lands after the loop, at a step
-                        # every host agrees on — a signaled host breaking by
-                        # itself would leave the others in a hung collective
-                        preempted = True
-                        done = True
-                        break
-                    if resize_agreed or (n_procs == 1 and resize.triggered):
-                        # same finish-the-step-then-exit shape as preemption,
-                        # but the exit code says "relaunch me onto a NEW
-                        # mesh" (EXIT_RESIZE) instead of "same argv"
-                        resized = True
-                        done = True
-                        break
-                    if global_step >= total_steps:
-                        done = True
-                        break
+                                agg = multihost_utils.process_allgather(
+                                    np.asarray(
+                                        [int(preempt.triggered), d_fail, d_total,
+                                         int(resize.triggered)],
+                                        np.int64,
+                                    )
+                                )
+                                preempt_agreed = bool(agg[:, 0].max())
+                                resize_agreed = bool(agg[:, 3].max())
+                                abort_fail = int(agg[:, 1].sum())
+                                abort_total = int(agg[:, 2].sum())
+                                if telemetry is not None:
+                                    # pod telemetry piggybacks on this already-
+                                    # synchronizing cadence: one extra small
+                                    # allgather, no new sync points; process 0
+                                    # folds the matrix into a `pod` record
+                                    telemetry.pod_record(
+                                        global_step,
+                                        multihost_utils.process_allgather(
+                                            telemetry.pod_vector()
+                                        ),
+                                    )
+                        if (
+                            config.decode_abort_rate
+                            and abort_total >= config.batch_size
+                            and abort_fail / abort_total > config.decode_abort_rate
+                        ):
+                            raise DataQualityError(
+                                f"decode-failure rate {abort_fail}/{abort_total} = "
+                                f"{abort_fail / abort_total:.1%} exceeds "
+                                f"decode_abort_rate={config.decode_abort_rate:.1%}: "
+                                "training on zero canvases would silently waste "
+                                "the run"
+                            )
+                        step_loss = None  # host-synced loss, when printing pulls it
+                        if i % config.print_freq == 0:
+                            # pull metrics (host sync) only when printing
+                            with tracer.span("loss_readback", detail=True):
+                                last_metrics = {k: float(v)
+                                                for k, v in metrics.items()}
+                            step_loss = last_metrics["loss"]
+                            if config.debug_nans and not np.isfinite(last_metrics["loss"]):
+                                raise FloatingPointError(
+                                    f"non-finite loss {last_metrics['loss']} at step {global_step}"
+                                )
+                            losses.update(last_metrics["loss"], config.batch_size)
+                            top1.update(last_metrics.get("acc1", 0.0), config.batch_size)
+                            top5.update(last_metrics.get("acc5", 0.0), config.batch_size)
+                            decode_fail.update(d_fail, d_total)
+                            progress.display(i)
+                            writer.write(
+                                global_step,
+                                dict(
+                                    last_metrics,
+                                    # per-step line reports the ROLLING rate (the
+                                    # cumulative one drags the compile stall
+                                    # through the whole epoch); the epoch summary
+                                    # below stays cumulative
+                                    imgs_per_sec=throughput.rolling_imgs_per_sec,
+                                    imgs_per_sec_per_chip=(
+                                        throughput.rolling_imgs_per_sec
+                                        / max(n_chips, 1)
+                                    ),
+                                    decode_failures=d_fail,
+                                    decode_failure_rate=decode_fail.rate,
+                                ),
+                            )
+                        throughput.update(config.batch_size)
+                        batch_time.update(time.perf_counter() - end)
+                        end = time.perf_counter()
+                        health_rec = None
+                        if telemetry is not None and on_health_stride:
+                            # health block for the step record (ISSUE 13):
+                            # pulled to host only on health-stride steps, as
+                            # ONE batched transfer — per-scalar float() would
+                            # pay a device→host round trip each × a dozen
+                            # scalars. Keys drop the h_ prefix — obsd rules
+                            # address them as health:<key>.
+                            pull = dict(health_dev)
+                            if logit_margin is not None:
+                                pull["_logit_margin"] = logit_margin
+                                pull["_neg_sim"] = neg_sim
+                                pull["_pos_sim"] = metrics["pos_sim"]
+                                pull["_acc1"] = metrics["acc1"]
+                            with tracer.span("loss_readback", detail=True):
+                                host = jax.device_get(pull)
+                            health_rec = {
+                                k[2:]: round(float(v), 6)
+                                for k, v in host.items()
+                                if k.startswith("h_")
+                            }
+                            if logit_margin is not None:
+                                health_rec["logit_margin"] = round(
+                                    float(host["_logit_margin"]), 6)
+                                health_rec["neg_sim"] = round(
+                                    float(host["_neg_sim"]), 6)
+                                health_rec["pos_sim"] = round(
+                                    float(host["_pos_sim"]), 6)
+                                health_rec["acc1"] = round(
+                                    float(host["_acc1"]), 4)
+                        if telemetry is not None:
+                            phases = telemetry.timer.finish_step()
+                            with tracer.span("telemetry", detail=True):
+                                flushed = telemetry.on_step(
+                                    global_step, phases, throughput,
+                                    loss=step_loss, health=health_rec)
+                            if flushed:
+                                # flushed: land the TensorBoard curves at the
+                                # same cadence (ISSUE 2 satellite)
+                                writer.flush()
+                        if plan is not None:
+                            plan.maybe_sigterm(global_step)
+                            # elastic-resize drill (ISSUE 11): record the target
+                            # device count where the supervisor will look for
+                            # it, then exit through the same path an operator
+                            # request takes
+                            chaos_devices = plan.maybe_resize(global_step)
+                            if chaos_devices is not None:
+                                if config.telemetry_dir:
+                                    write_resize_request(
+                                        config.telemetry_dir,
+                                        devices=chaos_devices or None,
+                                    )
+                                resize.trigger()
+                            if plan.maybe_collapse(global_step):
+                                # collapse drill (ISSUE 13): crush the key
+                                # encoder to a constant-feature tree, EVERY
+                                # step from here on — the in-step EMA would
+                                # heal a one-shot crush within one step
+                                from moco_tpu.telemetry.health import (
+                                    crush_key_params,
+                                )
+
+                                state = state.replace(
+                                    params_k=crush_key_params(state.params_k))
+                            # process-level faults (ISSUE 4): SIGKILL-grade death
+                            # and wedged-collective freeze — both invisible to
+                            # the in-process handlers, recoverable only by the
+                            # out-of-process supervisor. After on_step, so the
+                            # heartbeat's last beat records this step.
+                            plan.maybe_kill(global_step)
+                            plan.maybe_freeze(global_step)
+                        if preempt_agreed or (n_procs == 1 and preempt.triggered):
+                            # finish-the-step-then-exit: the emergency checkpoint
+                            # (a COLLECTIVE save) lands after the loop, at a step
+                            # every host agrees on — a signaled host breaking by
+                            # itself would leave the others in a hung collective
+                            preempted = True
+                            done = True
+                            break
+                        if resize_agreed or (n_procs == 1 and resize.triggered):
+                            # same finish-the-step-then-exit shape as preemption,
+                            # but the exit code says "relaunch me onto a NEW
+                            # mesh" (EXIT_RESIZE) instead of "same argv"
+                            resized = True
+                            done = True
+                            break
+                        if global_step >= total_steps:
+                            done = True
+                            break
             finally:
                 # unblock the prefetch thread on early break; quietly — a
                 # pending staged-read error raised here would replace an
@@ -1053,9 +1106,11 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                 # call it. Async (wait=False): serialization overlaps the
                 # next epoch's compute; the integrity manifest is deferred to
                 # the next save / finalize_checkpoints
-                save_checkpoint(mgr, state, global_step, wait=False,
-                                position=(epoch + 1, 0), devices=n_chips,
-                                sharding=config.sharding)
+                with tracer.span("checkpoint", cat="checkpoint",
+                                 step=global_step):
+                    save_checkpoint(mgr, state, global_step, wait=False,
+                                    position=(epoch + 1, 0), devices=n_chips,
+                                    sharding=config.sharding)
         if sentinel is not None:
             # the final step's loss is still pending (one-step lag)
             sentinel.flush()

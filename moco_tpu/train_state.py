@@ -12,6 +12,7 @@ reference's torch.save of the full state_dict.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -45,6 +46,11 @@ class TrainState:
     gradsync: Any = dataclasses.field(default_factory=dict)
 
 
+def no_span(name: str):
+    """The default of `create_train_state(span=...)`: no tracer at hand."""
+    return contextlib.nullcontext()
+
+
 def create_train_state(
     rng: jax.Array,
     model,
@@ -53,30 +59,35 @@ def create_train_state(
     num_negatives: int | None,
     embed_dim: int,
     queue_dtype=jnp.float32,
+    span=no_span,
 ) -> TrainState:
     """Initialise q, copy q → k (the reference's param copy,
     `moco/builder.py:≈L20-24` — k starts identical to q), build queue.
 
     `input_shape` is a per-device-shaped dummy `[local_b, H, W, C]`; init is
-    shape-driven only.
+    shape-driven only. `span(name)` opens the driver's set-up span of that
+    name (`model_init`, `opt_init`: ISSUE 25).
     """
     init_key, queue_key, state_key = jax.random.split(rng, 3)
-    variables = model.init(init_key, jnp.zeros(input_shape, jnp.float32), train=False)
-    params_q = variables["params"]
-    batch_stats_q = variables.get("batch_stats", {})
-    params_k = jax.tree.map(jnp.copy, params_q)
-    batch_stats_k = jax.tree.map(jnp.copy, batch_stats_q)
-    if num_negatives is not None:
-        queue, queue_ptr = init_queue(queue_key, num_negatives, embed_dim, queue_dtype)
-    else:
-        queue, queue_ptr = None, None
+    with span("model_init"):
+        variables = model.init(init_key, jnp.zeros(input_shape, jnp.float32), train=False)
+        params_q = variables["params"]
+        batch_stats_q = variables.get("batch_stats", {})
+        params_k = jax.tree.map(jnp.copy, params_q)
+        batch_stats_k = jax.tree.map(jnp.copy, batch_stats_q)
+        if num_negatives is not None:
+            queue, queue_ptr = init_queue(queue_key, num_negatives, embed_dim, queue_dtype)
+        else:
+            queue, queue_ptr = None, None
+    with span("opt_init"):
+        opt_state = tx.init(params_q)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params_q=params_q,
         params_k=params_k,
         batch_stats_q=batch_stats_q,
         batch_stats_k=batch_stats_k,
-        opt_state=tx.init(params_q),
+        opt_state=opt_state,
         queue=queue,
         queue_ptr=queue_ptr,
         rng=state_key,

@@ -39,7 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from moco_tpu.config import PretrainConfig
 from moco_tpu.models import build_resnet
-from moco_tpu.telemetry import health
+from moco_tpu.telemetry import health, scopes
 from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import (
     contrastive_accuracy,
@@ -173,8 +173,9 @@ def build_fused_step(step_fn, two_crops_fn, data_key):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def fused_step(state, imgs_u8, extents, step):
-        key = jax.random.fold_in(data_key, step)
-        im_q, im_k = two_crops_fn(imgs_u8, key, extents)
+        with jax.named_scope(scopes.AUG):
+            key = jax.random.fold_in(data_key, step)
+            im_q, im_k = two_crops_fn(imgs_u8, key, extents)
         return step_fn(state, im_q, im_k)
 
     return fused_step
@@ -193,12 +194,13 @@ def _build_key_path(config: PretrainConfig, model):
     chunks = int(getattr(config, "collective_chunks", 1))
 
     def key_path(params_k, stats_k, im_k, key):
-        if config.shuffle_mode == "ring":
-            from moco_tpu.parallel.collectives import ring_shuffle
+        with jax.named_scope(scopes.SHUFFLE_BN):
+            if config.shuffle_mode == "ring":
+                from moco_tpu.parallel.collectives import ring_shuffle
 
-            im_k_shuf = ring_shuffle(im_k, DATA_AXIS)
-        else:
-            im_k_shuf, perm = batch_shuffle(im_k, key, DATA_AXIS, chunks)
+                im_k_shuf = ring_shuffle(im_k, DATA_AXIS)
+            else:
+                im_k_shuf, perm = batch_shuffle(im_k, key, DATA_AXIS, chunks)
         k, mut_k = model.apply(
             {"params": params_k, "batch_stats": stats_k},
             im_k_shuf,
@@ -206,10 +208,11 @@ def _build_key_path(config: PretrainConfig, model):
             mutable=["batch_stats"],
         )
         k = l2_normalize(k)
-        if config.shuffle_mode == "ring":
-            k = ring_shuffle(k, DATA_AXIS, inverse=True)
-        else:
-            k = batch_unshuffle(k, perm, DATA_AXIS, chunks)
+        with jax.named_scope(scopes.KEY_GATHER):
+            if config.shuffle_mode == "ring":
+                k = ring_shuffle(k, DATA_AXIS, inverse=True)
+            else:
+                k = batch_unshuffle(k, perm, DATA_AXIS, chunks)
         k = lax.stop_gradient(k)  # the reference's no_grad key path
         return k, mut_k["batch_stats"]
 
@@ -229,10 +232,16 @@ def _build_query_loss(config: PretrainConfig, model, temperature: float):
             mutable=["batch_stats"],
         )
         q = l2_normalize(q)
-        logits, labels = infonce_logits(q, k, queue, temperature)
+        # innermost recognised scope wins in the trace's reduction: the
+        # logits against the queue and the loss (forward and, through the
+        # enclosing value_and_grad, backward) are `loss_queue`'s, not the
+        # encoder's
+        with jax.named_scope(scopes.LOSS_QUEUE):
+            logits, labels = infonce_logits(q, k, queue, temperature)
+            loss = softmax_cross_entropy(logits, labels)
         # q rides the aux for the health diagnostics (ISSUE 13) — already
         # computed, and DCE'd by XLA wherever nothing consumes it
-        return softmax_cross_entropy(logits, labels), (
+        return loss, (
             mut_q["batch_stats"],
             logits,
             labels,
@@ -324,43 +333,50 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
 
     def spmd_region(params_q, params_k, stats_q, stats_k, queue, gs_state,
                     im_q, im_k, key, step):
-        k, new_stats_k_local = key_path(params_k, stats_k, im_k, key)
+        with jax.named_scope(scopes.K_FWD):
+            k, new_stats_k_local = key_path(params_k, stats_k, im_k, key)
 
         def loss_fn(pq):
             return query_loss(pq, stats_q, im_q, k, queue)
 
         # w.r.t. the device-local view: the grads come out per-device and
         # gradsync's reduce below is the only one (collectives.device_local)
-        (loss, (new_stats_q, logits, labels, q)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(device_local(params_q, DATA_AXIS))
+        with jax.named_scope(scopes.Q_FWD_BWD):
+            (loss, (new_stats_q, logits, labels, q)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(device_local(params_q, DATA_AXIS))
         # DDP-equivalent gradient sync (mean over the data axis) through the
         # configured strategy; demo's replicated merge happens outside
-        payload, gs_new, gs_probe = gradsync.region_reduce(grads, gs_state, step)
+        with jax.named_scope(scopes.OPT_EMA), jax.named_scope(scopes.GRAD_SYNC):
+            payload, gs_new, gs_probe = gradsync.region_reduce(
+                grads, gs_state, step)
         # Running BN stats: averaged across devices so replicas stay
         # bit-identical (replaces DDP broadcast_buffers, SURVEY §2.2 note).
-        new_stats_q = lax.pmean(new_stats_q, DATA_AXIS)
-        new_stats_k = lax.pmean(new_stats_k_local, DATA_AXIS)
-        acc1, acc5 = contrastive_accuracy(logits, labels)
-        # positive-pair cosine alignment (column 0 is q·k⁺/T): the cheapest
-        # honest learning signal — only aug-invariance optimization moves
-        # it, so a silently frozen encoder leaves it at its init value
-        # while loss/acc metrics can still look plausible against a
-        # frozen-feature queue (measured r5)
-        pos_sim = jnp.mean(logits[:, 0]) * temperature
-        # the contrast the loss works with (ISSUE 13 standard metrics,
-        # popped by the driver like the gs_comm_* probes): a margin
-        # pinned at ~0 is collapse or a degenerate queue
-        neg_sim = health.neg_sim_mean(logits, labels, temperature)
-        metrics = {"loss": loss, "acc1": acc1, "acc5": acc5,
-                   "pos_sim": pos_sim, "neg_sim": neg_sim,
-                   "logit_margin": pos_sim - neg_sim}
-        if config.health_stride:
-            # stride-gated collapse diagnostics (ISSUE 13): they join the
-            # SAME metrics pmean below — no new collectives
-            metrics.update(health.region_health(
-                q, k, grads, step, config.health_stride))
-        metrics = lax.pmean(metrics, DATA_AXIS)
+        with jax.named_scope(scopes.Q_FWD_BWD):
+            new_stats_q = lax.pmean(new_stats_q, DATA_AXIS)
+        with jax.named_scope(scopes.K_FWD):
+            new_stats_k = lax.pmean(new_stats_k_local, DATA_AXIS)
+        with jax.named_scope(scopes.LOSS_QUEUE):
+            acc1, acc5 = contrastive_accuracy(logits, labels)
+            # positive-pair cosine alignment (column 0 is q·k⁺/T): the
+            # cheapest honest learning signal — only aug-invariance
+            # optimization moves it, so a silently frozen encoder leaves it
+            # at its init value while loss/acc metrics can still look
+            # plausible against a frozen-feature queue (measured r5)
+            pos_sim = jnp.mean(logits[:, 0]) * temperature
+            # the contrast the loss works with (ISSUE 13 standard metrics,
+            # popped by the driver like the gs_comm_* probes): a margin
+            # pinned at ~0 is collapse or a degenerate queue
+            neg_sim = health.neg_sim_mean(logits, labels, temperature)
+            metrics = {"loss": loss, "acc1": acc1, "acc5": acc5,
+                       "pos_sim": pos_sim, "neg_sim": neg_sim,
+                       "logit_margin": pos_sim - neg_sim}
+            if config.health_stride:
+                # stride-gated collapse diagnostics (ISSUE 13): they join
+                # the SAME metrics pmean below — no new collectives
+                metrics.update(health.region_health(
+                    q, k, grads, step, config.health_stride))
+            metrics = lax.pmean(metrics, DATA_AXIS)
         return payload, gs_new, gs_probe, k, new_stats_q, new_stats_k, metrics
 
     region = jax.shard_map(
@@ -373,18 +389,23 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
     )
 
     def train_step(state: TrainState, im_q, im_k):
-        shuffle_key = jax.random.fold_in(state.rng, state.step)
-        if config.momentum_ramp:
-            m = momentum_schedule(config.momentum_ema, state.step, total_steps)
-        else:
-            m = config.momentum_ema
-        # EMA BEFORE the key forward, every step (`moco/builder.py:≈L120-124`)
-        params_k = ema_update(state.params_k, state.params_q, m)
-        # barrier: without it XLA interleaves the ~163 per-leaf EMA fusions
-        # with the optimizer's per-leaf fusions and the VMEM prefetcher,
-        # costing ~20 ms/step of copy stalls on the v5e (measured r2: the
-        # update phase alone is 24.8 ms interleaved vs 5.0 ms fenced)
-        params_k = lax.optimization_barrier(params_k)
+        with jax.named_scope(scopes.K_FWD):
+            shuffle_key = jax.random.fold_in(state.rng, state.step)
+        with jax.named_scope(scopes.OPT_EMA):
+            if config.momentum_ramp:
+                m = momentum_schedule(config.momentum_ema, state.step,
+                                      total_steps)
+            else:
+                m = config.momentum_ema
+            # EMA BEFORE the key forward, every step
+            # (`moco/builder.py:≈L120-124`)
+            params_k = ema_update(state.params_k, state.params_q, m)
+            # barrier: without it XLA interleaves the ~163 per-leaf EMA
+            # fusions with the optimizer's per-leaf fusions and the VMEM
+            # prefetcher, costing ~20 ms/step of copy stalls on the v5e
+            # (measured r2: the update phase alone is 24.8 ms interleaved
+            # vs 5.0 ms fenced)
+            params_k = lax.optimization_barrier(params_k)
         payload, gs_new, gs_probe, k_global, stats_q, stats_k, metrics = region(
             state.params_q,
             params_k,
@@ -400,30 +421,39 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
         # demo's sparse merge (a no-op for the dense modes) lives at the
         # outer jit level: replicated values derived from gathered ones
         # cannot be typed replicated inside the region (collectives.py note)
-        grads = gradsync.finalize(payload, state.step)
-        grads = lax.optimization_barrier(grads)  # fence bwd from the update phase
-        updates, opt_state = tx.update(grads, state.opt_state, state.params_q)
-        params_q = optax.apply_updates(state.params_q, updates)
-        # enqueue AFTER the logits (`moco/builder.py:≈L160-163`)
-        queue, queue_ptr = dequeue_and_enqueue(
-            state.queue, state.queue_ptr, k_global
-        )
+        with jax.named_scope(scopes.OPT_EMA):
+            with jax.named_scope(scopes.GRAD_SYNC):
+                grads = gradsync.finalize(payload, state.step)
+            grads = lax.optimization_barrier(grads)  # fence bwd from the update phase
+            updates, opt_state = tx.update(grads, state.opt_state, state.params_q)
+            params_q = optax.apply_updates(state.params_q, updates)
+            lr = sched(state.step)
+            with jax.named_scope(scopes.GRAD_SYNC):
+                gs_post = gradsync.probe_post(grads)
+            next_step = state.step + 1
+        with jax.named_scope(scopes.LOSS_QUEUE):
+            # enqueue AFTER the logits (`moco/builder.py:≈L160-163`)
+            queue, queue_ptr = dequeue_and_enqueue(
+                state.queue, state.queue_ptr, k_global
+            )
         metrics = dict(
-            metrics, lr=sched(state.step), queue_ptr=queue_ptr,
+            metrics, lr=lr, queue_ptr=queue_ptr,
             # comm-phase probes (telemetry/timing.py): drained in order by
             # the stride-gated fence, popped by the driver before display
-            gs_comm_pre=gs_probe, gs_comm_post=gradsync.probe_post(grads),
+            gs_comm_pre=gs_probe, gs_comm_post=gs_post,
         )
         if config.health_stride:
             # replicated-state diagnostics (ISSUE 13) live at the outer
             # jit level where queue/params are replicated: no collective
-            metrics.update(health.queue_health(
-                state.queue, state.step, config.batch_size,
-                config.health_stride))
-            metrics.update(health.param_drift(
-                state.params_q, params_k, state.step, config.health_stride))
+            with jax.named_scope(scopes.LOSS_QUEUE):
+                metrics.update(health.queue_health(
+                    state.queue, state.step, config.batch_size,
+                    config.health_stride))
+                metrics.update(health.param_drift(
+                    state.params_q, params_k, state.step,
+                    config.health_stride))
         new_state = state.replace(
-            step=state.step + 1,
+            step=next_step,
             params_q=params_q,
             params_k=params_k,
             batch_stats_q=stats_q,

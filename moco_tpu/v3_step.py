@@ -32,8 +32,8 @@ from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import l2_normalize, v3_contrastive_loss
 from moco_tpu.parallel.collectives import all_gather_batch, device_local
 from moco_tpu.parallel.mesh import DATA_AXIS
-from moco_tpu.telemetry import health
-from moco_tpu.train_state import TrainState
+from moco_tpu.telemetry import health, scopes
+from moco_tpu.train_state import TrainState, no_span
 
 PREDICTOR_KEY = "predictor"
 
@@ -77,24 +77,30 @@ def patch_embed_trainable_mask(params) -> Any:
 
 
 def create_v3_train_state(
-    rng: jax.Array, model: V3Model, tx: optax.GradientTransformation, input_shape
+    rng: jax.Array, model: V3Model, tx: optax.GradientTransformation, input_shape,
+    span=no_span,
 ) -> TrainState:
-    """Init query model (with predictor); key tree = encoder subtree copy."""
+    """Init query model (with predictor); key tree = encoder subtree copy.
+    `span(name)` opens the driver's set-up span of that name, as in
+    `create_train_state`."""
     init_key, state_key = jax.random.split(rng)
-    variables = model.init(
-        init_key, jnp.zeros(input_shape, jnp.float32), train=False, predict=True
-    )
-    params_q = variables["params"]
-    batch_stats_q = variables.get("batch_stats", {})
-    params_k = jax.tree.map(jnp.copy, encoder_subtree(params_q))
-    batch_stats_k = jax.tree.map(jnp.copy, encoder_subtree(batch_stats_q))
+    with span("model_init"):
+        variables = model.init(
+            init_key, jnp.zeros(input_shape, jnp.float32), train=False, predict=True
+        )
+        params_q = variables["params"]
+        batch_stats_q = variables.get("batch_stats", {})
+        params_k = jax.tree.map(jnp.copy, encoder_subtree(params_q))
+        batch_stats_k = jax.tree.map(jnp.copy, encoder_subtree(batch_stats_q))
+    with span("opt_init"):
+        opt_state = tx.init(params_q)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params_q=params_q,
         params_k=params_k,
         batch_stats_q=batch_stats_q,
         batch_stats_k=batch_stats_k,
-        opt_state=tx.init(params_q),
+        opt_state=opt_state,
         queue=None,
         queue_ptr=None,
         rng=state_key,
@@ -144,8 +150,13 @@ def _build_query_loss(model: V3Model, temperature: float,
     def query_loss(pq, stats_q, x1, x2, k1, k2):
         q1, s = apply(pq, stats_q, x1, predict=True)
         q2, s = apply(pq, s, x2, predict=True)
-        loss = v3_contrastive_loss(q1, k2, temperature, batch_axis, chunks) + \
-               v3_contrastive_loss(q2, k1, temperature, batch_axis, chunks)
+        # innermost recognised scope wins in the trace's reduction: the
+        # symmetric loss (with the key gathers inside it, and through the
+        # enclosing value_and_grad its backward) is `loss_queue`'s, not the
+        # encoder's
+        with jax.named_scope(scopes.LOSS_QUEUE):
+            loss = v3_contrastive_loss(q1, k2, temperature, batch_axis, chunks) + \
+                   v3_contrastive_loss(q2, k1, temperature, batch_axis, chunks)
         return loss, (s, q1)
 
     return query_loss
@@ -234,45 +245,56 @@ def build_v3_train_step(
         if plan is not None:
             # all-gather-on-use: the full weights exist only inside the
             # region's forward/backward window
-            params_q = plan.gather(params_q, q_axes)
-            params_k = plan.gather(params_k, k_axes)
-        k1, k2, stats_k = momentum_keys(params_k, stats_k, x1, x2)
+            with jax.named_scope(scopes.Q_FWD_BWD):
+                params_q = plan.gather(params_q, q_axes)
+            with jax.named_scope(scopes.K_FWD):
+                params_k = plan.gather(params_k, k_axes)
+        with jax.named_scope(scopes.K_FWD):
+            k1, k2, stats_k = momentum_keys(params_k, stats_k, x1, x2)
 
         def loss_fn(pq):
             return query_loss(pq, stats_q, x1, x2, k1, k2)
 
         # w.r.t. the device-local view: the grads come out per-device and
         # gradsync's reduce below is the only one (collectives.device_local)
-        (loss, (new_stats_q, q1)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(device_local(params_q, batch_axis))
-        payload, gs_new, gs_probe = gradsync.region_reduce(grads, gs_state, step)
-        if plan is not None and gradsync.mode != "demo":
-            # reduce-scatter: the reduced full grads leave the region as
-            # this device's shard (demo's sparse payload merges outside)
-            payload = plan.scatter(payload, q_axes)
-        new_stats_q = lax.pmean(new_stats_q, batch_axis)
-        new_stats_k = lax.pmean(stats_k, batch_axis)
-        # monitoring: in-batch top-1 for the q1·k2 direction
-        k2_all = all_gather_batch(k2, batch_axis, chunks)
-        logits = jnp.einsum("nc,mc->nm", q1, k2_all, preferred_element_type=jnp.float32)
-        labels = jnp.arange(q1.shape[0]) + batch_axis_index(batch_axis) * q1.shape[0]
-        acc1 = 100.0 * jnp.mean(jnp.argmax(logits, axis=-1) == labels)
-        # positive-pair alignment, same frozen-encoder detector as the
-        # v1/v2 step's pos_sim (q1/k2 are L2-normalized, so the row-dot is
-        # the cosine of the local positive pair)
-        pos_sim = jnp.mean(jnp.sum(q1 * k2, axis=-1))
-        # ISSUE 13 standard metrics: the monitoring logits are raw
-        # cosines (no /T), so neg_sim_mean's ×T runs at T=1 here
-        neg_sim = health.neg_sim_mean(logits, labels, 1.0)
-        metrics = {"loss": loss, "acc1": acc1, "pos_sim": pos_sim,
-                   "neg_sim": neg_sim, "logit_margin": pos_sim - neg_sim}
-        if config.health_stride:
-            # stride-gated collapse diagnostics (queue-free v3: no queue
-            # stats) riding the SAME metrics pmean — no new collectives
-            metrics.update(health.region_health(
-                q1, k2, grads, step, config.health_stride))
-        metrics = lax.pmean(metrics, batch_axis)
+        with jax.named_scope(scopes.Q_FWD_BWD):
+            (loss, (new_stats_q, q1)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(device_local(params_q, batch_axis))
+        with jax.named_scope(scopes.OPT_EMA), jax.named_scope(scopes.GRAD_SYNC):
+            payload, gs_new, gs_probe = gradsync.region_reduce(
+                grads, gs_state, step)
+            if plan is not None and gradsync.mode != "demo":
+                # reduce-scatter: the reduced full grads leave the region as
+                # this device's shard (demo's sparse payload merges outside)
+                payload = plan.scatter(payload, q_axes)
+        with jax.named_scope(scopes.Q_FWD_BWD):
+            new_stats_q = lax.pmean(new_stats_q, batch_axis)
+        with jax.named_scope(scopes.K_FWD):
+            new_stats_k = lax.pmean(stats_k, batch_axis)
+        with jax.named_scope(scopes.LOSS_QUEUE):
+            # monitoring: in-batch top-1 for the q1·k2 direction
+            with jax.named_scope(scopes.KEY_GATHER):
+                k2_all = all_gather_batch(k2, batch_axis, chunks)
+            logits = jnp.einsum("nc,mc->nm", q1, k2_all, preferred_element_type=jnp.float32)
+            labels = jnp.arange(q1.shape[0]) + batch_axis_index(batch_axis) * q1.shape[0]
+            acc1 = 100.0 * jnp.mean(jnp.argmax(logits, axis=-1) == labels)
+            # positive-pair alignment, same frozen-encoder detector as the
+            # v1/v2 step's pos_sim (q1/k2 are L2-normalized, so the row-dot
+            # is the cosine of the local positive pair)
+            pos_sim = jnp.mean(jnp.sum(q1 * k2, axis=-1))
+            # ISSUE 13 standard metrics: the monitoring logits are raw
+            # cosines (no /T), so neg_sim_mean's ×T runs at T=1 here
+            neg_sim = health.neg_sim_mean(logits, labels, 1.0)
+            metrics = {"loss": loss, "acc1": acc1, "pos_sim": pos_sim,
+                       "neg_sim": neg_sim, "logit_margin": pos_sim - neg_sim}
+            if config.health_stride:
+                # stride-gated collapse diagnostics (queue-free v3: no
+                # queue stats) riding the SAME metrics pmean — no new
+                # collectives
+                metrics.update(health.region_health(
+                    q1, k2, grads, step, config.health_stride))
+            metrics = lax.pmean(metrics, batch_axis)
         return payload, gs_new, gs_probe, new_stats_q, new_stats_k, metrics
 
     if plan is None:
@@ -295,31 +317,41 @@ def build_v3_train_step(
     )
 
     def train_step(state: TrainState, x1, x2):
-        if config.momentum_ramp:
-            m = momentum_schedule(config.momentum_ema, state.step, total_steps)
-        else:
-            m = config.momentum_ema
-        params_k = ema_update(state.params_k, encoder_subtree(state.params_q), m)
+        with jax.named_scope(scopes.OPT_EMA):
+            if config.momentum_ramp:
+                m = momentum_schedule(config.momentum_ema, state.step,
+                                      total_steps)
+            else:
+                m = config.momentum_ema
+            params_k = ema_update(state.params_k,
+                                  encoder_subtree(state.params_q), m)
         payload, gs_new, gs_probe, stats_q, stats_k, metrics = region(
             state.params_q, params_k, state.batch_stats_q, state.batch_stats_k,
             state.gradsync, x1, x2, state.step,
         )
-        grads = gradsync.finalize(payload, state.step)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params_q)
-        params_q = optax.apply_updates(state.params_q, updates)
+        with jax.named_scope(scopes.OPT_EMA):
+            with jax.named_scope(scopes.GRAD_SYNC):
+                grads = gradsync.finalize(payload, state.step)
+            updates, opt_state = tx.update(grads, state.opt_state, state.params_q)
+            params_q = optax.apply_updates(state.params_q, updates)
+            lr = sched(state.step)
+            with jax.named_scope(scopes.GRAD_SYNC):
+                gs_post = gradsync.probe_post(grads)
+            next_step = state.step + 1
         metrics = dict(
-            metrics, lr=sched(state.step), momentum=m,
-            gs_comm_pre=gs_probe, gs_comm_post=gradsync.probe_post(grads),
+            metrics, lr=lr, momentum=m,
+            gs_comm_pre=gs_probe, gs_comm_post=gs_post,
         )
         if config.health_stride:
             # q↔k drift over the EMA-covered subtree (the predictor is
             # query-only); outer level, replicated: no collective
-            metrics.update(health.param_drift(
-                encoder_subtree(state.params_q), params_k, state.step,
-                config.health_stride))
+            with jax.named_scope(scopes.LOSS_QUEUE):
+                metrics.update(health.param_drift(
+                    encoder_subtree(state.params_q), params_k, state.step,
+                    config.health_stride))
         return (
             state.replace(
-                step=state.step + 1,
+                step=next_step,
                 params_q=params_q,
                 params_k=params_k,
                 batch_stats_q=stats_q,

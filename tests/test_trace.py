@@ -103,24 +103,45 @@ def test_modes_filter_detail_spans(tmp_path):
 
 
 def test_record_step_emits_phase_children_at_full(tmp_path):
+    """The step tree at `full` is built from REAL spans (ISSUE 25): the
+    driver's open `step` span takes the phases as attrs and its children
+    are the spans that ran inside it, at their own start times; a
+    retroactive record_step (no open step span) has no children."""
     t = Tracer(str(tmp_path), "full")
     phases = {"step_s": 0.1, "data_s": 0.03, "host_s": 0.02,
               "telemetry_s": 0.01, "device_s": 0.05}
-    sid = t.record_step(7, phases, loss=1.5)
+    with t.span("step", cat="step", step=7) as sp:
+        with t.span("data_wait", detail=True):
+            pass
+        with t.span("dispatch", detail=True):
+            pass
+        with t.span("telemetry", detail=True):
+            sid = t.record_step(7, phases, loss=1.5)
+    assert sid == sp.span_id
     t.flush()
     spans = read_spans(tmp_path)
     step = next(s for s in spans if s["cat"] == "step")
-    assert step["span"] == sid
+    assert step["span"] == sid and [s["cat"] for s in spans].count("step") == 1
     assert step["attrs"]["step"] == 7 and step["attrs"]["loss"] == 1.5
-    children = {s["name"]: s for s in spans if s["cat"] == "phase"}
     # device_s is a fenced drain sample, not a wall segment: attr only
-    assert set(children) == {"telemetry", "data", "host"}
-    assert all(c["parent"] == sid for c in children.values())
-    # at `steps` level the children are filtered, the step span remains
+    assert step["attrs"]["device_s"] == 0.05 and step["attrs"]["data_s"] == 0.03
+    children = {s["name"]: s for s in spans if s.get("parent") == sid}
+    assert set(children) == {"data_wait", "dispatch", "telemetry"}
+    assert all(step["t"] <= c["t"] <= step["t"] + step["dur"] + 1e-3
+               for c in children.values())
+    # at `steps` level the children are filtered, the step span remains;
+    # and without an open step span the step is recorded retroactively
     t2 = Tracer(str(tmp_path / "s"), "steps")
-    t2.record_step(8, phases)
+    with t2.span("step", cat="step", step=8):
+        with t2.span("data_wait", detail=True):
+            pass
+        t2.record_step(8, phases)
+    t2.record_step(9, phases)
     t2.flush()
-    assert [s["cat"] for s in read_spans(tmp_path / "s")] == ["step"]
+    recs = read_spans(tmp_path / "s")
+    assert [s["cat"] for s in recs] == ["step", "step"]
+    assert [s["attrs"]["step"] for s in recs] == [8, 9]
+    assert recs[1]["dur"] == pytest.approx(0.1)
 
 
 def test_null_tracer_is_inert():

@@ -180,7 +180,7 @@ def summarize(records: list[dict], skipped: int = 0) -> dict:
     # incidents = events that signal trouble; routine markers the driver
     # emits on purpose (epoch/eval bookkeeping) are reported separately,
     # matching the driver's own `incidents` counter (log_event-routed only)
-    routine = {"epoch_summary", "knn_eval", "grad_sync", "sharding"}
+    routine = {"epoch_summary", "knn_eval", "grad_sync", "sharding", "setup"}
     incidents = {k: v for k, v in events_by_kind.items() if k not in routine}
 
     summary: dict = {
@@ -271,6 +271,30 @@ def summarize(records: list[dict], skipped: int = 0) -> dict:
         input_snaps.append(run_ends[-1]["input"])
     if input_snaps:
         summary["input"] = input_snaps[-1]
+    # the snapshots' exact cumulative counters (ISSUE 25) between the first
+    # and the last: the feed in steady state, the compile stall left out
+    exact = [s for s in input_snaps
+             if "staged_images" in s and "worker_busy_s" in s and "wall_s" in s]
+    if len(exact) >= 2 and exact[-1]["wall_s"] > exact[0]["wall_s"]:
+        first, last = exact[0], exact[-1]
+        window_s = last["wall_s"] - first["wall_s"]
+        summary["input_steady"] = {
+            "seconds": round(window_s, 3),
+            "staged_imgs_per_s": round(
+                (last["staged_images"] - first["staged_images"]) / window_s, 2),
+            "worker_busy_frac": round(
+                (last["worker_busy_s"] - first["worker_busy_s"])
+                / (max(last.get("workers", 1), 1) * window_s), 4),
+        }
+    # compile counters are cumulative too; set-up spans come once per run
+    compiles = [r["compile"] for r in steps + run_ends
+                if isinstance(r.get("compile"), dict)]
+    if compiles:
+        summary["compile"] = compiles[-1]
+    setups = [e["spans"] for e in events
+              if e.get("event") == "setup" and isinstance(e.get("spans"), dict)]
+    if setups:
+        summary["setup_s"] = setups[-1]
     if pods:
         spreads = [
             p["step_s_max"] - p["step_s_min"]
@@ -838,6 +862,31 @@ def render(summary: dict) -> str:
                 f"({100 * inp.get('credit_stall_s', 0) / inp['wall_s']:.1f}% "
                 f"of {inp['wall_s']:.0f} s)"
             )
+        steady = summary.get("input_steady")
+        if steady:
+            lines.append(
+                f"  steady state ({steady['seconds']:.0f} s between the first "
+                f"and last snapshot): {steady['staged_imgs_per_s']:.0f} imgs/s "
+                f"staged · workers busy "
+                f"{100 * steady['worker_busy_frac']:.1f}%"
+            )
+    comp = summary.get("compile")
+    if comp:
+        lines.append(
+            f"compile: {comp.get('n', 0)} programs · backend "
+            f"{comp.get('backend_s', 0):.1f} s · trace+lower "
+            f"{comp.get('trace_lower_s', 0):.1f} s · persistent cache "
+            f"{comp.get('cache_hits', 0)} hit / "
+            f"{comp.get('cache_misses', 0)} miss · step program "
+            f"{comp.get('fused_step_n', 0)}× {comp.get('fused_step_s', 0):.1f} s"
+        )
+    setup = summary.get("setup_s")
+    if setup:
+        lines.append(
+            "set-up: " + " · ".join(
+                f"{name} {secs:.2f} s" for name, secs in
+                sorted(setup.items(), key=lambda kv: -kv[1]))
+        )
     isv = summary.get("input_servers")
     if isv:
         tot = isv.get("totals", {})
