@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any
 
 import flax.struct
@@ -51,6 +52,50 @@ def no_span(name: str):
     return contextlib.nullcontext()
 
 
+def compiled_init(init_fields, tx: optax.GradientTransformation, span=no_span) -> TrainState:
+    """The initial state as the output of compiled programs, not of one small
+    program a primitive (an eager `model.init` of ResNet-50 is 213 of them,
+    compiled again in every run: ISSUE 26). `init_fields()` is the jitted
+    initialiser bound to its arguments; it returns every `TrainState` field
+    but `opt_state`. `tx.init` is a second small program so that `opt_init`
+    stays a span of its own. Each span waits for its outputs (dispatch is
+    asynchronous), so it holds trace, compile and run. Nothing is placed or
+    committed here."""
+    with span("model_init"):
+        fields = jax.block_until_ready(init_fields())
+    with span("opt_init"):
+        opt_state = jax.block_until_ready(jax.jit(tx.init)(fields["params_q"]))
+    return TrainState(opt_state=opt_state, **fields)
+
+
+# everything but the key is static: the model (a flax module hashes by its
+# fields) and the shapes are closed over by the program, and a second call
+# with an equal model finds it in jit's own cache. Under `jax.jit` the dummy
+# forward of `model.init` is dead code: the program is the random draws and
+# the q → k copies.
+@functools.partial(jax.jit, static_argnames=("model", "input_shape", "num_negatives",
+                                             "embed_dim", "queue_dtype"))
+def _init_fields(rng, *, model, input_shape, num_negatives, embed_dim, queue_dtype):
+    init_key, queue_key, state_key = jax.random.split(rng, 3)
+    variables = model.init(init_key, jnp.zeros(input_shape, jnp.float32), train=False)
+    params_q = variables["params"]
+    batch_stats_q = variables.get("batch_stats", {})
+    if num_negatives is not None:
+        queue, queue_ptr = init_queue(queue_key, num_negatives, embed_dim, queue_dtype)
+    else:
+        queue, queue_ptr = None, None
+    return dict(
+        step=jnp.zeros((), jnp.int32),
+        params_q=params_q,
+        params_k=jax.tree.map(jnp.copy, params_q),
+        batch_stats_q=batch_stats_q,
+        batch_stats_k=jax.tree.map(jnp.copy, batch_stats_q),
+        queue=queue,
+        queue_ptr=queue_ptr,
+        rng=state_key,
+    )
+
+
 def create_train_state(
     rng: jax.Array,
     model,
@@ -66,29 +111,10 @@ def create_train_state(
 
     `input_shape` is a per-device-shaped dummy `[local_b, H, W, C]`; init is
     shape-driven only. `span(name)` opens the driver's set-up span of that
-    name (`model_init`, `opt_init`: ISSUE 25).
+    name (`model_init`, `opt_init`: ISSUE 25); each times a compiled call
+    that is waited for (`compiled_init`).
     """
-    init_key, queue_key, state_key = jax.random.split(rng, 3)
-    with span("model_init"):
-        variables = model.init(init_key, jnp.zeros(input_shape, jnp.float32), train=False)
-        params_q = variables["params"]
-        batch_stats_q = variables.get("batch_stats", {})
-        params_k = jax.tree.map(jnp.copy, params_q)
-        batch_stats_k = jax.tree.map(jnp.copy, batch_stats_q)
-        if num_negatives is not None:
-            queue, queue_ptr = init_queue(queue_key, num_negatives, embed_dim, queue_dtype)
-        else:
-            queue, queue_ptr = None, None
-    with span("opt_init"):
-        opt_state = tx.init(params_q)
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params_q=params_q,
-        params_k=params_k,
-        batch_stats_q=batch_stats_q,
-        batch_stats_k=batch_stats_k,
-        opt_state=opt_state,
-        queue=queue,
-        queue_ptr=queue_ptr,
-        rng=state_key,
-    )
+    init_fields = functools.partial(
+        _init_fields, rng, model=model, input_shape=tuple(input_shape),
+        num_negatives=num_negatives, embed_dim=embed_dim, queue_dtype=queue_dtype)
+    return compiled_init(init_fields, tx, span)

@@ -17,6 +17,7 @@ Differences from the v1/v2 step (train_step.py), per the reference:
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -33,7 +34,7 @@ from moco_tpu.ops.losses import l2_normalize, v3_contrastive_loss
 from moco_tpu.parallel.collectives import all_gather_batch, device_local
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.telemetry import health, scopes
-from moco_tpu.train_state import TrainState, no_span
+from moco_tpu.train_state import TrainState, compiled_init, no_span
 
 PREDICTOR_KEY = "predictor"
 
@@ -76,35 +77,36 @@ def patch_embed_trainable_mask(params) -> Any:
     return jax.tree_util.tree_map_with_path(is_trainable, params)
 
 
+@functools.partial(jax.jit, static_argnames=("model", "input_shape"))
+def _init_v3_fields(rng, *, model: V3Model, input_shape):
+    init_key, state_key = jax.random.split(rng)
+    variables = model.init(
+        init_key, jnp.zeros(input_shape, jnp.float32), train=False, predict=True
+    )
+    params_q = variables["params"]
+    batch_stats_q = variables.get("batch_stats", {})
+    return dict(
+        step=jnp.zeros((), jnp.int32),
+        params_q=params_q,
+        params_k=jax.tree.map(jnp.copy, encoder_subtree(params_q)),
+        batch_stats_q=batch_stats_q,
+        batch_stats_k=jax.tree.map(jnp.copy, encoder_subtree(batch_stats_q)),
+        queue=None,
+        queue_ptr=None,
+        rng=state_key,
+    )
+
+
 def create_v3_train_state(
     rng: jax.Array, model: V3Model, tx: optax.GradientTransformation, input_shape,
     span=no_span,
 ) -> TrainState:
     """Init query model (with predictor); key tree = encoder subtree copy.
     `span(name)` opens the driver's set-up span of that name, as in
-    `create_train_state`."""
-    init_key, state_key = jax.random.split(rng)
-    with span("model_init"):
-        variables = model.init(
-            init_key, jnp.zeros(input_shape, jnp.float32), train=False, predict=True
-        )
-        params_q = variables["params"]
-        batch_stats_q = variables.get("batch_stats", {})
-        params_k = jax.tree.map(jnp.copy, encoder_subtree(params_q))
-        batch_stats_k = jax.tree.map(jnp.copy, encoder_subtree(batch_stats_q))
-    with span("opt_init"):
-        opt_state = tx.init(params_q)
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params_q=params_q,
-        params_k=params_k,
-        batch_stats_q=batch_stats_q,
-        batch_stats_k=batch_stats_k,
-        opt_state=opt_state,
-        queue=None,
-        queue_ptr=None,
-        rng=state_key,
-    )
+    `create_train_state`: each times a compiled call that is waited for."""
+    init_fields = functools.partial(
+        _init_v3_fields, rng, model=model, input_shape=tuple(input_shape))
+    return compiled_init(init_fields, tx, span)
 
 
 def _build_apply(model: V3Model):
