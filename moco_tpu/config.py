@@ -22,6 +22,8 @@ class PretrainConfig:
     seed: int = 0
     # model (reference flags -a/--arch, --moco-dim/k/m/t, --mlp)
     arch: str = "resnet50"            # resnet18/34/50/101/152 | vit_small/base/large/huge
+                                      # | sdar_30b_a3b (a routed token encoder:
+                                      # widths in models/sdar.py::SDAR_SIZES)
     embed_dim: int = 128              # --moco-dim
     num_negatives: int = 65536        # --moco-k (ignored for v3)
     momentum_ema: float = 0.999       # --moco-m (v3: base for cosine ramp, 0.99)
@@ -109,8 +111,19 @@ class PretrainConfig:
                                       # it has no ledger row, and the one
                                       # builder-run A/B (2026-07-31, v5e)
                                       # read fused slower (ROADMAP D1)
+    # a token encoder's share of its published stack (models/sdar.py; 0 =
+    # the arch's own number). Named as the model's config.json names them:
+    # a benchmark configuration lists the ones it cuts under `reduced`
+    num_hidden_layers: int = 0        # blocks kept (the period is one block)
+    num_experts: int = 0              # routed experts HELD here, the first n;
+                                      # the router keeps the arch's width and
+                                      # experts per token, and this chip
+                                      # computes its own experts' part
+    vocab_size: int = 0               # the vocabulary's first n ids; the last
+                                      # of them is the mask id of the views
+    seq_len: int = 512                # tokens a view (token archs only)
     # data
-    dataset: str = "synthetic"        # synthetic | cifar10 | imagefolder
+    dataset: str = "synthetic"        # synthetic | cifar10 | imagefolder | synthetic_tokens
     data_dir: str = ""
     image_size: int = 224
     aug_plus: bool = False            # --aug-plus (v2 aug stack)
@@ -835,6 +848,36 @@ PRESETS: dict[str, PretrainConfig | EvalConfig] = {
         compute_dtype="bfloat16",
     ),
 }
+
+
+# 6. MoCo v2 over token sequences (Contriever's recipe, arXiv:2112.09118:
+#    momentum encoder, queue of negatives, two independent crops of a
+#    document, AdamW) with SDAR-30B-A3B-Chat's published stack as the
+#    encoder. The preset is the published model whole; one chip holds a
+#    share of it (`--num-hidden-layers 4 --num-experts 16 --vocab-size 18992`
+#    is one of eight expert-parallel chips' share of four layers: README).
+PRESETS["text-moco-v2-sdar"] = PretrainConfig(
+    name="text-moco-v2-sdar",
+    variant="v2",
+    arch="sdar_30b_a3b",
+    num_negatives=65536,
+    temperature=0.2,
+    momentum_ema=0.99,  # AdamW at lr 1e-5 moves a weight by 1e-5 a step: at
+                        # 0.999 the key encoder's share of that is under ten
+                        # float32 steps of a 0.03 weight and is lost in rounding
+    mlp_head=True,
+    optimizer="adamw",
+    lr=1e-5,
+    weight_decay=0.01,
+    batch_size=32,
+    seq_len=512,
+    epochs=200,
+    cos=True,
+    dataset="synthetic_tokens",
+    compute_dtype="bfloat16",
+    remat=True,
+    health_stride=16,  # the expert-load counters ride the health scalars
+)
 
 
 # 3. Same recipe, ShuffleBN across 8 chips (v3-8) — identical step program by

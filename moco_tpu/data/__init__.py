@@ -20,6 +20,8 @@ _EXPORTS = {
     "AugConfig": "augment",
     "augment_batch": "augment",
     "build_two_crops_sharded": "augment",
+    "build_token_views_sharded": "augment",
+    "token_view_config_for": "augment",
     "aug_config_for": "augment",
     "eval_aug_config": "augment",
     "two_crops": "augment",
@@ -30,6 +32,7 @@ _EXPORTS = {
     "CIFAR10": "datasets",
     "ImageFolder": "datasets",
     "SyntheticDataset": "datasets",
+    "SyntheticTokenDataset": "datasets",
     "build_dataset": "datasets",
     "Prefetcher": "loader",
     "epoch_loader": "loader",

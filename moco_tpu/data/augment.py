@@ -618,3 +618,73 @@ def build_two_crops_sharded(cfg, mesh):
         return sharded(imgs, extents, key)
 
     return fn
+
+
+# -- token views ----------------------------------------------------------------
+
+
+class TokenViewConfig(NamedTuple):
+    """Two views of a document for a token encoder (Contriever's recipe,
+    arXiv:2112.09118: independent contiguous crops, then token masking)."""
+
+    seq_len: int             # tokens a view
+    mask_id: int             # the id a masked position takes
+    mask_prob: float = 0.1   # share of a view's positions that are masked
+
+
+def token_view_config_for(config) -> TokenViewConfig:
+    """The one place the trainer's config becomes the views' recipe: the mask
+    id is the LAST id of the vocabulary slice held here (the traffic draws
+    its ids from the others)."""
+    from moco_tpu.models.sdar import held_vocab
+
+    return TokenViewConfig(seq_len=config.seq_len,
+                           mask_id=held_vocab(config.arch, config.vocab_size) - 1)
+
+
+def _token_view_one(row, length, key, cfg: TokenViewConfig):
+    """One view of one document: a contiguous crop of `seq_len` tokens whose
+    start is uniform over the document's `length`, then masking. A document
+    shorter than a view is not padded for: its view runs on into the row."""
+    k_start, k_mask = jax.random.split(key)
+    start = jax.random.randint(k_start, (), 0, jnp.maximum(length - cfg.seq_len, 0) + 1)
+    ids = jax.lax.dynamic_slice_in_dim(row, start, cfg.seq_len)
+    masked = jax.random.bernoulli(k_mask, cfg.mask_prob, (cfg.seq_len,))
+    return jnp.where(masked, jnp.int32(cfg.mask_id), ids)
+
+
+def build_token_views_sharded(cfg: TokenViewConfig, mesh):
+    """The token counterpart of `build_two_crops_sharded`, with its signature:
+    `fn(rows, key, lengths) -> (ids_q, ids_k)` over `int32` rows `[B, Lmax]`
+    and `lengths` `[B, 1]`. Each device cuts the views of its own shard, keyed
+    by GLOBAL sample index like the image views, so the result does not
+    depend on the mesh."""
+    from jax.sharding import PartitionSpec as P
+
+    from moco_tpu.parallel.collectives import batch_axis_index
+    from moco_tpu.parallel.mesh import batch_axes
+
+    axes = batch_axes(mesh)
+    axis = axes[0] if len(axes) == 1 else axes
+
+    def body(rows, lengths, key):
+        local_b = rows.shape[0]
+        start = batch_axis_index(axis) * local_b
+        kq, kk = jax.random.split(key)
+
+        def view(k):
+            keys = _sample_keys(k, start, local_b)
+            return jax.vmap(lambda r, n, sk: _token_view_one(r, n, sk, cfg))(
+                rows, lengths[:, 0], keys)
+
+        return view(kq), view(kk)
+
+    sharded = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+                                    out_specs=(P(axis), P(axis))))
+
+    def fn(rows, key, lengths=None):
+        if lengths is None:
+            lengths = jnp.full((rows.shape[0], 1), rows.shape[1], jnp.int32)
+        return sharded(rows, lengths, key)
+
+    return fn

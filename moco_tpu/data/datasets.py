@@ -72,6 +72,30 @@ class SyntheticDataset:
         )
 
 
+class SyntheticTokenDataset:
+    """Documents of `int32` token ids in memory, for a token encoder: ids
+    Zipf(1.0) over the vocabulary's first `vocab - 1` (the last id is the
+    views' mask id), every document `length` tokens. The batch protocol is
+    the images' own, `(rows, labels, extents)`, with `extents` the `[n, 1]`
+    lengths."""
+
+    def __init__(self, num_samples: int = 2048, length: int = 1024, vocab: int = 512,
+                 seed: int = 0):
+        rng = np.random.RandomState(seed)
+        p = 1.0 / np.arange(1, vocab)
+        self.rows = rng.choice(vocab - 1, size=(num_samples, length),
+                               p=p / p.sum()).astype(np.int32)
+        self.num_classes = 1
+
+    def __len__(self):
+        return len(self.rows)
+
+    def get_batch(self, indices: np.ndarray):
+        rows = self.rows[indices]
+        return (rows, np.zeros(len(rows), np.int32),
+                np.full((len(rows), 1), rows.shape[1], np.int32))
+
+
 class SyntheticTextureDataset:
     """Clusterable fake data that an UNTRAINED network cannot solve.
 
@@ -427,6 +451,8 @@ def build_dataset(
     In-memory datasets (synthetic/CIFAR) have no staging and ignore both."""
     if name == "synthetic":
         return SyntheticDataset(image_size=image_size, **kw)
+    if name == "synthetic_tokens":
+        return SyntheticTokenDataset(**kw)
     if name == "synthetic_texture":
         return SyntheticTextureDataset(image_size=image_size, **kw)
     if name == "cifar10":

@@ -92,11 +92,14 @@ def _host_memory_is_device_memory() -> bool:
 
 
 class _Canvas:
-    """One preallocated staging buffer: batch images + extents + labels."""
+    """One preallocated staging buffer: batch rows + extents + labels. The
+    rows are uint8 image canvases with `[h, w, rot]` extents, or `int32`
+    token rows with `[length]` extents: the first batch says which."""
 
-    def __init__(self, batch: int, img_shape: tuple, img_dtype, label_dtype):
+    def __init__(self, batch: int, img_shape: tuple, img_dtype, label_dtype,
+                 extent_shape: tuple = (3,)):
         self.imgs = np.empty((batch,) + tuple(img_shape), img_dtype)
-        self.extents = np.empty((batch, 3), np.int32)
+        self.extents = np.empty((batch,) + tuple(extent_shape), np.int32)
         self.labels = np.empty((batch,), label_dtype)
 
 
@@ -118,7 +121,8 @@ class _BatchCollector:
 
 class Prefetcher:
     """Iterate `(images_u8, labels)` device-sharded batches with parallel
-    background staging and overlapped H2D.
+    background staging and overlapped H2D. Token rows (`int32 [B, L]` with
+    `[B, 1]` lengths as extents) ride the same canvases, counters and spans.
 
     `workers` > 1 requires the standard 3-tuple batch protocol
     (`images, labels, extents`); `workers=1` keeps the generic single-call
@@ -356,11 +360,11 @@ class Prefetcher:
                     "multi-worker staging requires the (images, labels, "
                     f"extents) batch protocol; got a {len(item)}-tuple"
                 )
-            imgs, labels, _extents = item
+            imgs, labels, extents = item
             for _ in range(2):  # double-buffered canvas pool
                 self._free.put(
                     _Canvas(self.batch, imgs.shape[1:], imgs.dtype,
-                            labels.dtype)
+                            labels.dtype, np.shape(extents)[1:])
                 )
             self._pool_built = True
             return self._stage_to_device(item)
@@ -450,6 +454,9 @@ class Prefetcher:
         compiled shapes): content never fills less than the trimmed area,
         padding beyond it is edge-replication the on-device crop never
         samples. extents are unchanged — they describe content, not canvas."""
+        if imgs.ndim == 2:  # token rows: the longest document's length
+            tl = min(imgs.shape[1], int(-(-int(extents[:, 0].max()) // 64) * 64))
+            return imgs if tl == imgs.shape[1] else imgs[:, :tl]
         H, W = imgs.shape[1], imgs.shape[2]
         th = min(H, int(-(-int(extents[:, 0].max()) // 64) * 64))
         tw = min(W, int(-(-int(extents[:, 1].max()) // 64) * 64))

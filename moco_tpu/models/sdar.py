@@ -1,0 +1,357 @@
+"""A routed decoder stack as a MoCo text encoder: the `sdar_moe` family.
+
+The published stack of SDAR-30B-A3B-Chat (JetLM, `config.json`: `model_type`
+`sdar_moe`), read as an encoder of token sequences: token embedding, pre-norm
+blocks with RMSNorm, grouped-query attention with per-head RMSNorm on q and k
+and rotary positions, a BLOCK-CAUSAL mask (bidirectional inside a block of
+`block_length` positions, causal from block to block: how SDAR's forward pass
+sees a clean sequence), and in every block a routed expert layer. After the
+last block: RMSNorm, the mean over the positions, the v2 MLP head.
+
+The expert layer is told WHICH experts it holds (`experts_held`, the first
+`n` of the router's `num_experts`): it routes over all of them, keeps
+`top_k` a token, renormalises over those, and computes the part of the
+result that its own experts give. What the other experts would add is left
+out; on one chip of an expert-parallel deployment that is this chip's share
+of the layer, without the exchange. No capacity factor: the groups are of
+uneven size (`lax.ragged_dot` over rows sorted by expert) and no assignment
+to a held expert is dropped: a pass works on a static buffer of twice the rows
+that uniform routing sends here (the grouped product runs over all of it, the
+unassigned rows as zeros); what a router sends beyond it first meets a pass
+an eighth that size, and whole passes after that.
+
+A share does not train its router. The router is replicated over the chips
+that share the layer, and its gradient is whole only once every chosen
+expert's output has come back through the exchange; from its own experts
+alone a chip sees the absent ones as experts that add nothing, and Adam then
+walks the held experts' logits down together until the chip stands empty (at
+lr 1e-4 a token met 0.14 held experts by step 17 where 1.0 is uniform). So
+where `held < experts` the router's kernel is a constant of the step
+(`stop_gradient` here, `router_trainable_mask` for the optimizer's decay: the
+frozen patch projection's pattern, `v3_step.patch_embed_trainable_mask`); the
+gradient still flows through the logits into the layer's input. The whole
+layer trains its router.
+
+float32 whatever `dtype` is: the router's product and softmax, the
+attention softmax, every RMSNorm, the head. Parameters are float32.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from moco_tpu.telemetry import scopes
+
+# the published sizes by arch (config.json's keys in the comments); the
+# cut to one chip (layers, experts held, vocabulary slice) is the
+# config's, not the table's
+SDAR_SIZES = {
+    # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat/blob/main/config.json
+    "sdar_30b_a3b": dict(
+        hidden=2048,            # hidden_size
+        layers=48,              # num_hidden_layers
+        heads=32,               # num_attention_heads
+        kv_heads=4,             # num_key_value_heads
+        head_dim=128,           # head_dim
+        experts=128,            # num_experts
+        top_k=8,                # num_experts_per_tok (norm_topk_prob: true)
+        expert_width=768,       # moe_intermediate_size
+        vocab=151936,           # vocab_size
+        rope_theta=1e6,         # rope_theta
+        eps=1e-6,               # rms_norm_eps
+        block_length=4,         # not in config.json: the family's default
+    ),
+    # test size: the same mechanisms, nothing else
+    "sdar_tiny": dict(
+        hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16, experts=16,
+        top_k=4, expert_width=32, vocab=512, rope_theta=1e6, eps=1e-6,
+        block_length=2,
+    ),
+}
+# a collection of its own for what the router counted on the way: read by
+# the step's stride-gated counters, never by the forward pass
+MOE_STATS = "moe_stats"
+# each layer's chosen experts `[tokens, top_k]`, for whoever asks by making the
+# collection mutable (perfbench/calibrate_reference.py: the share of sets that
+# differ from the float32 reference's); never in the step
+MOE_CHOICES = "moe_choices"
+
+
+def is_sdar(arch: str) -> bool:
+    return arch.startswith("sdar")
+
+
+def router_trains(arch: str, held: int = 0) -> bool:
+    """Whether the router's kernel is updated: only where the layer is whole
+    (the module's docstring)."""
+    return not held or held >= SDAR_SIZES[arch]["experts"]
+
+
+def router_trainable_mask(params) -> Any:
+    """Optimizer mask: False for every leaf under a `router` module."""
+
+    def is_trainable(path, _leaf):
+        return not any(getattr(entry, "key", None) == "router" for entry in path)
+
+    return jax.tree_util.tree_map_with_path(is_trainable, params)
+
+
+def held_vocab(arch: str, vocab_size: int = 0) -> int:
+    """Ids of the vocabulary slice held here: `vocab_size` first ids, or all."""
+    return vocab_size or SDAR_SIZES[arch]["vocab"]
+
+
+def block_causal_mask(length: int, block_length: int) -> jax.Array:
+    """`[L, L]` bool: position i sees j iff j's block is not after i's."""
+    blocks = jnp.arange(length) // block_length
+    return blocks[None, :] <= blocks[:, None]
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary embedding over the whole head, positions 0..L-1.
+    `x` is `[B, L, H, D]` float32."""
+    length, dim = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], -1)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, -1)
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+
+
+class Attention(nn.Module):
+    heads: int
+    kv_heads: int
+    head_dim: int
+    block_length: int
+    rope_theta: float
+    eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, length, _ = h.shape
+        group = self.heads // self.kv_heads
+
+        def proj(name, n):
+            y = nn.Dense(n * self.head_dim, use_bias=False, dtype=self.dtype,
+                         param_dtype=jnp.float32, name=name)(h)
+            return y.reshape(b, length, n, self.head_dim)
+
+        q, k, v = proj("q", self.heads), proj("k", self.kv_heads), proj("v", self.kv_heads)
+        q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta).astype(self.dtype)
+        k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta).astype(self.dtype)
+        # each key/value head serves `group` query heads
+        q = q.reshape(b, length, self.kv_heads, group, self.head_dim)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(jnp.float32(self.head_dim))
+        s = jnp.where(block_causal_mask(length, self.block_length), s, -jnp.inf)
+        p = jax.nn.softmax(s, -1).astype(self.dtype)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
+        o = o.reshape(b, length, self.heads * self.head_dim)
+        return nn.Dense(h.shape[-1], use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, name="o")(o)
+
+
+def held_rows(tokens: int, top_k: int, experts: int, held: int) -> int:
+    """Rows of the buffer that the first pass of the held experts works on:
+    twice what uniform routing sends here, or every assignment where the
+    layer is whole. What a skewed router sends beyond it takes further,
+    smaller passes (`Experts`): nothing is dropped."""
+    if held == experts:
+        return tokens * top_k
+    return tokens * min(top_k, 2 * -(-top_k * held // experts))
+
+
+class Router(nn.Module):
+    """`u -> logits` over every expert, float32 at `highest`; `trains` False
+    makes the kernel a constant of the step."""
+
+    experts: int
+    trains: bool = True
+
+    @nn.compact
+    def __call__(self, u):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (u.shape[-1], self.experts), jnp.float32)
+        if not self.trains:
+            kernel = lax.stop_gradient(kernel)
+        return jnp.matmul(u.astype(jnp.float32), kernel, precision=lax.Precision.HIGHEST)
+
+
+class Experts(nn.Module):
+    """The routed expert layer's share: see the module's docstring."""
+
+    experts: int
+    held: int
+    top_k: int
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        tokens, hidden = u.shape
+        with jax.named_scope(scopes.MOE_ROUTER):
+            logits = Router(self.experts, self.held == self.experts, name="router")(u)
+            weight, expert = lax.top_k(jax.nn.softmax(logits, -1), self.top_k)
+            weight = weight / jnp.sum(weight, -1, keepdims=True)
+
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2,
+                                                out_axis=-1, batch_axis=0)
+        gate = self.param("gate", init, (self.held, hidden, self.width), jnp.float32)
+        up = self.param("up", init, (self.held, hidden, self.width), jnp.float32)
+        down = self.param("down", init, (self.held, self.width, hidden), jnp.float32)
+
+        rows = held_rows(tokens, self.top_k, self.experts, self.held)
+        # what a skewed router sends beyond the buffer first meets a pass an eighth
+        # its size, so that a small spill costs about what it weighs, and whole
+        # passes after that
+        spill = -(-rows // 8) if tokens * self.top_k > rows else 0
+        passes = -(-max(tokens * self.top_k - rows - spill, 0) // rows)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            # assignments sorted by expert, those of experts held elsewhere last
+            flat = jnp.where(expert < self.held, expert, self.held).reshape(-1)
+            order = jnp.pad(jnp.argsort(flat, stable=True),
+                            (0, rows + spill + passes * rows - flat.size))
+            sizes = jnp.bincount(flat, length=self.held + 1)[: self.held].astype(jnp.int32)
+            ends = jnp.cumsum(sizes)
+            assigned = ends[-1]
+            ub, flat_weight = u.astype(self.dtype), weight.reshape(-1)
+        self.sow(MOE_STATS, "held_counts", sizes, reduce_fn=lambda _, new: new,
+                 init_fn=lambda: None)
+        self.sow(MOE_CHOICES, "chosen", expert, reduce_fn=lambda _, new: new,
+                 init_fn=lambda: None)
+
+        def nothing():
+            # typed like a pass's result: inside a shard_map region that varies
+            # over the mesh axes, and scan and cond demand equal types
+            zeros, vma = jnp.zeros((tokens, hidden), jnp.float32), tuple(jax.typeof(u).vma)
+            return lax.pcast(zeros, vma, to="varying") if vma else zeros
+
+        def one_pass(first, n):
+            """The held experts' part for the sorted assignments `first ..
+            first + n`: gather, three grouped products, scatter-add."""
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                take = lax.dynamic_slice_in_dim(order, first, n)
+                token = take // self.top_k
+                valid = (first + jnp.arange(n) < assigned)[:, None]
+                x = jnp.where(valid, ub[token], 0)
+                w = jnp.where(valid, flat_weight[take][:, None], 0)
+                # each group's rows that fall inside this pass. The rows past the
+                # last assignment are zero rows and ride in the last group: every
+                # row of the static buffer lies in a group, so the product is
+                # zero there by arithmetic (forward and transposed) and not by
+                # what a kernel leaves outside its groups, and a step's time
+                # does not depend on the router's luck with this chip's experts
+                hi = jnp.clip(ends, first, first + n).at[-1].set(first + n)
+                lo = jnp.clip(ends - sizes, first, first + n)
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                g = lax.ragged_dot(x, gate.astype(self.dtype), hi - lo)
+                y = jax.nn.silu(g) * lax.ragged_dot(x, up.astype(self.dtype), hi - lo)
+                y = lax.ragged_dot(y, down.astype(self.dtype), hi - lo)
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                return nothing().at[token].add(y.astype(jnp.float32) * w)
+
+        def spilled(first, n):
+            # a pass that runs keeps nothing for the backward pass but its
+            # place (it is computed again there)
+            return jax.checkpoint(lambda f: one_pass(f, n))(first)
+
+        def overflow(acc, first):
+            return lax.cond(first < assigned, lambda acc: acc + spilled(first, rows),
+                            lambda acc: acc, acc), None
+
+        y = one_pass(jnp.int32(0), rows)
+        if spill:
+            # where routing is near uniform nothing is left after the first pass,
+            # and the passes after it cost neither time nor memory
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                firsts = rows + spill + jnp.arange(passes, dtype=jnp.int32) * rows
+                y = lax.cond(
+                    assigned > rows,
+                    lambda y: lax.scan(overflow, y + spilled(jnp.int32(rows), spill), firsts)[0],
+                    lambda y: y, y)
+        return y
+
+
+class Layer(nn.Module):
+    sizes: Any            # an SDAR_SIZES entry as a tuple of items (hashable)
+    held: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        z = dict(self.sizes)
+        b, length, hidden = x.shape
+        with jax.named_scope(scopes.ATTN):
+            h = RMSNorm(z["eps"], name="norm1")(x).astype(self.dtype)
+            x = x + Attention(z["heads"], z["kv_heads"], z["head_dim"], z["block_length"],
+                              z["rope_theta"], z["eps"], self.dtype, name="attn")(h)
+        with jax.named_scope(scopes.MOE_ROUTER):
+            u = RMSNorm(z["eps"], name="norm2")(x).reshape(b * length, hidden)
+        y = Experts(z["experts"], self.held, z["top_k"], z["expert_width"], self.dtype,
+                    name="moe")(u)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            return x + y.reshape(b, length, hidden).astype(x.dtype)
+
+
+class SDAREncoder(nn.Module):
+    """Token ids `[B, L]` -> the pooled feature (`num_classes=None`) or the v2
+    MLP head's embedding. `layers`, `held` and `vocab` are the cut: how deep,
+    which experts (the first `held`) and which slice of the vocabulary (its
+    first `vocab` ids) live here."""
+
+    sizes: Any
+    layers: int
+    held: int
+    vocab: int
+    num_classes: int | None = None
+    mlp_head: bool = True
+    remat: bool = False
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, ids, train: bool = True):
+        z = dict(self.sizes)
+        with jax.named_scope(scopes.EMBED_POOL):
+            x = nn.Embed(self.vocab, z["hidden"], dtype=self.dtype, param_dtype=jnp.float32,
+                         embedding_init=nn.initializers.normal(1.0), name="embed")(
+                ids.astype(jnp.int32))
+        layer_cls = nn.remat(Layer) if self.remat else Layer
+        for i in range(self.layers):
+            x = layer_cls(self.sizes, self.held, self.dtype, name=f"layer_{i}")(x)
+        with jax.named_scope(scopes.EMBED_POOL):
+            feat = jnp.mean(RMSNorm(z["eps"], name="norm")(x), axis=1)
+            if self.num_classes is None:
+                return feat
+            if self.mlp_head:
+                feat = nn.relu(nn.Dense(z["hidden"], param_dtype=jnp.float32,
+                                        name="fc_hidden")(feat))
+            return nn.Dense(self.num_classes, param_dtype=jnp.float32, name="fc")(feat)
+
+
+def build_sdar(arch: str, num_classes: int | None = None, *, layers: int = 0, held: int = 0,
+               vocab: int = 0, **kwargs) -> SDAREncoder:
+    """`layers` / `held` / `vocab`: 0 is the arch's own (published) number."""
+    if arch not in SDAR_SIZES:
+        raise ValueError(f"unknown sdar arch {arch!r}; choose from {sorted(SDAR_SIZES)}")
+    z = SDAR_SIZES[arch]
+    held = held or z["experts"]
+    if not 0 < held <= z["experts"]:
+        raise ValueError(f"experts held must be in 1..{z['experts']}, got {held}")
+    return SDAREncoder(tuple(sorted(z.items())), layers or z["layers"], held,
+                       held_vocab(arch, vocab), num_classes=num_classes, **kwargs)

@@ -38,6 +38,10 @@ computes the cheap in-graph signals that make those modes visible:
                              parameter group — a vanishing head (or
                              stem) gradient is the earliest signal of
                              a dead loss
+  expert load                a routed encoder only: assignments to the
+                             experts held here a token, and the fullest
+                             held expert's load over the mean — a
+                             router that starves or floods this chip
 
 Contract (the step builders enforce it; tests pin it):
 
@@ -171,6 +175,25 @@ def region_health(q: jax.Array, k: jax.Array, grads, step: jax.Array,
                "h_emb_std_k": std_k}
         out.update(grad_group_norms(grads))
         return out
+
+    return _gated(step, stride, compute)
+
+
+def expert_load(moe_stats, tokens: int, step: jax.Array,
+                stride: int) -> dict[str, jax.Array]:
+    """A routed encoder's counters (models/sdar.py sows each layer's
+    assignments by held expert): assignments to held experts a token, over
+    all layers (1.0 where routing is uniform and an eighth of the experts
+    live here), and the fullest held expert's load over the mean load, the
+    worst layer's. Per-device views of the query forward; the caller's
+    metrics pmean averages them."""
+
+    def compute():
+        counts = jnp.stack([c.astype(jnp.float32)
+                            for c in jax.tree.leaves(moe_stats)])   # [layers, held]
+        mean = jnp.maximum(jnp.mean(counts, -1), 1e-9)
+        return {"h_moe_assign_per_token": jnp.mean(jnp.sum(counts, -1)) / tokens,
+                "h_moe_load_max_over_mean": jnp.max(jnp.max(counts, -1) / mean)}
 
     return _gated(step, stride, compute)
 

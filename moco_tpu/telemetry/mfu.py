@@ -130,12 +130,40 @@ def vit_fwd_flops(arch: str, image_size: int) -> float:
     return flops + depth * per_block
 
 
+def sdar_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> float:
+    """Forward matmul FLOPs per VIEW (one document's `seq_len` tokens) for
+    the routed token encoder in models/sdar.py: per block the q/k/v/o
+    projections, scores and mix at the block-causal mask's density, the
+    router over all experts, and the held experts' three products at the
+    assignments uniform routing sends here (`top_k * held / experts` a
+    token); excludes norms, rotary, softmax, the sort and the embedding
+    lookup. `layers` / `held`: 0 is the arch's own number."""
+    from moco_tpu.models.sdar import SDAR_SIZES
+
+    z = SDAR_SIZES[arch]
+    d, hd = z["hidden"], z["head_dim"]
+    blocks = -(-seq_len // z["block_length"])
+    density = (blocks + 1) / (2.0 * blocks)
+    per_token = (
+        2.0 * d * (z["heads"] + 2 * z["kv_heads"]) * hd    # q, k, v projections
+        + 2.0 * 2 * seq_len * density * z["heads"] * hd    # scores + mix
+        + 2.0 * z["heads"] * hd * d                        # output projection
+        + 2.0 * d * z["experts"]                           # router
+        + z["top_k"] * (held or z["experts"]) / z["experts"]
+        * 3 * 2.0 * d * z["expert_width"]                  # gate, up, down
+    )
+    return (layers or z["layers"]) * seq_len * per_token
+
+
 def head_fwd_flops(arch: str, embed_dim: int, mlp_head: bool) -> float:
     """Projection-head dense FLOPs per image (fc, or the v2 2-layer MLP)."""
     from moco_tpu.models.resnet import FEATURE_DIMS
+    from moco_tpu.models.sdar import SDAR_SIZES
 
     if arch in _VIT_SPECS:
         feat = _VIT_SPECS[arch][0]
+    elif arch in SDAR_SIZES:
+        feat = SDAR_SIZES[arch]["hidden"]
     else:
         feat = FEATURE_DIMS[arch]
     if mlp_head:
@@ -144,12 +172,18 @@ def head_fwd_flops(arch: str, embed_dim: int, mlp_head: bool) -> float:
 
 
 def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
-                    embed_dim: int = 128, mlp_head: bool = False) -> float:
-    """Backbone + head forward FLOPs per image for any supported arch."""
+                    embed_dim: int = 128, mlp_head: bool = False, seq_len: int = 512,
+                    num_hidden_layers: int = 0, num_experts: int = 0) -> float:
+    """Backbone + head forward FLOPs per image (a token encoder: per view of
+    `seq_len` tokens) for any supported arch."""
+    from moco_tpu.models.sdar import SDAR_SIZES
+
     if arch in _VIT_SPECS:
         body = vit_fwd_flops(arch, image_size)
     elif arch in _RESNET_SPECS:
         body = resnet_fwd_flops(arch, image_size, cifar_stem)
+    elif arch in SDAR_SIZES:
+        body = sdar_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
     else:
         raise ValueError(f"no analytic FLOPs model for arch {arch!r}")
     return body + head_fwd_flops(arch, embed_dim, mlp_head)
@@ -165,6 +199,8 @@ def train_step_flops(config) -> float:
     per_image = model_fwd_flops(
         config.arch, config.image_size, cifar_stem=config.cifar_stem,
         embed_dim=config.embed_dim, mlp_head=config.mlp_head,
+        seq_len=config.seq_len, num_hidden_layers=config.num_hidden_layers,
+        num_experts=config.num_experts,
     )
     return per_image * _STEP_MULTIPLIER[config.variant] * config.batch_size
 

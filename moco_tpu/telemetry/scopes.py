@@ -22,7 +22,7 @@ anything it could ever want to import from here.
 from __future__ import annotations
 
 # -- device scopes --------------------------------------------------------------
-AUG = "aug"                # two-crop augmentation, incl. the blur kernel and fold_in
+AUG = "aug"                # two-crop augmentation, incl. the blur kernel and fold_in; token views
 K_FWD = "k_fwd"            # key / momentum encoder forward (v2: shuffle, unshuffle)
 Q_FWD_BWD = "q_fwd_bwd"    # query forward + backward, minus what is under loss_queue
 LOSS_QUEUE = "loss_queue"  # logits, contrastive loss, accuracy / health scalars, enqueue
@@ -33,6 +33,15 @@ SHUFFLE_BN = "shuffle_bn"  # v2: the all-gather + permutation before the key for
 KEY_GATHER = "key_gather"  # the all-gather of the keys (v2: unshuffle; v3: in-batch negatives)
 GRAD_SYNC = "grad_sync"    # GradSync's reduce of the per-device gradients
 COLLECTIVE_SCOPES = (SHUFFLE_BN, KEY_GATHER, GRAD_SYNC)
+
+# inside a routed token encoder (`models/sdar.py`), nested under `k_fwd` and
+# `q_fwd_bwd`: every operation of the encoder lies under exactly one of them
+ATTN = "attn"                    # pre-norm, projections, q/k norm, rotary, scores, output
+MOE_ROUTER = "moe_router"        # pre-norm, the float32 router, softmax, top-k
+MOE_DISPATCH = "moe_dispatch"    # sort by expert, gather, scatter-add back, residual
+MOE_EXPERTS = "moe_experts"      # the grouped products and SwiGLU
+EMBED_POOL = "embed_pool"        # token embedding; final norm, mean pool, head
+ENCODER_SCOPES = (ATTN, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, EMBED_POOL)
 
 # -- host spans -----------------------------------------------------------------
 STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler as

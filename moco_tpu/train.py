@@ -35,9 +35,12 @@ from moco_tpu.config import PRESETS, PretrainConfig, get_preset
 from moco_tpu.data import (
     aug_config_for,
     build_dataset,
+    build_token_views_sharded,
     build_two_crops_sharded,
     epoch_loader,
+    token_view_config_for,
 )
+from moco_tpu.models.sdar import held_vocab, is_sdar
 from moco_tpu.ops.knn import knn_accuracy
 from moco_tpu.parallel.mesh import create_mesh, local_batch_size
 from moco_tpu.resilience import (
@@ -351,6 +354,11 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
         jax.config.update("jax_debug_nans", True)
     n_chips = mesh.size
     local_b = local_batch_size(config.batch_size, mesh)  # validates divisibility
+    # a token encoder (models/sdar.py) is fed int32 rows and lengths where an
+    # image encoder is fed uint8 canvases and extents: same feed, same step
+    tokens = is_sdar(config.arch)
+    if tokens and config.knn_monitor:
+        raise ValueError("knn_monitor reads labelled images; a token encoder has none")
 
     dataset_len = None
     if dataset is None:
@@ -373,6 +381,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
             dataset = build_dataset(
                 config.dataset, config.data_dir, image_size=config.image_size,
                 stage_size=config.stage_size, num_workers=config.num_workers,
+                **(dict(vocab=held_vocab(config.arch, config.vocab_size),
+                        length=2 * config.seq_len) if tokens else {}),
             )
     if dataset_len is None:
         dataset_len = len(dataset)
@@ -455,10 +465,12 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                 init_key,
                 model,
                 tx,
-                (local_b, config.image_size, config.image_size, 3),
+                (local_b, config.seq_len) if tokens
+                else (local_b, config.image_size, config.image_size, 3),
                 config.num_negatives,
                 config.embed_dim,
                 span=setup_span,
+                input_dtype=jnp.int32 if tokens else jnp.float32,
             )
         with setup_span("place_state"):
             # gradient-sync accumulators (ISSUE 6): attached BEFORE any
@@ -551,16 +563,21 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
 
         state = state.replace(opt_state=shard_opt_state(state.opt_state, mesh))
 
-    aug_cfg = aug_config_for(config)
-    # image pipeline in the model's compute dtype: bf16 halves the aug's HBM
-    # traffic on TPU (the encoder casts to bf16 immediately anyway)
-    from moco_tpu.data.augment import with_dtype
     from moco_tpu.train_step import build_fused_step
 
-    aug_cfg = with_dtype(aug_cfg, config.compute_dtype)
     data_key = jax.random.key(config.seed + 1)
     with setup_span("build_step"):
-        two_crops_fn = build_two_crops_sharded(aug_cfg, mesh)
+        if tokens:
+            two_crops_fn = build_token_views_sharded(
+                token_view_config_for(config), mesh)
+        else:
+            # image pipeline in the model's compute dtype: bf16 halves the
+            # aug's HBM traffic on TPU (the encoder casts to bf16 immediately
+            # anyway)
+            from moco_tpu.data.augment import with_dtype
+
+            two_crops_fn = build_two_crops_sharded(
+                with_dtype(aug_config_for(config), config.compute_dtype), mesh)
         fused_step = build_fused_step(step_fn, two_crops_fn, data_key)
 
     # host-side step counter mirroring state.step: int(state.step) would be a

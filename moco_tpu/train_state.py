@@ -73,11 +73,12 @@ def compiled_init(init_fields, tx: optax.GradientTransformation, span=no_span) -
 # with an equal model finds it in jit's own cache. Under `jax.jit` the dummy
 # forward of `model.init` is dead code: the program is the random draws and
 # the q → k copies.
-@functools.partial(jax.jit, static_argnames=("model", "input_shape", "num_negatives",
-                                             "embed_dim", "queue_dtype"))
-def _init_fields(rng, *, model, input_shape, num_negatives, embed_dim, queue_dtype):
+@functools.partial(jax.jit, static_argnames=("model", "input_shape", "input_dtype",
+                                             "num_negatives", "embed_dim", "queue_dtype"))
+def _init_fields(rng, *, model, input_shape, input_dtype, num_negatives, embed_dim,
+                 queue_dtype):
     init_key, queue_key, state_key = jax.random.split(rng, 3)
-    variables = model.init(init_key, jnp.zeros(input_shape, jnp.float32), train=False)
+    variables = model.init(init_key, jnp.zeros(input_shape, input_dtype), train=False)
     params_q = variables["params"]
     batch_stats_q = variables.get("batch_stats", {})
     if num_negatives is not None:
@@ -105,16 +106,19 @@ def create_train_state(
     embed_dim: int,
     queue_dtype=jnp.float32,
     span=no_span,
+    input_dtype=jnp.float32,
 ) -> TrainState:
     """Initialise q, copy q → k (the reference's param copy,
     `moco/builder.py:≈L20-24` — k starts identical to q), build queue.
 
-    `input_shape` is a per-device-shaped dummy `[local_b, H, W, C]`; init is
-    shape-driven only. `span(name)` opens the driver's set-up span of that
+    `input_shape` is a per-device-shaped dummy `[local_b, H, W, C]` (a token
+    encoder's: `[local_b, L]` of `input_dtype` int32); init is shape-driven
+    only. `span(name)` opens the driver's set-up span of that
     name (`model_init`, `opt_init`: ISSUE 25); each times a compiled call
     that is waited for (`compiled_init`).
     """
     init_fields = functools.partial(
         _init_fields, rng, model=model, input_shape=tuple(input_shape),
-        num_negatives=num_negatives, embed_dim=embed_dim, queue_dtype=queue_dtype)
+        input_dtype=jnp.dtype(input_dtype), num_negatives=num_negatives,
+        embed_dim=embed_dim, queue_dtype=queue_dtype)
     return compiled_init(init_fields, tx, span)

@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from moco_tpu.config import PretrainConfig
 from moco_tpu.models import build_resnet
+from moco_tpu.models.sdar import MOE_STATS, is_sdar, router_trainable_mask, router_trains
 from moco_tpu.telemetry import health, scopes
 from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import (
@@ -63,6 +64,15 @@ def build_encoder(config: PretrainConfig):
     encoder is backbone→projector (+predictor on the query side), so this
     returns the composite `V3Model`."""
     dtype = jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
+    if is_sdar(config.arch):
+        if config.variant == "v3":
+            raise ValueError("a token encoder trains under the queue-based v2 step")
+        from moco_tpu.models.sdar import build_sdar
+
+        return build_sdar(
+            config.arch, num_classes=config.embed_dim, mlp_head=config.mlp_head,
+            layers=config.num_hidden_layers, held=config.num_experts,
+            vocab=config.vocab_size, dtype=dtype, remat=config.remat)
     if config.variant == "v3":
         from moco_tpu.v3_step import V3Model
 
@@ -160,6 +170,10 @@ def build_optimizer(
         from moco_tpu.v3_step import patch_embed_trainable_mask
 
         tx = optax.masked(tx, patch_embed_trainable_mask)
+    if is_sdar(config.arch) and not router_trains(config.arch, config.num_experts):
+        # a share of an expert layer does not train its router (models/sdar.py):
+        # the same pattern, stop_gradient in the model and the mask for the decay
+        tx = optax.masked(tx, router_trainable_mask)
     return tx, sched
 
 
@@ -194,6 +208,12 @@ def _build_key_path(config: PretrainConfig, model):
     chunks = int(getattr(config, "collective_chunks", 1))
 
     def key_path(params_k, stats_k, im_k, key):
+        if not jax.tree.leaves(stats_k):
+            # an encoder without BatchNorm (models/sdar.py) has no batch
+            # statistics to leak: rows are independent, ShuffleBN is a no-op
+            # and is left out, and so are its two gathers
+            k = model.apply({"params": params_k}, im_k, train=True)
+            return lax.stop_gradient(l2_normalize(k)), stats_k
         with jax.named_scope(scopes.SHUFFLE_BN):
             if config.shuffle_mode == "ring":
                 from moco_tpu.parallel.collectives import ring_shuffle
@@ -224,12 +244,18 @@ def _build_query_loss(config: PretrainConfig, model, temperature: float):
     (keys, queue). Shared by the spmd_region's value_and_grad and the
     grad-flow probe (which also differentiates w.r.t. the queue)."""
 
+    # what the forward pass hands out beside the embedding: BatchNorm's batch
+    # statistics, and a routed encoder's counts where the counters are on
+    mutable = ["batch_stats"]
+    if config.health_stride:
+        mutable.append(MOE_STATS)
+
     def query_loss(pq, stats_q, im_q, k, queue):
         q, mut_q = model.apply(
             {"params": pq, "batch_stats": stats_q},
             im_q,
             train=True,
-            mutable=["batch_stats"],
+            mutable=mutable,
         )
         q = l2_normalize(q)
         # innermost recognised scope wins in the trace's reduction: the
@@ -242,10 +268,11 @@ def _build_query_loss(config: PretrainConfig, model, temperature: float):
         # q rides the aux for the health diagnostics (ISSUE 13) — already
         # computed, and DCE'd by XLA wherever nothing consumes it
         return loss, (
-            mut_q["batch_stats"],
+            mut_q.get("batch_stats", stats_q),
             logits,
             labels,
             q,
+            mut_q.get(MOE_STATS, {}),
         )
 
     return query_loss
@@ -342,7 +369,7 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
         # w.r.t. the device-local view: the grads come out per-device and
         # gradsync's reduce below is the only one (collectives.device_local)
         with jax.named_scope(scopes.Q_FWD_BWD):
-            (loss, (new_stats_q, logits, labels, q)), grads = jax.value_and_grad(
+            (loss, (new_stats_q, logits, labels, q, moe_stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(device_local(params_q, DATA_AXIS))
         # DDP-equivalent gradient sync (mean over the data axis) through the
@@ -376,6 +403,10 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
                 # the SAME metrics pmean below — no new collectives
                 metrics.update(health.region_health(
                     q, k, grads, step, config.health_stride))
+                if moe_stats:
+                    metrics.update(health.expert_load(
+                        moe_stats, im_q.shape[0] * im_q.shape[1], step,
+                        config.health_stride))
             metrics = lax.pmean(metrics, DATA_AXIS)
         return payload, gs_new, gs_probe, k, new_stats_q, new_stats_k, metrics
 

@@ -18,10 +18,11 @@ def test_pretrain_preset_builds(name):
     tx, sched = build_optimizer(config, steps_per_epoch=100)
     s = config.image_size
     kwargs = {"predict": True} if config.variant == "v3" else {}
+    # a token encoder is fed ids, an image encoder pictures
+    dummy = (jnp.zeros((1, config.seq_len), jnp.int32) if config.arch.startswith("sdar")
+             else jnp.zeros((1, s, s, 3)))
     shapes = jax.eval_shape(
-        lambda: model.init(
-            jax.random.key(0), jnp.zeros((1, s, s, 3)), train=False, **kwargs
-        )
+        lambda: model.init(jax.random.key(0), dummy, train=False, **kwargs)
     )
     assert "params" in shapes
     # schedule evaluates finitely at the start/end of training

@@ -1166,6 +1166,14 @@ def render(summary: dict) -> str:
                 + (f" · participation ratio {last['emb_pr_q']:.1f}"
                    if "emb_pr_q" in last else "")
             )
+        if "moe_assign_per_token" in last:
+            # a routed token encoder (models/sdar.py): what its router sent
+            # to the experts this chip holds
+            lines.append(
+                f"  experts: {last['moe_assign_per_token']:.3f} assignment(s) "
+                f"a token to held experts · fullest over mean load "
+                f"{last.get('moe_load_max_over_mean', 0):.3f}"
+            )
         inc = health.get("incidents")
         if inc:
             preds = ", ".join(
