@@ -34,6 +34,14 @@ layer trains its router.
 
 float32 whatever `dtype` is: the router's product and softmax, the
 attention softmax, every RMSNorm, the head. Parameters are float32.
+
+Where the attention softmax happens: on a TPU, with `head_dim` and the view's
+length multiples of 128 and `block_length` a divisor of 128 (the published
+arch at 512 tokens), in VMEM, inside `ops/pallas_attention.py`'s kernels; the
+scores never reach memory and the tiles the mask empties are not computed. Any
+other backend or shape (`sdar_tiny`, every CPU test) takes `einsum_attention`,
+the float32 scores through memory. One rule, `attention_plan`, on what the
+code can observe; the run's `setup` event says which path was built.
 """
 
 from __future__ import annotations
@@ -45,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from moco_tpu.ops.pallas_attention import attention_plan, block_causal_attention
 from moco_tpu.telemetry import scopes
 
 # the published sizes by arch (config.json's keys in the comments); the
@@ -106,6 +115,13 @@ def held_vocab(arch: str, vocab_size: int = 0) -> int:
     return vocab_size or SDAR_SIZES[arch]["vocab"]
 
 
+def attention_path(arch: str, length: int) -> dict:
+    """The path `Attention` takes for views of `length` tokens on this backend,
+    with its tile counts: the `attn` block of the run's `setup` event."""
+    z = SDAR_SIZES[arch]
+    return attention_plan(length, z["head_dim"], z["block_length"])
+
+
 def block_causal_mask(length: int, block_length: int) -> jax.Array:
     """`[L, L]` bool: position i sees j iff j's block is not after i's."""
     blocks = jnp.arange(length) // block_length
@@ -133,6 +149,22 @@ class RMSNorm(nn.Module):
         return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
+def einsum_attention(q: jax.Array, k: jax.Array, v: jax.Array, block_length: int) -> jax.Array:
+    """Block-causal grouped-query attention as plain einsums, the float32
+    scores through memory: q `[B, L, heads, D]`, k and v `[B, L, kv_heads, D]`
+    -> `[B, L, heads, D]`. What runs wherever `ops/pallas_attention.py` does
+    not, and that kernel's oracle."""
+    b, length, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    # each key/value head serves `group` query heads
+    q = q.reshape(b, length, kv_heads, heads // kv_heads, dim)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32)
+    s = s / jnp.sqrt(jnp.float32(dim))
+    s = jnp.where(block_causal_mask(length, block_length), s, -jnp.inf)
+    p = jax.nn.softmax(s, -1).astype(q.dtype)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, length, heads, dim)
+
+
 class Attention(nn.Module):
     heads: int
     kv_heads: int
@@ -145,7 +177,6 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, h):
         b, length, _ = h.shape
-        group = self.heads // self.kv_heads
 
         def proj(name, n):
             y = nn.Dense(n * self.head_dim, use_bias=False, dtype=self.dtype,
@@ -155,13 +186,10 @@ class Attention(nn.Module):
         q, k, v = proj("q", self.heads), proj("k", self.kv_heads), proj("v", self.kv_heads)
         q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta).astype(self.dtype)
         k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta).astype(self.dtype)
-        # each key/value head serves `group` query heads
-        q = q.reshape(b, length, self.kv_heads, group, self.head_dim)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32)
-        s = s / jnp.sqrt(jnp.float32(self.head_dim))
-        s = jnp.where(block_causal_mask(length, self.block_length), s, -jnp.inf)
-        p = jax.nn.softmax(s, -1).astype(self.dtype)
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
+        if attention_plan(length, self.head_dim, self.block_length)["path"] == "fused":
+            o = block_causal_attention(q, k, v, block_length=self.block_length)
+        else:
+            o = einsum_attention(q, k, v, self.block_length)
         o = o.reshape(b, length, self.heads * self.head_dim)
         return nn.Dense(h.shape[-1], use_bias=False, dtype=self.dtype,
                         param_dtype=jnp.float32, name="o")(o)

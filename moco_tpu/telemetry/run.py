@@ -110,6 +110,7 @@ class RunTelemetry:
         # set-up spans' seconds by name, written once as the `setup` event
         self._setup_s: dict = {}
         self._setup_emitted = False
+        self._attn: dict | None = None
         self.mfu = MFUEstimator.for_config(config, n_chips, device.device_kind)
         self.devices = DeviceMonitor(device)
         self.pod = PodAggregator(self.registry, n_procs, process_index)
@@ -194,12 +195,21 @@ class RunTelemetry:
                 self._setup_s[name] = (self._setup_s.get(name, 0.0)
                                        + time.perf_counter() - t0)
 
+    def set_attn(self, plan: dict) -> None:
+        """How a token encoder's attention was built (`ops/pallas_attention.py::
+        attention_plan`: the path, and the score tiles computed and skipped).
+        Static per program, so it rides the `setup` event and costs the step
+        nothing."""
+        self._attn = dict(plan)
+
     def _emit_setup(self) -> None:
-        """Once, with the first step record: every set-up span's seconds."""
+        """Once, with the first step record: every set-up span's seconds, and
+        the `attn` block where the encoder has one."""
         self._setup_emitted = True
+        fields = {"attn": self._attn} if self._attn is not None else {}
         self.registry.emit(
             "event", event="setup",
-            spans={k: round(v, 6) for k, v in self._setup_s.items()},
+            spans={k: round(v, 6) for k, v in self._setup_s.items()}, **fields,
         )
 
     # -- per-step ------------------------------------------------------------

@@ -40,7 +40,7 @@ from moco_tpu.data import (
     epoch_loader,
     token_view_config_for,
 )
-from moco_tpu.models.sdar import held_vocab, is_sdar
+from moco_tpu.models.sdar import attention_path, held_vocab, is_sdar
 from moco_tpu.ops.knn import knn_accuracy
 from moco_tpu.parallel.mesh import create_mesh, local_batch_size
 from moco_tpu.resilience import (
@@ -493,6 +493,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
     with setup_span("build_step"):
         step_fn = build_train_step(config, model, tx, mesh, steps_per_epoch,
                                    sched, state=state)
+    if telemetry is not None and tokens:
+        telemetry.set_attn(attention_path(config.arch, config.seq_len))
     if telemetry is not None:
         # static comm facts for the record stream: mode, knobs, analytic
         # per-device sync payload (bytes/step) — rendered by

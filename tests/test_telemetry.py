@@ -262,6 +262,57 @@ def test_close_preempted_marks_heartbeat_phase(tmp_path, mesh8):
     assert payload["step"] == 7 and payload["pid"] == os.getpid()
 
 
+ATTN_PLANS = {"fused": {"path": "fused", "tiles": 16, "tiles_skipped": 6},
+              "einsum": {"path": "einsum", "tiles": 1, "tiles_skipped": 0}}
+
+
+@pytest.mark.parametrize("path", [None, "fused", "einsum"])
+def test_the_setup_event_carries_the_attn_block_beside_its_spans(tmp_path, mesh8, path):
+    """ISSUE 28: how a token encoder's attention was built rides the one `setup`
+    event (static per program: no step record pays for it); a run without a
+    token encoder has no such block."""
+    from moco_tpu.telemetry import RunTelemetry
+
+    config = get_preset("cifar10-moco-v1").replace(
+        telemetry_dir=str(tmp_path), heartbeat_secs=0.0, telemetry_stride=0)
+    tel = RunTelemetry(config, n_chips=8, n_procs=1, process_index=0, steps_per_epoch=4)
+    try:
+        with tel.setup_span("build_step"):
+            pass
+        if path:
+            tel.set_attn(ATTN_PLANS[path])
+        thr = Throughput(8, window=4)
+        thr.update(16)
+        for step in (1, 2):
+            tel.on_step(step, {"step_s": 0.01, "data_s": 0.001, "host_s": 0.001}, thr)
+    finally:
+        tel.close(last_step=2)
+    with open(os.path.join(str(tmp_path), "events.jsonl")) as f:
+        setups = [r for r in map(json.loads, f) if r.get("event") == "setup"]
+    assert len(setups) == 1 and "build_step" in setups[0]["spans"]
+    assert setups[0].get("attn") == (ATTN_PLANS[path] if path else None)
+
+
+@pytest.mark.parametrize("path, holds", [
+    ("fused", "attention fused, 6 of 16 score tiles skipped"),
+    ("einsum", "attention einsum, 0 of 1 score tiles skipped"),
+    (None, None),
+])
+def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):
+    spec = importlib.util.spec_from_file_location(
+        "telemetry_report", os.path.join(os.path.dirname(__file__), "..", "tools",
+                                         "telemetry_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    setup = {"kind": "event", "event": "setup", "spans": {"model_init": 3.5, "build_step": 0.25}}
+    if path:
+        setup["attn"] = ATTN_PLANS[path]
+    records = [setup, {"kind": "step", "step": 1, "step_s": 0.5}]
+    lines = [t for t in report.render(report.summarize(records)).splitlines() if "set-up:" in t]
+    assert len(lines) == 1 and "model_init 3.50 s" in lines[0]
+    assert ("attention" not in lines[0]) if holds is None else lines[0].endswith(holds)
+
+
 # ---------------------------------------------------------------------------
 # MFU / analytic FLOPs
 # ---------------------------------------------------------------------------

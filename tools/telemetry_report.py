@@ -291,10 +291,12 @@ def summarize(records: list[dict], skipped: int = 0) -> dict:
                 if isinstance(r.get("compile"), dict)]
     if compiles:
         summary["compile"] = compiles[-1]
-    setups = [e["spans"] for e in events
+    setups = [e for e in events
               if e.get("event") == "setup" and isinstance(e.get("spans"), dict)]
     if setups:
-        summary["setup_s"] = setups[-1]
+        summary["setup_s"] = setups[-1]["spans"]
+        if isinstance(setups[-1].get("attn"), dict):
+            summary["attn"] = setups[-1]["attn"]
     if pods:
         spreads = [
             p["step_s_max"] - p["step_s_min"]
@@ -882,10 +884,13 @@ def render(summary: dict) -> str:
         )
     setup = summary.get("setup_s")
     if setup:
+        attn = summary.get("attn")
         lines.append(
             "set-up: " + " · ".join(
                 f"{name} {secs:.2f} s" for name, secs in
                 sorted(setup.items(), key=lambda kv: -kv[1]))
+            + (f" · attention {attn.get('path')}, {attn.get('tiles_skipped', 0)} of "
+               f"{attn.get('tiles', 0)} score tiles skipped" if attn else "")
         )
     isv = summary.get("input_servers")
     if isv:
