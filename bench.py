@@ -727,8 +727,7 @@ def bench_step():
     # aug in the compute dtype (bf16) fused into ONE program with the step
     # via the SAME build_fused_step the train driver uses; the assembly and
     # timing semantics (sync via float(loss), generous warmup,
-    # best-of-rounds, finite-loss asserts) live in benchkit, shared with
-    # tools/_tpu_validate.py and tools/_perf_ab.py
+    # best-of-rounds, finite-loss asserts) live in benchkit
     fused, state, imgs_u8, extents = build_v2_fused_bench(config, mesh)
     best, compile_warmup_s, loss, state = time_fused_step(
         fused, state, imgs_u8, extents, warmup=warmup, steps=steps)
@@ -761,7 +760,6 @@ def bench_step():
                 "unit": "imgs/sec/chip",
                 "vs_baseline": round(per_chip / BASELINE_IMGS_PER_SEC_PER_CHIP, 3),
                 "device": device,
-                "fused_bn_conv": bool(config.fused_bn_conv),
                 "final_loss": round(loss, 4),
                 "step_time_synced_ms": step_pcts,
                 "grad_sync": grad_sync_detail,

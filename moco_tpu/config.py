@@ -102,15 +102,6 @@ class PretrainConfig:
                                       # and cast back to their OWN dtype,
                                       # integer leaves are summed exactly,
                                       # never cast (gradsync.leaf_wire_dtype)
-    fused_bn_conv: bool = False       # interior bn→relu→conv passes through
-                                      # Pallas fused kernels on TPU: the
-                                      # Bottleneck 1x1 tail + stride-1 3x3
-                                      # mids, and BasicBlock's conv2
-                                      # (identical params and math;
-                                      # models/fused_block). Default OFF:
-                                      # it has no ledger row, and the one
-                                      # builder-run A/B (2026-07-31, v5e)
-                                      # read fused slower (ROADMAP D1)
     # a token encoder's share of its published stack (models/sdar.py; 0 =
     # the arch's own number). Named as the model's config.json names them:
     # a benchmark configuration lists the ones it cuts under `reduced`

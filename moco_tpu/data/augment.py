@@ -462,20 +462,10 @@ def _use_pallas_blur(cfg: AugConfig) -> bool:
         # solarizing view keeps the in-pipeline (portable) blur
         return False
     if cfg.pallas_blur == "on":
-        # explicit force-on wins over backend/env (the AugConfig contract:
-        # auto|on|off) — this is how the CPU interpret-mode equivalence
-        # tests exercise the kernel off-TPU; the r5 env_flag refactor
-        # briefly dropped this branch and the tests passed vacuously
-        # (review, r5)
+        # the AugConfig contract (auto|on|off): force-on is how the CPU
+        # interpret-mode equivalence tests run the kernel off-TPU
         return True
-    from moco_tpu.utils.envflags import env_flag
-
-    # MOCO_TPU_DISABLE_PALLAS_BLUR: blur-only switch so tools/_perf_ab.py
-    # can attribute step time between the Pallas families (r5); uniform
-    # "0"-means-off parsing via env_flag (review, r5)
-    return (jax.default_backend() == "tpu"
-            and not env_flag("MOCO_TPU_DISABLE_PALLAS")
-            and not env_flag("MOCO_TPU_DISABLE_PALLAS_BLUR"))
+    return jax.default_backend() == "tpu"
 
 
 def _sample_keys(key: jax.Array, start, n: int) -> jax.Array:
@@ -549,7 +539,7 @@ def two_crops(images_u8: jax.Array, key: jax.Array, cfg: AugConfig, extents=None
     reshard the whole batch across chips every step (measured: 12
     collective-permutes + 20 all-to-alls in the compiled HLO vs ZERO for
     this form). For MULTI-chip meshes with the Pallas blur, use
-    `build_two_crops_sharded` — a pallas_call has no GSPMD partitioning rule
+    `build_two_crops_sharded` — a Pallas kernel has no GSPMD partitioning rule
     and would otherwise be computed on a replicated (all-gathered) batch."""
     kq, kk = jax.random.split(key)
     return (

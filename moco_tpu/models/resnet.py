@@ -38,6 +38,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from moco_tpu.models.batchnorm import BatchNorm
+
 ModuleDef = Any
 
 
@@ -81,20 +83,12 @@ def _space_to_depth_stem(x, kernel, dtype):
 
 
 class BasicBlock(nn.Module):
-    """2x3x3 residual block (ResNet-18/34).
-
-    `fused_tail=True` runs the interior bn1→relu→conv2 pass (conv2 is
-    ALWAYS stride 1 here) through the Pallas 3x3 fused kernel
-    (models/fused_block.py) — same params/names/math as the unfused
-    modules."""
+    """2x3x3 residual block (ResNet-18/34)."""
 
     filters: int
     strides: int = 1
     conv: ModuleDef = nn.Conv
-    norm: ModuleDef = nn.BatchNorm
-    fused_tail: bool = False
-    bn_momentum: float = 0.9
-    dtype: Any = jnp.float32
+    norm: ModuleDef = BatchNorm
 
     @nn.compact
     def __call__(self, x):
@@ -107,22 +101,11 @@ class BasicBlock(nn.Module):
             self.filters, (3, 3), (self.strides, self.strides),
             padding=[(1, 1), (1, 1)], name="conv1",
         )(x)
-        if self.fused_tail:
-            from moco_tpu.models.fused_block import (
-                fused_bn_relu_conv2,
-                norm_train_flag,
-            )
-
-            y = fused_bn_relu_conv2(
-                self, y, self.filters, norm_train_flag(self.norm),
-                self.bn_momentum, 1e-5, self.dtype,
-            )
-        else:
-            y = self.norm(name="bn1")(y)
-            y = nn.relu(y)
-            y = self.conv(
-                self.filters, (3, 3), padding=[(1, 1), (1, 1)], name="conv2"
-            )(y)
+        y = self.norm(name="bn1")(y)
+        y = nn.relu(y)
+        y = self.conv(
+            self.filters, (3, 3), padding=[(1, 1), (1, 1)], name="conv2"
+        )(y)
         y = self.norm(name="bn2")(y)
         if residual.shape != y.shape:
             residual = self.conv(
@@ -133,64 +116,28 @@ class BasicBlock(nn.Module):
 
 
 class Bottleneck(nn.Module):
-    """1x1 → 3x3(stride) → 1x1(x4) residual block (ResNet-50/101/152, v1.5).
-
-    `fused_tail=True` computes BOTH interior normalize passes through Pallas
-    fused kernels (models/fused_block.py): bn1→relu→conv2 (3x3; stride-1
-    mids AND the stride-2 stage-first blocks) and bn2→relu→conv3 (1x1, all
-    blocks) — identical params/names/math, the normalized activations never
-    materialize in HBM.
-    Engages the kernels on TPU only; incompatible with SyncBN (callers gate
-    on that)."""
+    """1x1 → 3x3(stride) → 1x1(x4) residual block (ResNet-50/101/152, v1.5)."""
 
     filters: int
     strides: int = 1
     conv: ModuleDef = nn.Conv
-    norm: ModuleDef = nn.BatchNorm
+    norm: ModuleDef = BatchNorm
     expansion: int = 4
-    fused_tail: bool = False
-    bn_momentum: float = 0.9
-    dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         residual = x
         y = self.conv(self.filters, (1, 1), name="conv1")(x)
-        if self.fused_tail:
-            from moco_tpu.models.fused_block import (
-                fused_bn_relu_conv2,
-                fused_bn_relu_conv2_s2,
-                fused_bn_relu_conv3,
-                norm_train_flag,
-            )
-
-            train = norm_train_flag(self.norm)
-            # interior fusion #2: bn1→relu→conv2 through the Pallas 3x3
-            # kernels — stride-1 mids and (since r4) the stride-2
-            # stage-first blocks
-            fuse2 = (fused_bn_relu_conv2 if self.strides == 1
-                     else fused_bn_relu_conv2_s2)
-            y = fuse2(
-                self, y, self.filters, train, self.bn_momentum, 1e-5,
-                self.dtype,
-            )
-        else:
-            y = self.norm(name="bn1")(y)
-            y = nn.relu(y)
-            # explicit pad 1: torchvision-symmetric (see BasicBlock note)
-            y = self.conv(
-                self.filters, (3, 3), (self.strides, self.strides),
-                padding=[(1, 1), (1, 1)], name="conv2",
-            )(y)
-        if self.fused_tail:
-            y = fused_bn_relu_conv3(
-                self, y, self.filters * self.expansion, train,
-                self.bn_momentum, 1e-5, self.dtype,
-            )
-        else:
-            y = self.norm(name="bn2")(y)
-            y = nn.relu(y)
-            y = self.conv(self.filters * self.expansion, (1, 1), name="conv3")(y)
+        y = self.norm(name="bn1")(y)
+        y = nn.relu(y)
+        # explicit pad 1: torchvision-symmetric (see BasicBlock note)
+        y = self.conv(
+            self.filters, (3, 3), (self.strides, self.strides),
+            padding=[(1, 1), (1, 1)], name="conv2",
+        )(y)
+        y = self.norm(name="bn2")(y)
+        y = nn.relu(y)
+        y = self.conv(self.filters * self.expansion, (1, 1), name="conv3")(y)
         y = self.norm(name="bn3")(y)
         if residual.shape != y.shape:
             residual = self.conv(
@@ -226,32 +173,19 @@ class ResNet(nn.Module):
                            # (identical math, ~4x MXU contraction depth);
                            # params/exports unchanged. Auto-skipped for odd
                            # input sizes.
-    fast_bn: bool = True   # FastBatchNorm: Pallas streaming BN reductions on
-                           # TPU (identical flax math/params off-TPU)
     remat: bool = False    # per-residual-block rematerialization: save only
                            # block boundaries, recompute internals in the
                            # backward — trades (underutilized) MXU FLOPs for
                            # HBM traffic on the memory-bound step. Identical
                            # numerics (same ops, re-executed).
-    fused_bn_conv: bool = False  # interior bn→relu→conv passes through the
-                                 # Pallas fused kernels: Bottleneck conv3
-                                 # tail + stride-1 conv2 mids, BasicBlock
-                                 # conv2 (same params; TPU-only engagement;
-                                 # ignored for SyncBN)
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         conv = partial(
             nn.Conv, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32
         )
-        if self.fast_bn:
-            from moco_tpu.models.fast_bn import FastBatchNorm
-
-            norm_cls = FastBatchNorm
-        else:
-            norm_cls = nn.BatchNorm
         norm = partial(
-            norm_cls,
+            BatchNorm,
             use_running_average=not train,
             momentum=self.bn_momentum,
             epsilon=1e-5,
@@ -287,19 +221,6 @@ class ResNet(nn.Module):
             x = nn.relu(x)
             x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
 
-        block_kwargs = {}
-        if (
-            self.fused_bn_conv
-            and self.block_cls in (Bottleneck, BasicBlock)
-            and self.bn_cross_replica_axis is None
-            # engage on TPU only: the CPU fallback inside the fused tail is
-            # mathematically equal but uses the closed-form BN backward,
-            # while off-TPU goldens pin flax-autodiff numerics bit-exactly
-            and jax.default_backend() == "tpu"
-        ):
-            block_kwargs = dict(
-                fused_tail=True, bn_momentum=self.bn_momentum, dtype=self.dtype
-            )
         block_cls = nn.remat(self.block_cls) if self.remat else self.block_cls
         for i, num_blocks in enumerate(self.stage_sizes):
             for j in range(num_blocks):
@@ -310,7 +231,6 @@ class ResNet(nn.Module):
                     conv=conv,
                     norm=norm,
                     name=f"layer{i + 1}_{j}",
-                    **block_kwargs,
                 )(x)
 
         x = jnp.mean(x, axis=(1, 2))  # global average pool → [B, feat_dim]

@@ -90,7 +90,6 @@ def build_encoder(config: PretrainConfig):
                 dtype=dtype,
                 bn_cross_replica_axis=DATA_AXIS if config.sync_bn else None,
                 remat=config.remat,
-                fused_bn_conv=config.fused_bn_conv,
             )
         return V3Model(backbone, embed_dim=config.embed_dim)
     if config.arch.startswith("vit"):
@@ -107,7 +106,6 @@ def build_encoder(config: PretrainConfig):
         dtype=dtype,
         bn_cross_replica_axis=DATA_AXIS if config.sync_bn else None,
         remat=config.remat,
-        fused_bn_conv=config.fused_bn_conv,
     )
 
 

@@ -1,12 +1,9 @@
 """Shared assembly + timing for the step-mode benchmark program.
 
 One definition of "the benchmark" — the fused aug+train-step program built
-the way the train driver builds it — used by `bench.py`'s step mode,
-`chip_smoke.py`, `tools/_tpu_validate.py`, and `tools/_perf_ab.py`. Before
-r5 each of those carried its own near-identical copy of this ~25-line
-block, which is exactly how an A/B tool silently stops timing the same
-program the bench publishes (review, r5). Every hyperparameter comes from
-the config; the callers only choose WHICH config.
+the way the train driver builds it — used by `bench.py`'s step mode and
+`chip_smoke.py`. Every hyperparameter comes from the config; the callers
+only choose WHICH config.
 
 Timing semantics:
 - rounds end in `float(loss)`: a device→host read of a step output, so the

@@ -80,7 +80,6 @@ def test_midepoch_resume_no_replay(mesh8, tmp_path):
     np.testing.assert_array_equal(np.asarray(state_a.queue), np.asarray(state_b.queue))
 
 
-@pytest.mark.slow
 def test_imagefolder_through_driver(mesh8, tmp_path):
     """Real-data path: JPEG tree → (native or PIL) staging → device aug →
     step. Images are written per class from distinct base colors so the
@@ -121,7 +120,6 @@ def test_imagefolder_through_driver(mesh8, tmp_path):
     assert np.isfinite(metrics["loss"])
 
 
-@pytest.mark.slow
 def test_steps_per_epoch_clamped_to_loader(mesh8):
     """A steps_per_epoch above what the dataset can yield used to silently
     truncate epochs (and stretch the lr schedule); it now clamps to the
@@ -136,7 +134,6 @@ def test_steps_per_epoch_clamped_to_loader(mesh8):
     assert int(state.step) == 2 * 8  # 2 real epochs of the 8 real batches
 
 
-@pytest.mark.slow
 def test_knn_monitor_synthetic_texture_val_split(mesh8):
     """synthetic_texture gets a held-out-seed val split (fixed class tiles
     keep the label space aligned across seeds): the monitor reports real

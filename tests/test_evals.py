@@ -48,7 +48,6 @@ def test_load_frozen_backbone_arch_mismatch(exported_ckpt):
         load_frozen_backbone(config)
 
 
-@pytest.mark.slow
 def test_lincls_end_to_end(mesh8, exported_ckpt):
     """Probe on RANDOM frozen features of clusterable data still beats
     chance (random projections are linearly separable enough), proving the
@@ -60,7 +59,6 @@ def test_lincls_end_to_end(mesh8, exported_ckpt):
     assert fc["w"].shape == (32, 10)
 
 
-@pytest.mark.slow
 def test_knn_eval_end_to_end(exported_ckpt):
     config = eval_config(exported_ckpt, knn_k=20)
     acc = run_knn(config)
@@ -102,7 +100,6 @@ def test_v3_backbone_dialect_roundtrip(tmp_path):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.slow
 def test_lincls_checkpoint_resume(mesh8, exported_ckpt, tmp_path):
     """Probe checkpointing + --resume auto (the reference's main_lincls
     saves fc/optimizer/epoch/best every epoch)."""
@@ -124,7 +121,6 @@ def test_lincls_checkpoint_resume(mesh8, exported_ckpt, tmp_path):
         train_lincls(cfg.replace(ckpt_dir="", resume="auto"), mesh8, max_steps=1)
 
 
-@pytest.mark.slow
 def test_lincls_evaluate_only(mesh8, exported_ckpt, tmp_path):
     """--evaluate (reference -e): validate the resumed probe, no training —
     the returned acc matches the training run's last validation, and the

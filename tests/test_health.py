@@ -551,7 +551,6 @@ def test_collapse_drill_e2e(mesh8, tmp_path):
 
 
 @pytest.mark.chaos
-@pytest.mark.slow
 def test_collapse_rollback_soak_exhausts_budget(mesh8, tmp_path):
     """The opt-in rollback path under a PERSISTENT collapse: every
     rollback restores a pre-collapse checkpoint, the wedged-momentum

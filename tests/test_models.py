@@ -23,25 +23,25 @@ def r18_vars():
     return model, v
 
 
-def test_resnet18_param_count_matches_torchvision():
-    # ImageNet stem so the structure matches torchvision exactly
+@pytest.mark.parametrize("arch, torchvision_total", [
+    ("resnet18", 11_689_512),
+    ("resnet34", 21_797_672),
+    ("resnet50", 25_557_032),
+    ("resnet101", 44_549_160),
+    ("resnet152", 60_192_808),
+])
+def test_param_count_matches_torchvision(arch, torchvision_total):
+    """Every registry entry, ImageNet stem, against torchvision's published
+    total with its 1000-way fc swapped for the 128-d head."""
+    from moco_tpu.models.resnet import FEATURE_DIMS
+
     v = jax.eval_shape(
-        lambda: ResNet18(num_classes=128).init(
+        lambda: build_resnet(arch, num_classes=128).init(
             jax.random.key(0), jnp.zeros((1, 224, 224, 3)), train=False
         )
     )
-    expected = 11_689_512 - (512 * 1000 + 1000) + (512 * 128 + 128)
-    assert _count(v["params"]) == expected
-
-
-def test_resnet50_param_count_matches_torchvision():
-    v = jax.eval_shape(
-        lambda: ResNet50(num_classes=128).init(
-            jax.random.key(0), jnp.zeros((1, 224, 224, 3)), train=False
-        )
-    )
-    expected = 25_557_032 - (2048 * 1000 + 1000) + (2048 * 128 + 128)
-    assert _count(v["params"]) == expected
+    d = FEATURE_DIMS[arch]
+    assert _count(v["params"]) == torchvision_total - (d * 1000 + 1000) + (d * 128 + 128)
 
 
 def test_mlp_head_param_count():
@@ -184,3 +184,29 @@ def test_remat_blocks_identical_outputs_and_grads():
     gb = jax.grad(loss)(v["params"], rm)
     for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _package_sources(*subdirs):
+    import pathlib
+
+    import moco_tpu
+
+    root = pathlib.Path(moco_tpu.__file__).parent
+    for sub in subdirs:
+        for path in sorted((root / sub).rglob("*.py")):
+            yield path.relative_to(root).as_posix(), path.read_text(encoding="utf-8")
+
+
+def test_the_package_has_two_mosaic_kernels():
+    """PERF.md's layers name two kernel layers; a third `pallas_call` anywhere
+    in the package is a new layer and says so there first."""
+    holders = {name for name, text in _package_sources("") if "pallas_call" in text}
+    assert holders == {"ops/pallas_blur.py", "ops/pallas_attention.py"}
+
+
+def test_no_switch_is_read_from_the_environment_in_models_and_ops():
+    """What a model or an op computes follows from its arguments and the
+    backend: the program a test pins is the program the chip runs."""
+    readers = [name for name, text in _package_sources("models", "ops")
+               if "environ" in text.replace("environment", "") or "getenv" in text]
+    assert readers == []

@@ -4,9 +4,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from moco_tpu.data.augment import augment_batch, v2_aug_config
-from moco_tpu.ops.pallas_blur import blur_weights, gaussian_blur_batch
+from moco_tpu.data.augment import _use_pallas_blur, augment_batch, v2_aug_config
+from moco_tpu.ops.pallas_blur import blur_radius, blur_weights, gaussian_blur_batch
 
 
 def test_identity_kernel_is_noop():
@@ -71,3 +72,36 @@ def test_sharded_two_crops_matches_unsharded(mesh8):
     q_sh, k_sh = fn(imgs, key)
     np.testing.assert_allclose(np.asarray(q_sh), np.asarray(q_ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(k_sh), np.asarray(k_ref), atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_blur_kernel_lowers_for_tpu_at_cell_shapes(dtype):
+    """`[128, 224, 224, 3]` with the 23 taps of a 224-pixel crop, the one Mosaic
+    kernel of `r50-v2-f32.synthetic` (and, in bfloat16, of the image presets),
+    exported for the TPU platform from the CPU: a tracing or typing break of
+    the kernel fails here, not on the chip."""
+    radius = blur_radius(224)
+    images = jax.ShapeDtypeStruct((128, 224, 224, 3), jnp.dtype(dtype))
+    weights = jax.ShapeDtypeStruct((128, 2 * radius + 1), jnp.float32)
+    exported = jax.export.export(
+        jax.jit(lambda x, w: gaussian_blur_batch(x, w, radius)), platforms=["tpu"]
+    )(images, weights)
+    module = exported.mlir_module()
+    assert module.count("tpu_custom_call") == 1 and 'kernel_name = "_blur_kernel"' in module
+    assert [(o.shape, str(o.dtype)) for o in exported.out_avals] == [(images.shape, dtype)]
+
+
+def test_blur_gate_is_config_and_backend_only(monkeypatch):
+    """`AugConfig.pallas_blur` (auto | on | off) and the backend decide, as
+    `attention_plan` does for the other kernel; the environment is not asked
+    (the kill switches of `utils/envflags.py` went with it)."""
+    cfg = v2_aug_config(out_size=16)
+    monkeypatch.setenv("MOCO_TPU_DISABLE_PALLAS", "1")
+    monkeypatch.setenv("MOCO_TPU_DISABLE_PALLAS_BLUR", "1")
+    assert not _use_pallas_blur(cfg)                                  # auto, on the tests' CPU
+    assert _use_pallas_blur(cfg._replace(pallas_blur="on"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _use_pallas_blur(cfg)
+    assert not _use_pallas_blur(cfg._replace(pallas_blur="off"))
+    assert not _use_pallas_blur(cfg._replace(blur_prob=0.0))
+    assert not _use_pallas_blur(cfg._replace(solarize_prob=0.2))     # v3's second view

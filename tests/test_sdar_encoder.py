@@ -312,3 +312,19 @@ def test_the_cli_runs_the_text_preset(tmp_path):
     steps = [r for r in records if r.get("kind") == "step"]
     assert len(steps) == 3 and "moe_assign_per_token" in steps[0]["health"]
     assert all(r.get("mfu", 1) != 0 for r in steps)
+
+
+@pytest.mark.parametrize("name, devices", [
+    ("text-moco-v2-sdar", 1),            # the preset, at its configuration's 4 layers
+    ("cell:sdar-30b-a3b-ep8", 1),        # the benchmark's own configuration
+    ("cell:sdar-30b-a3b-ep8", 8),        # the key gather and gradient sync across devices
+])
+def test_step_program_lowers_for_tpu(name, devices, mesh8):
+    """The token program at the published widths exports for the TPU platform
+    from the CPU and reaches `attn`'s two Mosaic kernels 16 times (4 layers:
+    key forward, query forward, its rematerialised twin, backward) and no other."""
+    from step_lowering import cell_config, census_for_tpu
+
+    layers = cell_config("sdar-30b-a3b-ep8").num_hidden_layers
+    assert census_for_tpu(name, devices, mesh8, batch_size=8, num_hidden_layers=layers) == {
+        "_fwd_kernel": 12, "_bwd_kernel": 4}

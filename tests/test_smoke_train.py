@@ -58,7 +58,6 @@ def test_moco_v1_smoke_loss_falls_knn_above_chance(trained):
         assert any("tfevents" in f for f in tb_files), tb_files
 
 
-@pytest.mark.slow
 def test_lincls_on_trained_export(trained, mesh8):
     """Probe on PRETRAINED features must beat chance comfortably — the full
     pretrain→export→surgery→probe pipeline (config 4 on config 1's output)."""
@@ -129,7 +128,6 @@ def test_knn_every_epochs_zero_rejected(mesh8):
         train(config, mesh8)
 
 
-@pytest.mark.slow
 def test_knn_on_trained_export(trained):
     from moco_tpu.evals.knn import run_knn
 

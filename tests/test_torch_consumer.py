@@ -136,7 +136,6 @@ def _load_torch(model, flat):
     return model.eval()
 
 
-@pytest.mark.slow
 def test_torch_resnet18_consumes_export():
     """`module.encoder_q.`-style export → real torch ResNet-18, strict names,
     matching eval forward (the lincls surgery consumer contract)."""
@@ -160,7 +159,6 @@ def test_torch_resnet18_consumes_export():
     np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_torch_bottleneck_mlp_consumes_export():
     """Bottleneck + v2 MLP head (fc.0/fc.2) through the same contract."""
     from moco_tpu.checkpoint import resnet_to_torchvision
@@ -186,7 +184,6 @@ def test_torch_bottleneck_mlp_consumes_export():
     np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_torch_consumes_detectron2_pkl():
     """pkl → rename Detectron2 names back to torchvision → torch backbone
     forward matches the flax feature output (value-level consumer check the
@@ -299,7 +296,6 @@ class TViT(torch.nn.Module):
         return self.norm(x)[:, 0]
 
 
-@pytest.mark.slow
 def test_torch_vit_consumes_timm_export():
     """vit_to_timm export → real torch fused-qkv ViT (timm layout) → class
     token feature matches the flax forward (moco-v3 lincls consumer)."""

@@ -192,3 +192,20 @@ def test_lr_follows_step_schedule(setup):
         lrs.append(float(metrics["lr"]))
     assert np.allclose(lrs[0], 0.05)
     assert np.allclose(lrs[8], 0.005)  # step 8 = epoch 2 → first milestone
+
+
+@pytest.mark.parametrize("name, devices, kernels", [
+    ("cifar10-moco-v1", 1, {}),                       # BasicBlock, the v1 recipe: no blur
+    ("imagenet-moco-v1", 1, {}),
+    ("imagenet-moco-v2", 1, {"_blur_kernel": 2}),     # one kernel, both crops
+    ("imagenet-moco-v2-8chip", 1, {"_blur_kernel": 2}),
+    ("cell:r50-v2-f32", 1, {"_blur_kernel": 2}),      # the benchmark's float32 cell
+    ("imagenet-moco-v2", 8, {"_blur_kernel": 2}),     # ShuffleBN gathers, key gather, grad sync
+])
+def test_step_program_lowers_for_tpu(name, devices, kernels, mesh8):
+    """Every v1 / v2 image program the repo says it supports exports for the
+    TPU platform from the CPU, uint8 staging canvas to donated queue update,
+    and holds the blur's Mosaic kernel and no other."""
+    from step_lowering import census_for_tpu
+
+    assert census_for_tpu(name, devices, mesh8, batch_size=16) == kernels

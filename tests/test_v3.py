@@ -242,41 +242,16 @@ def test_v3_r50_lars_step_on_mesh(mesh8):
     assert ratio < 0.5 or ratio > 2.0, ratio
 
 
-@pytest.mark.slow
-def test_v3_vits_full_step_lowers_for_tpu():
-    """Config 5's whole benchmark program (asymmetric v3 aug pair with the
-    Pallas blur, ViT-S with remat, symmetric loss, AdamW) exports for the
-    TPU platform from CPU — hardware-free lowering assurance like the v2
-    pin in test_fused_conv."""
-    import unittest.mock as mock
+@pytest.mark.parametrize("name, devices", [
+    ("imagenet-moco-v3-vits", 1),
+    ("imagenet-moco-v3-vitb", 1),      # remat
+    ("imagenet-moco-v3-r50", 1),       # LARS over the ResNet
+    ("imagenet-moco-v3-vits", 8),      # the in-batch key gather across devices
+])
+def test_step_program_lowers_for_tpu(name, devices, mesh8):
+    """The v3 programs (asymmetric pair of views, symmetric loss, AdamW or
+    LARS) export for the TPU platform from the CPU. The blur's Mosaic kernel
+    serves view 1 only: view 2 solarizes, which keeps the in-pipeline blur."""
+    from step_lowering import census_for_tpu
 
-    from moco_tpu.config import get_preset
-    from moco_tpu.data.augment import build_two_crops_sharded, v3_aug_configs, with_dtype
-    from moco_tpu.parallel.mesh import create_mesh
-    from moco_tpu.train_step import (
-        build_encoder, build_fused_step, build_optimizer, build_train_step,
-    )
-    from moco_tpu.v3_step import create_v3_train_state
-
-    Bv = 256
-    config = get_preset("imagenet-moco-v3-vits").replace(batch_size=Bv, remat=True)
-    mesh = create_mesh(1)
-    # the backend patch routes the aug's blur gate onto the Pallas path;
-    # fast_bn is not part of the ViT program (LayerNorm backbone)
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        model = build_encoder(config)
-        tx, sched = build_optimizer(config, 1000)
-        state = jax.eval_shape(lambda: create_v3_train_state(
-            jax.random.key(0), model, tx, (Bv, 224, 224, 3)))
-        step_fn = build_train_step(config, model, tx, mesh, 1000, sched)
-        two = build_two_crops_sharded(
-            with_dtype(v3_aug_configs(224), "bfloat16"), mesh
-        )
-        fused = build_fused_step(step_fn, two, jax.random.key(1))
-        imgs = jax.ShapeDtypeStruct((Bv, 252, 252, 3), jnp.uint8)
-        ext = jax.ShapeDtypeStruct((Bv, 3), jnp.int32)
-        exp = jax.export.export(fused, platforms=["tpu"])(
-            state, imgs, ext, jax.ShapeDtypeStruct((), jnp.int32)
-        )
-        # the Pallas blur is the one custom kernel on the ViT path
-        assert exp.mlir_module().count("tpu_custom_call") >= 1
+    assert census_for_tpu(name, devices, mesh8, batch_size=16) == {"_blur_kernel": 1}

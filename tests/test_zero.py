@@ -113,7 +113,6 @@ def test_zero_step_identical_numerics_and_smaller_footprint(mesh8):
                 jax.tree_util.keystr(path), leaf.sharding.spec, want.spec)
 
 
-@pytest.mark.slow
 def test_zero_through_driver(mesh8):
     from moco_tpu.train import train
 
