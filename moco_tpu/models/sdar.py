@@ -35,13 +35,17 @@ layer trains its router.
 float32 whatever `dtype` is: the router's product and softmax, the
 attention softmax, every RMSNorm, the head. Parameters are float32.
 
-Where the attention softmax happens: on a TPU, with `head_dim` and the view's
-length multiples of 128 and `block_length` a divisor of 128 (the published
-arch at 512 tokens), in VMEM, inside `ops/pallas_attention.py`'s kernels; the
-scores never reach memory and the tiles the mask empties are not computed. Any
-other backend or shape (`sdar_tiny`, every CPU test) takes `einsum_attention`,
-the float32 scores through memory. One rule, `attention_plan`, on what the
-code can observe; the run's `setup` event says which path was built.
+Where the attention softmax happens, and q and k's norm and rotary: on a TPU,
+with `head_dim` and the view's length multiples of 128 and `block_length` a
+divisor of 128 (the published arch at 512 tokens), in VMEM, inside
+`ops/pallas_attention.py`'s kernels; the scores never reach memory, the tiles
+the mask empties are not computed, and q, k, v and o keep the projections'
+`[B, L, heads * head_dim]` from `Dense` to `Dense` (`norm_rotary` reads a
+projection's output once and writes the finished q or k once). Any other
+backend or shape (`sdar_tiny`, every CPU test) takes `RMSNorm`, `rotary` and
+`einsum_attention` on `[B, L, heads, head_dim]`, the float32 scores through
+memory: the kernels' oracle. One rule, `attention_plan`, on what the code can
+observe; the run's `setup` event says which path was built.
 """
 
 from __future__ import annotations
@@ -53,7 +57,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from moco_tpu.ops.pallas_attention import attention_plan, block_causal_attention
+from moco_tpu.ops.pallas_attention import (attention_plan, block_causal_attention,
+                                            norm_rotary)
 from moco_tpu.telemetry import scopes
 
 # the published sizes by arch (config.json's keys in the comments); the
@@ -165,6 +170,17 @@ def einsum_attention(q: jax.Array, k: jax.Array, v: jax.Array, block_length: int
     return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, length, heads, dim)
 
 
+class HeadScale(nn.Module):
+    """An `RMSNorm`'s parameter without its arithmetic, under the same name in
+    the tree: where `norm_rotary` does the norm, it takes the scale from here."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.dim,), jnp.float32)
+
+
 class Attention(nn.Module):
     heads: int
     kv_heads: int
@@ -177,20 +193,28 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, h):
         b, length, _ = h.shape
+        fused = attention_plan(length, self.head_dim, self.block_length)["path"] == "fused"
 
         def proj(name, n):
             y = nn.Dense(n * self.head_dim, use_bias=False, dtype=self.dtype,
                          param_dtype=jnp.float32, name=name)(h)
-            return y.reshape(b, length, n, self.head_dim)
+            # fused, a head stays `head_dim` lanes of the projection's last axis
+            # from here to the `o` projection: nothing is reshaped, nothing relaid
+            return y if fused else y.reshape(b, length, n, self.head_dim)
 
         q, k, v = proj("q", self.heads), proj("k", self.kv_heads), proj("v", self.kv_heads)
-        q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta).astype(self.dtype)
-        k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta).astype(self.dtype)
-        if attention_plan(length, self.head_dim, self.block_length)["path"] == "fused":
-            o = block_causal_attention(q, k, v, block_length=self.block_length)
+        if fused:
+            # float32 in as `RMSNorm` casts: see `norm_rotary`
+            q, k = (norm_rotary(x.astype(jnp.float32), HeadScale(self.head_dim, name=name)(),
+                                dtype=self.dtype, theta=self.rope_theta, eps=self.eps)
+                    for x, name in ((q, "q_norm"), (k, "k_norm")))
+            o = block_causal_attention(q, k, v, heads=self.heads, kv_heads=self.kv_heads,
+                                       block_length=self.block_length)
         else:
+            q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta).astype(self.dtype)
+            k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta).astype(self.dtype)
             o = einsum_attention(q, k, v, self.block_length)
-        o = o.reshape(b, length, self.heads * self.head_dim)
+            o = o.reshape(b, length, self.heads * self.head_dim)
         return nn.Dense(h.shape[-1], use_bias=False, dtype=self.dtype,
                         param_dtype=jnp.float32, name="o")(o)
 

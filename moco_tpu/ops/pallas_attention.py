@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: block-causal grouped-query attention with the scores in VMEM.
+"""Pallas TPU kernels: block-causal grouped-query attention with the scores in
+VMEM, and q and k's way to it from their projections.
 
 `models/sdar.py::Attention` as plain einsums writes a float32 `[B, heads, L, L]`
 score tensor to memory, masks it, and reads it back for every pass of the
@@ -21,15 +22,24 @@ weights' sum are multiplications by the reciprocal.
 The forward pass of a differentiated call also writes the rows' log-sum-exp
 (`[B, heads, 1, L]` float32). The backward pass recomputes the weights from q,
 k and it, with keys on sublanes and queries on lanes (the log-sum-exp and
-`delta = sum(o * do)` are then lane rows, and two of the three transposed
-products need no transpose), and forms dv, dp, ds = p (dp - delta), dq and dk
-in VMEM; dk and dv accumulate in float32 over the `group` query heads that
-share a key/value head. ds meets k and q as `dtype`, which is what the TPU's
-default precision makes of the einsum path's float32 ds.
+`delta = sum(o * do)`, which it forms from o and do in float32, are then lane
+rows, and two of the three transposed products need no transpose), and forms
+dv, dp, ds = p (dp - delta), dq and dk in VMEM; dk and dv accumulate in float32
+over the `group` query heads that share a key/value head. ds meets k and q as
+`dtype`, which is what the TPU's default precision makes of the einsum path's
+float32 ds.
 
 A head is `head_dim` lanes of the projections' own last axis
-(`[B, L, heads * head_dim]`), so nothing is transposed on the way in or out.
-Outputs carry the inputs' `vma`: the call type-checks inside a `shard_map`
+(`[B, L, heads * head_dim]`), and q, k, v and o are never seen in another shape:
+`norm_rotary` (one kernel and its transpose) does the per-head RMSNorm, the
+rotate-half rotary and the cast of q and of k on that layout, a head's lanes at
+a time in VMEM and in float32, so that from a projection's product to the `o`
+projection's operand nothing is reshaped and XLA has no layout to change. The
+two `optimization_barrier`s that held XLA's change of layout at the finished
+`dtype` tensors (PR 28) are gone with it; the compiled step holds no copy of a
+q- or k-sized tensor (`PERF.md` section 6, PR 30).
+
+Outputs carry the inputs' `vma`: the calls type-check inside a `shard_map`
 region with `check_vma` on. `interpret=True` runs the same code on the CPU
 (outside any `shard_map`: see `data/augment.py::build_two_crops_sharded`).
 """
@@ -50,20 +60,25 @@ TILE = 128
 # one head a program read 10 % slower (1 024 grid steps a call for 128; my chip
 # run, PR 28)
 HEADS_PER_PROGRAM = 8
+# rows of a batch row that a program of `norm_rotary` takes, at most
+PREP_ROWS = 4 * TILE
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel"))
 
 
 def attention_plan(length: int, head_dim: int, block_length: int,
                    backend: str | None = None) -> dict:
-    """Which path attention takes for these shapes, and how many `TILE` x
-    `TILE` score tiles it computes and skips: the `attn` block of the `setup`
-    event. The kernel needs a TPU, whole lane tiles for a head, whole q tiles,
-    and blocks that do not straddle a tile."""
+    """Which path attention takes for these shapes, how many `TILE` x `TILE`
+    score tiles it computes and skips, and who prepares q and k for it
+    (`qk_prep`: `norm_rotary` where attention is fused, XLA elsewhere): the
+    `attn` block of the `setup` event. The kernels need a TPU, whole lane tiles
+    for a head, whole q tiles, and blocks that do not straddle a tile."""
     side = -(-length // TILE)
     fused = ((backend or jax.default_backend()) == "tpu" and head_dim % TILE == 0
              and length % TILE == 0 and TILE % block_length == 0)
     return {"path": "fused" if fused else "einsum", "tiles": side * side,
-            "tiles_skipped": side * (side - 1) // 2 if fused else 0}
+            "tiles_skipped": side * (side - 1) // 2 if fused else 0,
+            "qk_prep": "fused" if fused else "xla"}
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -113,7 +128,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, dim, block_length):
                 lse_ref[0][j, :, rows] = lse.T[:1, :]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
                 dk_acc, dv_acc, *, dim, block_length):
     """As `_fwd_kernel`, with the rest of the group on grid axis 2: dk and dv
     accumulate over both. Scores are `[keys, queries]` here."""
@@ -131,7 +146,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         for t in range(q_ref.shape[0] // TILE):
             rows = slice(t * TILE, (t + 1) * TILE)
             q, do = q_ref[rows, head], do_ref[rows, head]
-            lse, delta = lse_ref[j, :, rows], delta_ref[j, :, rows]
+            lse = lse_ref[j, :, rows]
+            delta = jnp.sum(o_ref[rows, head].astype(jnp.float32) * do.astype(jnp.float32),
+                            -1, keepdims=True)
+            delta = jnp.broadcast_to(delta, (TILE, TILE)).T[:1, :]    # as `lse`: a lane row
             dq = jnp.zeros(q.shape, jnp.float32)
             for keys in ([slice(0, t * TILE)] if t else []) + [rows]:
                 k, v = k_ref[keys, :], v_ref[keys, :]
@@ -151,18 +169,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flat(x):
-    """`[B, L, heads, D]` -> `[B, L, heads * D]`: a head is `D` lanes of the
-    projections' own last axis."""
-    return x.reshape(*x.shape[:2], -1)
-
-
-def _grid_and_specs(q, k):
+def _grid_and_specs(q, k, heads, kv_heads):
     """The grid `(batch, kv_head, programs a group)` and its block specs: `n`
     query heads' and a key/value head's lanes of `[B, L, heads * D]`, and the
     query heads' rows of `[B, heads, 1, L]`."""
-    b, length, heads, dim = q.shape
-    kv_heads = k.shape[2]
+    b, length, _ = q.shape
+    dim = q.shape[2] // heads
     n = math.gcd(heads // kv_heads, HEADS_PER_PROGRAM)
     per = heads // kv_heads // n
     q_spec = pl.BlockSpec((None, length, n * dim), lambda b, h, g: (b, 0, h * per + g))
@@ -178,81 +190,214 @@ def _out_shapes(inputs, *shapes_and_dtypes):
     return [jax.ShapeDtypeStruct(shape, dtype, vma=vma) for shape, dtype in shapes_and_dtypes]
 
 
-# jitted, as the kernels beside this one: the step calls each of the three
-# programs four times or eight, and an inner jit is traced and lowered to Mosaic
-# once a signature where a bare `pallas_call` is lowered at every call (11 s of
-# a warm start's 71: my chip run, PR 28)
-@functools.partial(jax.jit, static_argnames=("block_length", "interpret", "with_lse"))
-def _forward(q, k, v, block_length, interpret, with_lse):
-    grid, q_spec, kv_spec, row_spec = _grid_and_specs(q, k)
-    qf, kf, vf = _flat(q), _flat(k), _flat(v)
-    b, length, heads, dim = q.shape
+# jitted, as the kernels beside this one: the step calls each program four
+# times or more, and an inner jit is traced and lowered to Mosaic once a
+# signature where a bare `pallas_call` is lowered at every call (11 s of a warm
+# start's 71: my chip run, PR 28)
+@functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "block_length", "interpret",
+                                             "with_lse"))
+def _forward(q, k, v, heads, kv_heads, block_length, interpret, with_lse):
+    grid, q_spec, kv_spec, row_spec = _grid_and_specs(q, k, heads, kv_heads)
+    b, length, _ = q.shape
     n_out = 2 if with_lse else 1
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, dim=dim, block_length=block_length),
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, dim=q.shape[2] // heads, block_length=block_length),
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec][:n_out],
-        out_shape=_out_shapes((q, k, v), (qf.shape, q.dtype),
+        out_shape=_out_shapes((q, k, v), (q.shape, q.dtype),
                               ((b, heads, 1, length), jnp.float32))[:n_out],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=_PARALLEL,
         interpret=interpret,
-    )(qf, kf, vf)
-    return (out[0].reshape(q.shape), *out[1:])
+    )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attention(q, k, v, block_length, interpret):
-    return _forward(q, k, v, block_length, interpret, with_lse=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attention(q, k, v, heads, kv_heads, block_length, interpret):
+    return _forward(q, k, v, heads, kv_heads, block_length, interpret, with_lse=False)[0]
 
 
-def _attention_fwd(q, k, v, block_length, interpret):
-    o, lse = _forward(q, k, v, block_length, interpret, with_lse=True)
+def _attention_fwd(q, k, v, heads, kv_heads, block_length, interpret):
+    o, lse = _forward(q, k, v, heads, kv_heads, block_length, interpret, with_lse=True)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(block_length, interpret, residuals, do):
-    return _backward(*residuals, do, block_length=block_length, interpret=interpret)
+def _attention_bwd(heads, kv_heads, block_length, interpret, residuals, do):
+    return _backward(*residuals, do, heads=heads, kv_heads=kv_heads, block_length=block_length,
+                     interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block_length", "interpret"))
-def _backward(q, k, v, o, lse, do, block_length, interpret):
-    grid, q_spec, kv_spec, row_spec = _grid_and_specs(q, k)
-    qf, kf, vf, dof = _flat(q), _flat(k), _flat(v), _flat(do)
-    delta = jnp.einsum("blhd,blhd->bhl", o.astype(jnp.float32), do.astype(jnp.float32))
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, dim=q.shape[3], block_length=block_length),
+@functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "block_length", "interpret"))
+def _backward(q, k, v, o, lse, do, heads, kv_heads, block_length, interpret):
+    grid, q_spec, kv_spec, row_spec = _grid_and_specs(q, k, heads, kv_heads)
+    dim = q.shape[2] // heads
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, dim=dim, block_length=block_length),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
         out_specs=[q_spec, kv_spec, kv_spec],
-        out_shape=_out_shapes((q, k, v, do), *((x.shape, x.dtype) for x in (qf, kf, vf))),
-        scratch_shapes=[pltpu.VMEM((k.shape[1], k.shape[3]), jnp.float32)] * 2,
+        out_shape=_out_shapes((q, k, v, do), *((x.shape, x.dtype) for x in (q, k, v))),
+        scratch_shapes=[pltpu.VMEM((q.shape[1], dim), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta[:, :, None, :])
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    )(q, k, v, o, do, lse)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def block_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, block_length: int,
+def block_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, heads: int,
+                           kv_heads: int, block_length: int,
                            interpret: bool = False) -> jax.Array:
     """softmax(q k^T / sqrt(D) under the block-causal mask) v.
 
-    q `[B, L, heads, D]`, k and v `[B, L, kv_heads, D]` in one dtype, after
-    norm and rotary; query head `h` reads key/value head `h // (heads //
-    kv_heads)`. Returns `[B, L, heads, D]` in that dtype. The shapes are those
-    `attention_plan` sends here: `D` and `L` multiples of 128, `block_length`
-    a divisor of 128.
+    q `[B, L, heads * D]`, k and v `[B, L, kv_heads * D]` in one dtype, as the
+    projections and `norm_rotary` leave them; query head `h` reads key/value
+    head `h // (heads // kv_heads)`. Returns `[B, L, heads * D]` in that dtype.
+    The shapes are those `attention_plan` sends here: `D` and `L` multiples of
+    128, `block_length` a divisor of 128."""
+    return _attention(q, k, v, heads, kv_heads, block_length, interpret)
 
-    The barriers hold the change of layout (`[B, L, H, D]` tiled over `(H, D)`
-    to `[B, L, H * D]` tiled over `(L, lanes)`) at the finished `dtype` tensors,
-    one copy each way, and the cotangents' likewise. Without them XLA moves it up
-    into norm and rotary's float32 operands, three copies of twice the size a
-    tensor: the layer's forward and backward read 35.7 ms without and 27.5 with
-    (my chip run, PR 28; the einsums 40.5)."""
-    q, k, v = lax.optimization_barrier((q, k, v))
-    return lax.optimization_barrier(_attention(q, k, v, block_length, interpret))
+
+# ---------------------------------------------------------------------------
+# q and k on their way from the projection to the kernel above
+# ---------------------------------------------------------------------------
+
+
+def _rotary_tables(length: int, dim: int, theta: float):
+    """`cos` and `sin` of `models/sdar.py::rotary`'s angles as `[L, D]` float32,
+    the rotate-half sign folded into the sine: `concat(-sin, sin)`."""
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
+
+
+def _prep_kernel(y_ref, scale_ref, cos_ref, sin_ref, o_ref, *, eps):
+    """Rows of one batch row and whole heads: y/o refs `[rows, n * D]`, the
+    tables' refs `[rows, D]`, scale `[1, D]`. float32 until the one cast, to
+    o's dtype."""
+    dim = cos_ref.shape[1]
+    scale = scale_ref[...]
+    for t in range(y_ref.shape[0] // TILE):
+        rows = slice(t * TILE, (t + 1) * TILE)
+        cos, sin = cos_ref[rows, :], sin_ref[rows, :]
+        for j in range(y_ref.shape[1] // dim):
+            head = slice(j * dim, (j + 1) * dim)
+            x = y_ref[rows, head].astype(jnp.float32)
+            r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+            xn = x * r * scale
+            # the other half of the head is `dim // 2` lanes away, either way round
+            out = xn * cos + pltpu.roll(xn, dim // 2, 1) * sin
+            o_ref[rows, head] = out.astype(o_ref.dtype)
+
+
+def _prep_bwd_kernel(y_ref, g_ref, scale_ref, cos_ref, sin_ref, dx_ref, dscale_ref, *, eps):
+    """The transpose of `_prep_kernel` at the same blocks; `r` is computed
+    again from y. dscale ref `[8, D]`: this program's rows summed eight apart,
+    the rest of the sum is XLA's."""
+    dim = cos_ref.shape[1]
+    scale = scale_ref[...]
+    dscale = jnp.zeros(dscale_ref.shape, jnp.float32)
+    for t in range(y_ref.shape[0] // TILE):
+        rows = slice(t * TILE, (t + 1) * TILE)
+        cos, sin = cos_ref[rows, :], sin_ref[rows, :]
+        for j in range(y_ref.shape[1] // dim):
+            head = slice(j * dim, (j + 1) * dim)
+            x = y_ref[rows, head].astype(jnp.float32)
+            g = g_ref[rows, head].astype(jnp.float32)
+            r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+            # a roll by half the lanes is its own transpose
+            gn = g * cos + pltpu.roll(g * sin, dim // 2, 1)
+            u = x * r
+            dscale = dscale + jnp.sum((gn * u).reshape(-1, *dscale.shape), 0)
+            gx = gn * scale
+            dx = r * (gx - u * (r * jnp.mean(gx * x, -1, keepdims=True)))
+            dx_ref[rows, head] = dx.astype(dx_ref.dtype)
+    dscale_ref[...] = dscale
+
+
+def _prep_grid_and_specs(y, dim):
+    """The grid `(batch, row blocks, head groups)`, the block specs of y and of
+    a table, and the shape and spec of the scale's partial sums."""
+    b, length, width = y.shape
+    rows = math.gcd(length, PREP_ROWS)
+    n = math.gcd(width // dim, HEADS_PER_PROGRAM)
+    grid = (b, length // rows, width // (n * dim))
+    y_spec = pl.BlockSpec((None, rows, n * dim), lambda b, r, h: (b, r, h))
+    table_spec = pl.BlockSpec((rows, dim), lambda b, r, h: (r, 0))
+    scale_spec = pl.BlockSpec((1, dim), lambda b, r, h: (0, 0))
+    sums_spec = pl.BlockSpec((None, None, None, 8, dim), lambda b, r, h: (b, r, h, 0, 0))
+    return grid, y_spec, table_spec, scale_spec, (*grid, 8, dim), sums_spec
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "theta", "eps", "interpret"))
+def _prep_forward(y, scale, dtype, theta, eps, interpret):
+    dim = scale.shape[0]
+    grid, y_spec, table_spec, scale_spec, _, _ = _prep_grid_and_specs(y, dim)
+    return pl.pallas_call(
+        functools.partial(_prep_kernel, eps=eps),
+        grid=grid,
+        in_specs=[y_spec, scale_spec, table_spec, table_spec],
+        out_specs=y_spec,
+        out_shape=_out_shapes((y, scale), (y.shape, dtype))[0],
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="qk_norm_rotary",
+    )(y, scale[None, :], *_rotary_tables(y.shape[1], dim, theta))
+
+
+@functools.partial(jax.jit, static_argnames=("theta", "eps", "interpret"))
+def _prep_backward(y, g, scale, theta, eps, interpret):
+    dim = scale.shape[0]
+    grid, y_spec, table_spec, scale_spec, sums_shape, sums_spec = _prep_grid_and_specs(y, dim)
+    dx, sums = pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, eps=eps),
+        grid=grid,
+        in_specs=[y_spec, y_spec, scale_spec, table_spec, table_spec],
+        out_specs=[y_spec, sums_spec],
+        out_shape=_out_shapes((y, g, scale), (y.shape, g.dtype), (sums_shape, jnp.float32)),
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="qk_norm_rotary_bwd",
+    )(y, g, scale[None, :], *_rotary_tables(y.shape[1], dim, theta))
+    # dx leaves the kernel in the cotangent's dtype, as the transpose of the
+    # XLA path's `astype` would round it on its way into the projection
+    return dx.astype(y.dtype), jnp.sum(sums, (0, 1, 2, 3))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _norm_rotary(y, scale, dtype, theta, eps, interpret):
+    return _prep_forward(y, scale, dtype, theta, eps, interpret)
+
+
+def _norm_rotary_fwd(y, scale, dtype, theta, eps, interpret):
+    return _prep_forward(y, scale, dtype, theta, eps, interpret), (y, scale)
+
+
+def _norm_rotary_bwd(dtype, theta, eps, interpret, residuals, g):
+    y, scale = residuals
+    return _prep_backward(y, g, scale, theta, eps, interpret)
+
+
+_norm_rotary.defvjp(_norm_rotary_fwd, _norm_rotary_bwd)
+
+
+def norm_rotary(y: jax.Array, scale: jax.Array, *, dtype, theta: float, eps: float,
+                interpret: bool = False) -> jax.Array:
+    """`rotary(RMSNorm(eps)(y per head), theta).astype(dtype)` of
+    `models/sdar.py` on the projection's own output `[B, L, heads * D]`, heads
+    of `D = scale.shape[0]` lanes, positions 0..L-1: what q and k pass through
+    between their `Dense` and `block_causal_attention`. One read of y and one
+    write; the backward pass keeps y alone and reads it once more with the
+    cotangent. `scale` is the norm's `[D]` float32 parameter and gets its
+    gradient.
+
+    y comes as `RMSNorm` takes it, cast to float32: XLA's fusion of the
+    projection with that cast hands over the product's float32 accumulator
+    and not its rounding to `dtype` (my chip run, PR 30: the layer's output
+    and gradients read 8 - 14 % further from a float32 oracle with y in
+    bfloat16), and the cotangent of the cast is the rounding that the XLA
+    path's backward pass has there too."""
+    return _norm_rotary(y, scale, jnp.dtype(dtype), float(theta), float(eps), interpret)

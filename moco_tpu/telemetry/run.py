@@ -197,7 +197,8 @@ class RunTelemetry:
 
     def set_attn(self, plan: dict) -> None:
         """How a token encoder's attention was built (`ops/pallas_attention.py::
-        attention_plan`: the path, and the score tiles computed and skipped).
+        attention_plan`: the path, the score tiles computed and skipped, and who
+        prepares q and k: `qk_prep`).
         Static per program, so it rides the `setup` event and costs the step
         nothing."""
         self._attn = dict(plan)

@@ -1,7 +1,12 @@
 """ISSUE 28: the fused block-causal attention kernel (`ops/pallas_attention.py`)
-against the einsum path of `models/sdar.py` that it replaces on the TPU. On the
-CPU the kernel runs interpreted, outside any `shard_map`; inside one it is only
-traced (interpret-mode Pallas does not run there: `data/augment.py`)."""
+against the einsum path of `models/sdar.py` that it replaces on the TPU; ISSUE
+30: `norm_rotary`, the per-head RMSNorm, rotary and cast of q and k on the
+projections' own layout, against `rotary(RMSNorm(...))`. On the CPU the kernels
+run interpreted, outside any `shard_map`; inside one they are only traced
+(interpret-mode Pallas does not run there: `data/augment.py`)."""
+
+import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +36,20 @@ def inputs(shape, dtype, seed=0):
     return [jax.random.normal(k, s, jnp.float32).astype(dtype) for k, s in zip(keys, sizes)]
 
 
+def flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
+def attention(q, k, v, interpret=False):
+    """The kernel on `[B, L, heads, D]` arrays: it takes and gives the
+    projections' `[B, L, heads * D]`."""
+    o = pa.block_causal_attention(flat(q), flat(k), flat(v), heads=q.shape[2],
+                                  kv_heads=k.shape[2], block_length=BLOCK, interpret=interpret)
+    return o.reshape(q.shape)
+
+
 def kernel(q, k, v):
-    return pa.block_causal_attention(q, k, v, block_length=BLOCK, interpret=True)
+    return attention(q, k, v, interpret=True)
 
 
 def einsums(q, k, v):
@@ -99,7 +116,8 @@ REAL = sdar.SDAR_SIZES["sdar_30b_a3b"]
 def test_the_dispatch_rule(case, length, head_dim, block_length, backend, path, skipped):
     plan = pa.attention_plan(length, head_dim, block_length, backend=backend)
     side = -(-length // 128)
-    assert plan == {"path": path, "tiles": side * side, "tiles_skipped": skipped}
+    assert plan == {"path": path, "tiles": side * side, "tiles_skipped": skipped,
+                    "qk_prep": "fused" if path == "fused" else "xla"}
 
 
 def test_this_backend_takes_the_einsums_and_the_module_follows_the_rule(monkeypatch):
@@ -107,16 +125,27 @@ def test_this_backend_takes_the_einsums_and_the_module_follows_the_rule(monkeypa
     assert pa.attention_plan(512, 128, 4)["path"] == "einsum"          # the tests' CPU
     assert sdar.attention_path("sdar_30b_a3b", 512)["path"] == "einsum"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert sdar.attention_path("sdar_30b_a3b", 512) == {"path": "fused", "tiles": 16,
-                                                          "tiles_skipped": 6}
+    assert sdar.attention_path("sdar_30b_a3b", 512) == {
+        "path": "fused", "tiles": 16, "tiles_skipped": 6, "qk_prep": "fused"}
     assert sdar.attention_path("sdar_tiny", 16)["path"] == "einsum"
+    assert sdar.attention_path("sdar_tiny", 16)["qk_prep"] == "xla"
     calls = []
     monkeypatch.setattr(sdar, "block_causal_attention",
-                        lambda q, k, v, block_length: calls.append(q.shape) or q)
+                        lambda q, k, v, **kw: calls.append((q.shape, k.shape, kw)) or q)
+    monkeypatch.setattr(sdar, "norm_rotary",
+                        lambda y, scale, **kw: calls.append((y.shape, scale.shape, kw)) or y)
     module = sdar.Attention(2, 1, 128, 4, 1e6, 1e-6)
     h = jnp.zeros((1, 128, 32))
-    jax.eval_shape(lambda: module.init_with_output(jax.random.key(0), h)[0])
-    assert calls == [(1, 128, 2, 128)]
+    params = jax.eval_shape(lambda: module.init_with_output(jax.random.key(0), h)[1])
+    # where attention is fused the preparation of q and k is too, on flat arrays
+    prep = dict(dtype=jnp.float32, theta=1e6, eps=1e-6)
+    assert calls == [((1, 128, 256), (128,), prep), ((1, 128, 128), (128,), prep),
+                     ((1, 128, 256), (1, 128, 128), dict(heads=2, kv_heads=1, block_length=4))]
+    # and the parameter tree is the einsum path's
+    monkeypatch.undo()
+    plain = jax.eval_shape(lambda: module.init_with_output(jax.random.key(0), h)[1])
+    assert jax.tree.structure(params) == jax.tree.structure(plain)
+    assert jax.tree.leaves(params) == jax.tree.leaves(plain)
 
 
 def test_outputs_carry_vma_under_a_two_device_shard_map_with_the_check_on():
@@ -132,8 +161,7 @@ def test_outputs_carry_vma_under_a_two_device_shard_map_with_the_check_on():
     seen = []
 
     def region(q, k, v, ct):
-        o, vjp = jax.vjp(lambda q, k, v: pa.block_causal_attention(q, k, v, block_length=BLOCK),
-                         q, k, v)
+        o, vjp = jax.vjp(attention, q, k, v)
         grads = vjp(ct)
         seen.extend(jax.typeof(x).vma for x in (o, *grads))
         return (o, *grads)
@@ -155,10 +183,239 @@ def test_the_cells_shapes_lower_for_the_tpu_forward_and_backward():
     q, k = (jax.ShapeDtypeStruct((32, 512, h, 128), jnp.bfloat16) for h in (32, 4))
 
     def both(q, k, v, ct):
-        o, vjp = jax.vjp(lambda q, k, v: pa.block_causal_attention(q, k, v, block_length=BLOCK),
-                         q, k, v)
+        o, vjp = jax.vjp(attention, q, k, v)
         return (o, *vjp(ct))
 
     exported = jax.export.export(jax.jit(both), platforms=["tpu"])(q, k, k, q)
     assert exported.mlir_module().count("tpu_custom_call") == 2
     assert [x.shape for x in exported.out_avals] == [q.shape, q.shape, k.shape, k.shape]
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 30: norm_rotary
+# ---------------------------------------------------------------------------
+
+THETA, EPS = REAL["rope_theta"], REAL["eps"]
+# [B, L, heads] of 128 lanes: a q shape and a k shape, two lengths
+PREP_SHAPES = {"q_b2_l256_h8": (2, 256, 8), "k_b1_l512_h2": (1, 512, 2)}
+
+
+def xla_path(y, scale, dtype=None):
+    """What `Attention` does to q and k wherever the kernel does not run."""
+    b, length, width = y.shape
+    x = sdar.RMSNorm(EPS).apply({"params": {"scale": scale}}, y.reshape(b, length, -1, 128))
+    return sdar.rotary(x, THETA).astype(dtype or y.dtype).reshape(b, length, width)
+
+
+def prep_kernel(y, scale, dtype=None):
+    return pa.norm_rotary(y, scale, dtype=dtype or y.dtype, theta=THETA, eps=EPS, interpret=True)
+
+
+def prep_inputs(shape, dtype, seed=0):
+    """y with rows of every size from far under `sqrt(eps)` up (so `eps`
+    matters in some and not in others), a scale that is not 1."""
+    b, length, heads = shape
+    ky, kr, ks = jax.random.split(jax.random.key(seed), 3)
+    size = jnp.exp(3.0 * jax.random.normal(kr, (b, length, 1)) - 6.0)
+    y = (jax.random.normal(ky, (b, length, heads * 128)) * size).astype(dtype)
+    return y, 1.0 + 0.3 * jax.random.normal(ks, (128,))
+
+
+# y's dtype -> the result's: float32 throughout; what `Attention` runs in
+# bfloat16 (y as `RMSNorm` takes it, cast to float32); y in bfloat16 itself
+PREP_DTYPES = {"float32": ("float32", "float32"), "bfloat16": ("float32", "bfloat16"),
+               "bfloat16_in": ("bfloat16", "bfloat16")}
+_PREP: dict = {}
+
+
+def prep_results(shape_name, case):
+    """`(o, dx, dscale)` of the kernel, of the XLA path, and of the XLA path in
+    float32 on the same inputs with its result left in float32 (the oracle).
+    All three jitted: XLA draws the angles' powers one way eagerly and another
+    way compiled, 1e-5 of an element apart in float32. The XLA path's dx is
+    rounded to the cotangent's dtype, as the transpose of the projection's cast
+    rounds it in the step, and as the kernel writes it."""
+    if (shape_name, case) not in _PREP:
+        y_dtype, dtype = (jnp.dtype(d) for d in PREP_DTYPES[case])
+        y, scale = prep_inputs(PREP_SHAPES[shape_name], y_dtype)
+        g = jax.random.normal(jax.random.key(9), y.shape).astype(dtype)
+        def forward_and_transpose(fn, out, y, g):
+            o, vjp = jax.vjp(lambda y, s: fn(y, s, out), y, scale)
+            return (o, *vjp(g))
+
+        runs = []
+        for fn, out, wide in ((prep_kernel, dtype, False), (xla_path, dtype, False),
+                              (xla_path, jnp.float32, True)):
+            yy, gg = (y.astype(jnp.float32), g.astype(jnp.float32)) if wide else (y, g)
+            o, dx, dscale = jax.jit(forward_and_transpose, static_argnums=(0, 1))(fn, out, yy, gg)
+            assert (o.dtype, dx.dtype, dscale.dtype) == (out, yy.dtype, jnp.float32)
+            if fn is xla_path and not wide:
+                dx = dx.astype(dtype)
+            runs.append([np.asarray(x, np.float64) for x in (o, dx, dscale)])
+        _PREP[shape_name, case] = runs
+    return _PREP[shape_name, case]
+
+
+@pytest.mark.parametrize("which", ["o", "dx", "dscale"])
+@pytest.mark.parametrize("case", list(PREP_DTYPES))
+@pytest.mark.parametrize("shape_name", list(PREP_SHAPES))
+def test_norm_rotary_agrees_with_rotary_of_rmsnorm(shape_name, case, which):
+    """float32: the same arithmetic in the same order but for the lanes' sum
+    and the reciprocal root, so a few float32 steps of the largest element.
+    bfloat16: both paths compute in float32 and round once; the kernel is held
+    to the XLA path's own distance from the float32 oracle."""
+    i = ["o", "dx", "dscale"].index(which)
+    got, want, oracle = (r[i] for r in prep_results(shape_name, case))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    if case == "float32" or which == "dscale":
+        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max()
+    else:
+        ours, theirs = (np.linalg.norm(x - oracle) for x in (got, want))
+        assert theirs > 0 and ours <= 1.02 * theirs
+        assert np.abs(got - oracle).max() <= 1.02 * np.abs(want - oracle).max()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_eps_matters_where_a_row_is_small_and_nowhere_else(dtype):
+    """A row of zeros comes out as zeros with a zero gradient (1 / sqrt(eps) is
+    finite), a row far under sqrt(eps) is scaled by 1 / sqrt(eps) and not to
+    unit size, and a row of ordinary size does not see `eps`."""
+    y, _ = prep_inputs((1, 128, 2), jnp.dtype(dtype), seed=3)
+    g = jax.random.normal(jax.random.key(9), y.shape).astype(dtype)
+    scale = jnp.ones((128,))          # rotary turns pairs of lanes: a head's size stays
+    rows = jnp.arange(128)[None, :, None]
+    y = jnp.where(rows == 0, 0, jnp.where(rows == 1, 1e-6 * jnp.sign(y),
+                                          jnp.where(rows == 2, jnp.sign(y), y))).astype(dtype)
+    o, vjp = jax.vjp(prep_kernel, y, scale)
+    dx, _ = vjp(g)
+    o, dx = (np.asarray(x, np.float32) for x in (o, dx))
+    assert np.isfinite(o).all() and np.isfinite(dx).all()
+    assert (o[0, 0] == 0).all()
+    size = lambda row: np.sqrt((row.reshape(2, 128) ** 2).mean(-1))
+    rtol = 1e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(size(o[0, 1]), 1e-6 / np.sqrt(1e-12 + EPS), rtol=rtol)
+    np.testing.assert_allclose(size(o[0, 2]), 1.0, rtol=rtol)
+    want = np.asarray(jax.jit(xla_path)(y, scale), np.float32)
+    assert np.abs(o - want).max() <= (2e-6 if dtype == "float32" else 2 ** -7) * np.abs(want).max()
+
+
+_MODULE: dict = {}
+MODULE_LEAVES = ["out", "dh", "q/kernel", "k/kernel", "v/kernel", "o/kernel", "q_norm/scale",
+                 "k_norm/scale"]
+
+
+def module_results(monkeypatch):
+    """Output, input gradient and every parameter gradient of `Attention` as the
+    chip builds it (`norm_rotary` twice and `block_causal_attention`, here
+    interpreted) and as plain einsums, on the same float32 parameters."""
+    if not _MODULE:
+        module = sdar.Attention(4, 2, 128, BLOCK, THETA, EPS)
+        kp, kh, ks, kc = jax.random.split(jax.random.key(11), 4)
+        h = jax.random.normal(kh, (2, 256, 64))
+        params = module.init(kp, h)["params"]
+        for name, key in (("q_norm", ks), ("k_norm", kc)):
+            params[name]["scale"] = 1.0 + 0.3 * jax.random.normal(key, (128,))
+        ct = jax.random.normal(kc, h.shape)
+
+        def run():
+            out, vjp = jax.vjp(lambda p, h: module.apply({"params": p}, h), params, h)
+            dp, dh = vjp(ct)
+            return {"out": out, "dh": dh,
+                    **{f"{m}/{leaf}": v for m, sub in dp.items() for leaf, v in sub.items()}}
+
+        _MODULE["einsum"] = {k: np.asarray(v) for k, v in run().items()}
+        with monkeypatch.context() as patch:
+            patch.setattr(sdar, "attention_plan", lambda *a: {"path": "fused"})
+            patch.setattr(sdar, "norm_rotary", functools.partial(pa.norm_rotary, interpret=True))
+            patch.setattr(sdar, "block_causal_attention",
+                          functools.partial(pa.block_causal_attention, interpret=True))
+            _MODULE["fused"] = {k: np.asarray(v) for k, v in run().items()}
+    return _MODULE["fused"], _MODULE["einsum"]
+
+
+@pytest.mark.parametrize("leaf", MODULE_LEAVES)
+def test_the_fused_module_agrees_with_the_einsum_module_on_the_same_parameters(leaf, monkeypatch):
+    fused, einsum = module_results(monkeypatch)
+    assert sorted(fused) == sorted(einsum) == sorted(MODULE_LEAVES)
+    got, want = fused[leaf], einsum[leaf]
+    assert got.shape == want.shape and np.abs(want).max() > 0
+    assert np.abs(got - want).max() <= 5e-5 * np.abs(want).max()
+
+
+def test_norm_rotary_outputs_carry_vma_under_a_two_device_shard_map_with_the_check_on():
+    """Forward and transpose, as the step's region calls them: y varies over
+    the data axis, and so does the scale (`collectives.device_local`: the
+    region differentiates with respect to a device-local view of the
+    parameters), so dx and the scale's gradient do."""
+    from jax.sharding import PartitionSpec as P
+
+    from moco_tpu.parallel.collectives import device_local
+    from moco_tpu.parallel.mesh import DATA_AXIS, create_mesh
+
+    mesh = create_mesh(devices=jax.devices()[:2])
+    y, scale = prep_inputs((2, 128, 2), jnp.float32)
+    g = y.astype(jnp.bfloat16)
+    seen = []
+
+    def region(y, scale, g):
+        o, vjp = jax.vjp(lambda y, s: pa.norm_rotary(y, s, dtype=g.dtype, theta=THETA, eps=EPS),
+                         y, device_local(scale, DATA_AXIS))
+        dx, dscale = vjp(g)
+        seen.extend(jax.typeof(x).vma for x in (o, dx, dscale))
+        return o, dx, dscale[None]
+
+    spec = P(DATA_AXIS)
+    sharded = jax.shard_map(region, mesh=mesh, in_specs=(spec, P(), spec),
+                            out_specs=(spec,) * 3, check_vma=True)
+    out = jax.eval_shape(sharded, y, scale, g)
+    assert [x.shape for x in out] == [y.shape, y.shape, (2, 128)]
+    assert seen == [frozenset({DATA_AXIS})] * 3
+    jaxpr = str(jax.make_jaxpr(sharded)(y, scale, g))
+    assert jaxpr.count("pallas_call") == 2 and "check_vma=True" in jaxpr
+
+
+@pytest.mark.parametrize("name, heads", [("q", 32), ("k", 4)])
+def test_norm_rotary_lowers_for_the_tpu_at_the_cells_shapes_forward_and_backward(name, heads):
+    """`[32, 512, heads * 128]`, float32 in and bfloat16 out, exported for the
+    TPU platform from the CPU: two Mosaic kernels, each under its own name in a
+    trace."""
+    y = jax.ShapeDtypeStruct((32, 512, heads * 128), jnp.float32)
+    g = jax.ShapeDtypeStruct(y.shape, jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32)
+
+    def both(y, scale, g):
+        o, vjp = jax.vjp(lambda y, s: pa.norm_rotary(y, s, dtype=g.dtype, theta=THETA, eps=EPS),
+                         y, scale)
+        return (o, *vjp(g))
+
+    exported = jax.export.export(jax.jit(both), platforms=["tpu"])(y, scale, g)
+    text = exported.mlir_module()
+    assert text.count("tpu_custom_call") == 2
+    assert text.count('kernel_name = "qk_norm_rotary"') == 1
+    assert text.count('kernel_name = "qk_norm_rotary_bwd"') == 1
+    assert [(x.shape, x.dtype) for x in exported.out_avals] == [
+        (y.shape, g.dtype), (y.shape, y.dtype), ((128,), jnp.float32)]
+
+
+# sha256 of `Attention`'s lowered forward-and-backward program at `sdar_tiny`'s
+# sizes on this backend, printed without locations, read on the parent of ISSUE
+# 30 (commit 4221496): the einsum path is the oracle and did not move. Whoever
+# changes `RMSNorm`, `rotary`, `einsum_attention` or the order `Attention` calls
+# them in changes this on purpose, and says so.
+TINY_ATTENTION_SHA256 = "37b6ee1a844e7875383de51d1c99220169b27f9d037fc8fc24c2bc74a5f50bdf"
+
+
+def test_this_backend_prepares_q_and_k_in_xla_and_its_program_is_the_parents():
+    assert pa.attention_plan(512, 128, 4)["qk_prep"] == "xla"          # the tests' CPU
+    module = sdar.Attention(TINY["heads"], TINY["kv_heads"], TINY["head_dim"],
+                            TINY["block_length"], TINY["rope_theta"], TINY["eps"])
+    h = jnp.zeros((2, 16, TINY["hidden"]))
+    params = jax.eval_shape(lambda: module.init(jax.random.key(0), h))
+
+    def both(params, h, ct):
+        out, vjp = jax.vjp(module.apply, params, h)
+        return (out, *vjp(ct))
+
+    text = jax.jit(both).lower(params, h, h).as_text()
+    assert "pallas" not in text and "custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == TINY_ATTENTION_SHA256

@@ -321,10 +321,12 @@ def test_the_cli_runs_the_text_preset(tmp_path):
 ])
 def test_step_program_lowers_for_tpu(name, devices, mesh8):
     """The token program at the published widths exports for the TPU platform
-    from the CPU and reaches `attn`'s two Mosaic kernels 16 times (4 layers:
-    key forward, query forward, its rematerialised twin, backward) and no other."""
+    from the CPU and reaches `attn`'s Mosaic kernels 48 times and no other: in
+    each of 4 layers the attention kernel in the key forward, the query forward,
+    its rematerialised twin and the backward (16), and `norm_rotary` before each
+    of them for q and for k (24 forward, 8 backward: ISSUE 30)."""
     from step_lowering import cell_config, census_for_tpu
 
     layers = cell_config("sdar-30b-a3b-ep8").num_hidden_layers
     assert census_for_tpu(name, devices, mesh8, batch_size=8, num_hidden_layers=layers) == {
-        "_fwd_kernel": 12, "_bwd_kernel": 4}
+        "_fwd_kernel": 12, "_bwd_kernel": 4, "qk_norm_rotary": 24, "qk_norm_rotary_bwd": 8}

@@ -262,8 +262,8 @@ def test_close_preempted_marks_heartbeat_phase(tmp_path, mesh8):
     assert payload["step"] == 7 and payload["pid"] == os.getpid()
 
 
-ATTN_PLANS = {"fused": {"path": "fused", "tiles": 16, "tiles_skipped": 6},
-              "einsum": {"path": "einsum", "tiles": 1, "tiles_skipped": 0}}
+ATTN_PLANS = {"fused": {"path": "fused", "tiles": 16, "tiles_skipped": 6, "qk_prep": "fused"},
+              "einsum": {"path": "einsum", "tiles": 1, "tiles_skipped": 0, "qk_prep": "xla"}}
 
 
 @pytest.mark.parametrize("path", [None, "fused", "einsum"])
@@ -294,8 +294,10 @@ def test_the_setup_event_carries_the_attn_block_beside_its_spans(tmp_path, mesh8
 
 
 @pytest.mark.parametrize("path, holds", [
-    ("fused", "attention fused, 6 of 16 score tiles skipped"),
-    ("einsum", "attention einsum, 0 of 1 score tiles skipped"),
+    ("fused", "attention fused, 6 of 16 score tiles skipped, q/k prep fused"),
+    ("einsum", "attention einsum, 0 of 1 score tiles skipped, q/k prep xla"),
+    # a record from before ISSUE 30 has no `qk_prep`: XLA prepared q and k then
+    ("fused_without_qk_prep", "attention fused, 6 of 16 score tiles skipped, q/k prep xla"),
     (None, None),
 ])
 def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):
@@ -306,7 +308,8 @@ def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):
     spec.loader.exec_module(report)
     setup = {"kind": "event", "event": "setup", "spans": {"model_init": 3.5, "build_step": 0.25}}
     if path:
-        setup["attn"] = ATTN_PLANS[path]
+        setup["attn"] = {k: v for k, v in ATTN_PLANS[path.split("_")[0]].items()
+                         if k != "qk_prep" or "without" not in path}
     records = [setup, {"kind": "step", "step": 1, "step_s": 0.5}]
     lines = [t for t in report.render(report.summarize(records)).splitlines() if "set-up:" in t]
     assert len(lines) == 1 and "model_init 3.50 s" in lines[0]

@@ -890,7 +890,8 @@ def render(summary: dict) -> str:
                 f"{name} {secs:.2f} s" for name, secs in
                 sorted(setup.items(), key=lambda kv: -kv[1]))
             + (f" · attention {attn.get('path')}, {attn.get('tiles_skipped', 0)} of "
-               f"{attn.get('tiles', 0)} score tiles skipped" if attn else "")
+               f"{attn.get('tiles', 0)} score tiles skipped, q/k prep "
+               f"{attn.get('qk_prep', 'xla')}" if attn else "")
         )
     isv = summary.get("input_servers")
     if isv:
