@@ -24,6 +24,8 @@ class PretrainConfig:
     arch: str = "resnet50"            # resnet18/34/50/101/152 | vit_small/base/large/huge
                                       # | sdar_30b_a3b (a routed token encoder:
                                       # widths in models/sdar.py::SDAR_SIZES)
+                                      # | ouro_2p6b (a looped dense token encoder:
+                                      # models/ouro.py::OURO_SIZES)
     embed_dim: int = 128              # --moco-dim
     num_negatives: int = 65536        # --moco-k (ignored for v3)
     momentum_ema: float = 0.999       # --moco-m (v3: base for cosine ramp, 0.99)
@@ -102,8 +104,8 @@ class PretrainConfig:
                                       # and cast back to their OWN dtype,
                                       # integer leaves are summed exactly,
                                       # never cast (gradsync.leaf_wire_dtype)
-    # a token encoder's share of its published stack (models/sdar.py; 0 =
-    # the arch's own number). Named as the model's config.json names them:
+    # a token encoder's share of its published stack (models/sdar.py,
+    # models/ouro.py; 0 = the arch's own number). Named as the model's config.json names them:
     # a benchmark configuration lists the ones it cuts under `reduced`
     num_hidden_layers: int = 0        # blocks kept (the period is one block)
     num_experts: int = 0              # routed experts HELD here, the first n;
@@ -868,6 +870,19 @@ PRESETS["text-moco-v2-sdar"] = PretrainConfig(
     compute_dtype="bfloat16",
     remat=True,
     health_stride=16,  # the expert-load counters ride the health scalars
+)
+
+
+# 7. The same recipe with Ouro-2.6B's published stack as the encoder
+#    (models/ouro.py: 48 dense layers run `total_ut_steps` = 4 times with the
+#    same weights, one compiled pass under scan). The preset is the published
+#    model whole (2.6 B parameters, 52 GB of train state: more than a chip);
+#    `--num-hidden-layers 6` is the first of eight pipeline stages, looped on
+#    its own six layers, which one chip holds (README).
+PRESETS["text-moco-v2-ouro"] = PRESETS["text-moco-v2-sdar"].replace(
+    name="text-moco-v2-ouro",
+    arch="ouro_2p6b",
+    batch_size=16,
 )
 
 

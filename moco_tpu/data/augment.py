@@ -626,7 +626,7 @@ def token_view_config_for(config) -> TokenViewConfig:
     """The one place the trainer's config becomes the views' recipe: the mask
     id is the LAST id of the vocabulary slice held here (the traffic draws
     its ids from the others)."""
-    from moco_tpu.models.sdar import held_vocab
+    from moco_tpu.models import held_vocab
 
     return TokenViewConfig(seq_len=config.seq_len,
                            mask_id=held_vocab(config.arch, config.vocab_size) - 1)

@@ -11,18 +11,88 @@ from moco_tpu.models.resnet import (
 from moco_tpu.models.heads import V3Predictor, V3Projector
 
 
+# -- token encoders: one door for every family ---------------------------------
+# A family is a module of this package whose archs share a name's prefix. It
+# has `SIZES` (arch -> the published sizes), `build(arch, num_classes, *,
+# layers, held, vocab, **kwargs)`, `STAT_COLLECTIONS` (the flax collections its
+# forward pass sows counters into) and `health(counted, tokens)` (those counters
+# reduced for the step's `health` block). What the trainer asks of a token
+# encoder it asks here, of the sizes, never of a family's name.
+_TOKEN_FAMILIES = {"sdar": "moco_tpu.models.sdar",    # routed, block-causal GQA
+                   "ouro": "moco_tpu.models.ouro"}    # dense, weight-shared and looped
+
+
+def _family_module(arch: str) -> str | None:
+    return next((m for prefix, m in _TOKEN_FAMILIES.items() if arch.startswith(prefix)), None)
+
+
+def is_token_encoder(arch: str) -> bool:
+    """Fed `int32` rows and lengths where an image encoder is fed canvases."""
+    return _family_module(arch) is not None
+
+
+def _token_family(arch: str):
+    import importlib
+
+    module = _family_module(arch)
+    if module is None:
+        raise ValueError(f"unknown token-encoder arch {arch!r}")
+    return importlib.import_module(module)
+
+
+def token_sizes(arch: str) -> dict:
+    sizes = _token_family(arch).SIZES
+    if arch not in sizes:
+        raise ValueError(f"unknown token-encoder arch {arch!r}; choose from {sorted(sizes)}")
+    return sizes[arch]
+
+
+def held_vocab(arch: str, vocab_size: int = 0) -> int:
+    """Ids of the vocabulary slice held here: `vocab_size` first ids, or all."""
+    return vocab_size or token_sizes(arch)["vocab"]
+
+
+def has_router(arch: str) -> bool:
+    """Whether the encoder routes tokens to experts: a router to mask where a
+    share holds it constant, expert counters to read."""
+    return "experts" in token_sizes(arch)
+
+
+def token_counters(arch: str) -> tuple:
+    """The collections the encoder's forward pass sows its counters into, and
+    the family's `health(counted, tokens)` that reduces them for the step's
+    stride-gated block."""
+    family = _token_family(arch)
+    return family.STAT_COLLECTIONS, family.health
+
+
+def attention_path(arch: str, length: int) -> dict:
+    """The path the encoder's attention takes for views of `length` tokens on
+    this backend, with its tile counts and who prepares q and k: the `attn`
+    block of the run's `setup` event."""
+    from moco_tpu.ops.pallas_attention import attention_plan
+
+    z = token_sizes(arch)
+    return attention_plan(length, z["head_dim"], z["block_length"],
+                          qk_norm=z.get("qk_norm", True))
+
+
+def build_token_encoder(arch: str, num_classes=None, **cut):
+    """`cut`: `layers`, `held`, `vocab` (0 is the arch's own number) and the
+    module's own arguments (`mlp_head`, `remat`, `dtype`)."""
+    return _token_family(arch).build(arch, num_classes, **cut)
+
+
 def build_backbone(arch: str, *, cifar_stem: bool = False, num_classes=None):
     """Feature-mode encoder for NON-TRAINING consumers (the lincls probe,
-    the serve/ embedding service): one arch router for the three families
-    (ResNet, ViT, the routed token encoder), so 'which constructor does this
+    the serve/ embedding service): one arch router for the three kinds
+    (ResNet, ViT, a token encoder), so 'which constructor does this
     arch use' is decided in exactly one place. `num_classes=None` yields
     pooled backbone features, the transfer product both consumers read; a
     token encoder's is the mean over the positions, and it comes whole
-    (every layer, every expert held: `build_sdar`'s own arguments cut it)."""
-    if arch.startswith("sdar"):
-        from moco_tpu.models.sdar import build_sdar
-
-        return build_sdar(arch, num_classes=num_classes)
+    (every layer, every expert held: the builder's own arguments cut it)."""
+    if is_token_encoder(arch):
+        return build_token_encoder(arch, num_classes=num_classes)
     if arch.startswith("vit"):
         from moco_tpu.models.vit import build_vit
 
@@ -32,6 +102,13 @@ def build_backbone(arch: str, *, cifar_stem: bool = False, num_classes=None):
 
 __all__ = [
     "build_backbone",
+    "is_token_encoder",
+    "token_sizes",
+    "held_vocab",
+    "has_router",
+    "token_counters",
+    "attention_path",
+    "build_token_encoder",
     "ARCHS",
     "FEATURE_DIMS",
     "ResNet",

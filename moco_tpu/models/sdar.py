@@ -87,17 +87,28 @@ SDAR_SIZES = {
         block_length=2,
     ),
 }
+SIZES = SDAR_SIZES    # what `models/__init__.py`'s door for token encoders reads
 # a collection of its own for what the router counted on the way: read by
 # the step's stride-gated counters, never by the forward pass
 MOE_STATS = "moe_stats"
+STAT_COLLECTIONS = (MOE_STATS,)
+
+
+def health(counted, tokens: int) -> dict:
+    """The family's counters for the step's stride-gated `health` block, from
+    what the forward pass sowed (each layer's assignments by held expert):
+    assignments to held experts a token, over all layers (1.0 where routing is
+    uniform and an eighth of the experts live here), and the fullest held
+    expert's load over the mean load, the worst layer's."""
+    counts = jnp.stack([c.astype(jnp.float32)
+                        for c in jax.tree.leaves(counted[MOE_STATS])])   # [layers, held]
+    mean = jnp.maximum(jnp.mean(counts, -1), 1e-9)
+    return {"h_moe_assign_per_token": jnp.mean(jnp.sum(counts, -1)) / tokens,
+            "h_moe_load_max_over_mean": jnp.max(jnp.max(counts, -1) / mean)}
 # each layer's chosen experts `[tokens, top_k]`, for whoever asks by making the
 # collection mutable (perfbench/calibrate_reference.py: the share of sets that
 # differ from the float32 reference's); never in the step
 MOE_CHOICES = "moe_choices"
-
-
-def is_sdar(arch: str) -> bool:
-    return arch.startswith("sdar")
 
 
 def router_trains(arch: str, held: int = 0) -> bool:
@@ -113,18 +124,6 @@ def router_trainable_mask(params) -> Any:
         return not any(getattr(entry, "key", None) == "router" for entry in path)
 
     return jax.tree_util.tree_map_with_path(is_trainable, params)
-
-
-def held_vocab(arch: str, vocab_size: int = 0) -> int:
-    """Ids of the vocabulary slice held here: `vocab_size` first ids, or all."""
-    return vocab_size or SDAR_SIZES[arch]["vocab"]
-
-
-def attention_path(arch: str, length: int) -> dict:
-    """The path `Attention` takes for views of `length` tokens on this backend,
-    with its tile counts: the `attn` block of the run's `setup` event."""
-    z = SDAR_SIZES[arch]
-    return attention_plan(length, z["head_dim"], z["block_length"])
 
 
 def block_causal_mask(length: int, block_length: int) -> jax.Array:
@@ -182,6 +181,10 @@ class HeadScale(nn.Module):
 
 
 class Attention(nn.Module):
+    """`qk_norm` off leaves q and k's per-head RMSNorm out (and its two scales
+    out of the tree): rotary alone, for an encoder that has none
+    (`models/ouro.py`)."""
+
     heads: int
     kv_heads: int
     head_dim: int
@@ -189,6 +192,7 @@ class Attention(nn.Module):
     rope_theta: float
     eps: float
     dtype: Any = jnp.float32
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, h):
@@ -205,14 +209,19 @@ class Attention(nn.Module):
         q, k, v = proj("q", self.heads), proj("k", self.kv_heads), proj("v", self.kv_heads)
         if fused:
             # float32 in as `RMSNorm` casts: see `norm_rotary`
-            q, k = (norm_rotary(x.astype(jnp.float32), HeadScale(self.head_dim, name=name)(),
-                                dtype=self.dtype, theta=self.rope_theta, eps=self.eps)
+            q, k = (norm_rotary(x.astype(jnp.float32),
+                                HeadScale(self.head_dim, name=name)() if self.qk_norm else None,
+                                dtype=self.dtype, theta=self.rope_theta, eps=self.eps,
+                                head_dim=self.head_dim)
                     for x, name in ((q, "q_norm"), (k, "k_norm")))
             o = block_causal_attention(q, k, v, heads=self.heads, kv_heads=self.kv_heads,
                                        block_length=self.block_length)
         else:
-            q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta).astype(self.dtype)
-            k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta).astype(self.dtype)
+            def prep(x, name):
+                x = RMSNorm(self.eps, name=name)(x) if self.qk_norm else x.astype(jnp.float32)
+                return rotary(x, self.rope_theta).astype(self.dtype)
+
+            q, k = prep(q, "q_norm"), prep(k, "k_norm")
             o = einsum_attention(q, k, v, self.block_length)
             o = o.reshape(b, length, self.heads * self.head_dim)
         return nn.Dense(h.shape[-1], use_bias=False, dtype=self.dtype,
@@ -406,4 +415,7 @@ def build_sdar(arch: str, num_classes: int | None = None, *, layers: int = 0, he
     if not 0 < held <= z["experts"]:
         raise ValueError(f"experts held must be in 1..{z['experts']}, got {held}")
     return SDAREncoder(tuple(sorted(z.items())), layers or z["layers"], held,
-                       held_vocab(arch, vocab), num_classes=num_classes, **kwargs)
+                       vocab or z["vocab"], num_classes=num_classes, **kwargs)
+
+
+build = build_sdar    # the door's name for a family's builder

@@ -67,10 +67,11 @@ _PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "p
 
 
 def attention_plan(length: int, head_dim: int, block_length: int,
-                   backend: str | None = None) -> dict:
+                   backend: str | None = None, qk_norm: bool = True) -> dict:
     """Which path attention takes for these shapes, how many `TILE` x `TILE`
     score tiles it computes and skips, and who prepares q and k for it
-    (`qk_prep`: `norm_rotary` where attention is fused, XLA elsewhere): the
+    (`qk_prep`: `norm_rotary` where attention is fused, `fused` with the
+    per-head norm and `rotary` for an encoder that has none; XLA elsewhere): the
     `attn` block of the `setup` event. The kernels need a TPU, whole lane tiles
     for a head, whole q tiles, and blocks that do not straddle a tile."""
     side = -(-length // TILE)
@@ -78,7 +79,7 @@ def attention_plan(length: int, head_dim: int, block_length: int,
              and length % TILE == 0 and TILE % block_length == 0)
     return {"path": "fused" if fused else "einsum", "tiles": side * side,
             "tiles_skipped": side * (side - 1) // 2 if fused else 0,
-            "qk_prep": "fused" if fused else "xla"}
+            "qk_prep": ("fused" if qk_norm else "rotary") if fused else "xla"}
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -274,48 +275,56 @@ def _rotary_tables(length: int, dim: int, theta: float):
     return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
 
 
-def _prep_kernel(y_ref, scale_ref, cos_ref, sin_ref, o_ref, *, eps):
+def _prep_kernel(y_ref, *refs, eps, norm):
     """Rows of one batch row and whole heads: y/o refs `[rows, n * D]`, the
-    tables' refs `[rows, D]`, scale `[1, D]`. float32 until the one cast, to
-    o's dtype."""
+    tables' refs `[rows, D]`, and with `norm` the scale's `[1, D]` before them.
+    float32 until the one cast, to o's dtype. `norm` off leaves the per-head
+    RMSNorm out: rotary and the cast alone."""
+    *scale_ref, cos_ref, sin_ref, o_ref = refs
     dim = cos_ref.shape[1]
-    scale = scale_ref[...]
     for t in range(y_ref.shape[0] // TILE):
         rows = slice(t * TILE, (t + 1) * TILE)
         cos, sin = cos_ref[rows, :], sin_ref[rows, :]
         for j in range(y_ref.shape[1] // dim):
             head = slice(j * dim, (j + 1) * dim)
             x = y_ref[rows, head].astype(jnp.float32)
-            r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-            xn = x * r * scale
+            if norm:
+                x = x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale_ref[0][...]
             # the other half of the head is `dim // 2` lanes away, either way round
-            out = xn * cos + pltpu.roll(xn, dim // 2, 1) * sin
+            out = x * cos + pltpu.roll(x, dim // 2, 1) * sin
             o_ref[rows, head] = out.astype(o_ref.dtype)
 
 
-def _prep_bwd_kernel(y_ref, g_ref, scale_ref, cos_ref, sin_ref, dx_ref, dscale_ref, *, eps):
-    """The transpose of `_prep_kernel` at the same blocks; `r` is computed
-    again from y. dscale ref `[8, D]`: this program's rows summed eight apart,
-    the rest of the sum is XLA's."""
+def _prep_bwd_kernel(*refs, eps, norm):
+    """The transpose of `_prep_kernel` at the same blocks. With `norm`: refs y,
+    g, scale, the tables, dx and dscale `[8, D]` (this program's rows summed
+    eight apart, the rest of the sum is XLA's); `r` is computed again from y.
+    Without: g, the tables and dx, since rotary is linear and keeps nothing."""
+    if norm:
+        y_ref, g_ref, scale_ref, cos_ref, sin_ref, dx_ref, dscale_ref = refs
+        scale = scale_ref[...]
+        dscale = jnp.zeros(dscale_ref.shape, jnp.float32)
+    else:
+        g_ref, cos_ref, sin_ref, dx_ref = refs
     dim = cos_ref.shape[1]
-    scale = scale_ref[...]
-    dscale = jnp.zeros(dscale_ref.shape, jnp.float32)
-    for t in range(y_ref.shape[0] // TILE):
+    for t in range(g_ref.shape[0] // TILE):
         rows = slice(t * TILE, (t + 1) * TILE)
         cos, sin = cos_ref[rows, :], sin_ref[rows, :]
-        for j in range(y_ref.shape[1] // dim):
+        for j in range(g_ref.shape[1] // dim):
             head = slice(j * dim, (j + 1) * dim)
-            x = y_ref[rows, head].astype(jnp.float32)
             g = g_ref[rows, head].astype(jnp.float32)
-            r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
             # a roll by half the lanes is its own transpose
-            gn = g * cos + pltpu.roll(g * sin, dim // 2, 1)
-            u = x * r
-            dscale = dscale + jnp.sum((gn * u).reshape(-1, *dscale.shape), 0)
-            gx = gn * scale
-            dx = r * (gx - u * (r * jnp.mean(gx * x, -1, keepdims=True)))
+            dx = g * cos + pltpu.roll(g * sin, dim // 2, 1)
+            if norm:
+                x = y_ref[rows, head].astype(jnp.float32)
+                r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+                u = x * r
+                dscale = dscale + jnp.sum((dx * u).reshape(-1, *dscale.shape), 0)
+                gx = dx * scale
+                dx = r * (gx - u * (r * jnp.mean(gx * x, -1, keepdims=True)))
             dx_ref[rows, head] = dx.astype(dx_ref.dtype)
-    dscale_ref[...] = dscale
+    if norm:
+        dscale_ref[...] = dscale
 
 
 def _prep_grid_and_specs(y, dim):
@@ -332,67 +341,77 @@ def _prep_grid_and_specs(y, dim):
     return grid, y_spec, table_spec, scale_spec, (*grid, 8, dim), sums_spec
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "theta", "eps", "interpret"))
-def _prep_forward(y, scale, dtype, theta, eps, interpret):
-    dim = scale.shape[0]
+@functools.partial(jax.jit, static_argnames=("dim", "dtype", "theta", "eps", "interpret"))
+def _prep_forward(y, scale, dim, dtype, theta, eps, interpret):
+    norm = scale is not None
     grid, y_spec, table_spec, scale_spec, _, _ = _prep_grid_and_specs(y, dim)
+    scales = [scale[None, :]] if norm else []
     return pl.pallas_call(
-        functools.partial(_prep_kernel, eps=eps),
+        functools.partial(_prep_kernel, eps=eps, norm=norm),
         grid=grid,
-        in_specs=[y_spec, scale_spec, table_spec, table_spec],
+        in_specs=[y_spec] + [scale_spec] * norm + [table_spec, table_spec],
         out_specs=y_spec,
-        out_shape=_out_shapes((y, scale), (y.shape, dtype))[0],
+        out_shape=_out_shapes((y, *scales), (y.shape, dtype))[0],
         compiler_params=_PARALLEL,
         interpret=interpret,
-        name="qk_norm_rotary",
-    )(y, scale[None, :], *_rotary_tables(y.shape[1], dim, theta))
+        name="qk_norm_rotary" if norm else "qk_rotary",
+    )(y, *scales, *_rotary_tables(y.shape[1], dim, theta))
 
 
-@functools.partial(jax.jit, static_argnames=("theta", "eps", "interpret"))
-def _prep_backward(y, g, scale, theta, eps, interpret):
-    dim = scale.shape[0]
-    grid, y_spec, table_spec, scale_spec, sums_shape, sums_spec = _prep_grid_and_specs(y, dim)
-    dx, sums = pl.pallas_call(
-        functools.partial(_prep_bwd_kernel, eps=eps),
+@functools.partial(jax.jit, static_argnames=("dim", "theta", "eps", "interpret"))
+def _prep_backward(y, g, scale, dim, theta, eps, interpret):
+    """`y` and `scale` are `None` where there is no norm: the cotangent alone."""
+    norm = scale is not None
+    grid, y_spec, table_spec, scale_spec, sums_shape, sums_spec = _prep_grid_and_specs(g, dim)
+    operands = [y, g, scale[None, :]] if norm else [g]
+    out = pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, eps=eps, norm=norm),
         grid=grid,
-        in_specs=[y_spec, y_spec, scale_spec, table_spec, table_spec],
-        out_specs=[y_spec, sums_spec],
-        out_shape=_out_shapes((y, g, scale), (y.shape, g.dtype), (sums_shape, jnp.float32)),
+        in_specs=([y_spec, y_spec, scale_spec] if norm else [y_spec]) + [table_spec, table_spec],
+        out_specs=[y_spec, sums_spec][: 1 + norm],
+        out_shape=_out_shapes(operands, (g.shape, g.dtype), (sums_shape, jnp.float32))[: 1 + norm],
         compiler_params=_PARALLEL,
         interpret=interpret,
-        name="qk_norm_rotary_bwd",
-    )(y, g, scale[None, :], *_rotary_tables(y.shape[1], dim, theta))
+        name="qk_norm_rotary_bwd" if norm else "qk_rotary_bwd",
+    )(*operands, *_rotary_tables(g.shape[1], dim, theta))
     # dx leaves the kernel in the cotangent's dtype, as the transpose of the
     # XLA path's `astype` would round it on its way into the projection
-    return dx.astype(y.dtype), jnp.sum(sums, (0, 1, 2, 3))
+    return out[0], (jnp.sum(out[1], (0, 1, 2, 3)) if norm else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
-def _norm_rotary(y, scale, dtype, theta, eps, interpret):
-    return _prep_forward(y, scale, dtype, theta, eps, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _norm_rotary(y, scale, dim, dtype, theta, eps, interpret):
+    return _prep_forward(y, scale, dim, dtype, theta, eps, interpret)
 
 
-def _norm_rotary_fwd(y, scale, dtype, theta, eps, interpret):
-    return _prep_forward(y, scale, dtype, theta, eps, interpret), (y, scale)
+def _norm_rotary_fwd(y, scale, dim, dtype, theta, eps, interpret):
+    out = _prep_forward(y, scale, dim, dtype, theta, eps, interpret)
+    # rotary alone is linear: its transpose needs y's dtype and nothing of y
+    return out, ((y, scale) if scale is not None else (jnp.zeros((0,), y.dtype), None))
 
 
-def _norm_rotary_bwd(dtype, theta, eps, interpret, residuals, g):
+def _norm_rotary_bwd(dim, dtype, theta, eps, interpret, residuals, g):
     y, scale = residuals
-    return _prep_backward(y, g, scale, theta, eps, interpret)
+    dx, dscale = _prep_backward(y if scale is not None else None, g, scale, dim, theta, eps,
+                                interpret)
+    return dx.astype(y.dtype), dscale
 
 
 _norm_rotary.defvjp(_norm_rotary_fwd, _norm_rotary_bwd)
 
 
-def norm_rotary(y: jax.Array, scale: jax.Array, *, dtype, theta: float, eps: float,
-                interpret: bool = False) -> jax.Array:
+def norm_rotary(y: jax.Array, scale: jax.Array | None, *, dtype, theta: float, eps: float = 0.0,
+                head_dim: int = 0, interpret: bool = False) -> jax.Array:
     """`rotary(RMSNorm(eps)(y per head), theta).astype(dtype)` of
     `models/sdar.py` on the projection's own output `[B, L, heads * D]`, heads
     of `D = scale.shape[0]` lanes, positions 0..L-1: what q and k pass through
     between their `Dense` and `block_causal_attention`. One read of y and one
     write; the backward pass keeps y alone and reads it once more with the
     cotangent. `scale` is the norm's `[D]` float32 parameter and gets its
-    gradient.
+    gradient. `scale=None` is an encoder without the per-head norm
+    (`models/ouro.py`): `rotary(y, theta).astype(dtype)` over heads of
+    `head_dim` lanes, the same kernel body with the norm switched off; its
+    backward pass keeps nothing.
 
     y comes as `RMSNorm` takes it, cast to float32: XLA's fusion of the
     projection with that cast hands over the product's float32 accumulator
@@ -400,4 +419,5 @@ def norm_rotary(y: jax.Array, scale: jax.Array, *, dtype, theta: float, eps: flo
     and gradients read 8 - 14 % further from a float32 oracle with y in
     bfloat16), and the cotangent of the cast is the rounding that the XLA
     path's backward pass has there too."""
-    return _norm_rotary(y, scale, jnp.dtype(dtype), float(theta), float(eps), interpret)
+    dim = int(scale.shape[0]) if scale is not None else int(head_dim)
+    return _norm_rotary(y, scale, dim, jnp.dtype(dtype), float(theta), float(eps), interpret)

@@ -179,23 +179,14 @@ def region_health(q: jax.Array, k: jax.Array, grads, step: jax.Array,
     return _gated(step, stride, compute)
 
 
-def expert_load(moe_stats, tokens: int, step: jax.Array,
-                stride: int) -> dict[str, jax.Array]:
-    """A routed encoder's counters (models/sdar.py sows each layer's
-    assignments by held expert): assignments to held experts a token, over
-    all layers (1.0 where routing is uniform and an eighth of the experts
-    live here), and the fullest held expert's load over the mean load, the
-    worst layer's. Per-device views of the query forward; the caller's
-    metrics pmean averages them."""
-
-    def compute():
-        counts = jnp.stack([c.astype(jnp.float32)
-                            for c in jax.tree.leaves(moe_stats)])   # [layers, held]
-        mean = jnp.maximum(jnp.mean(counts, -1), 1e-9)
-        return {"h_moe_assign_per_token": jnp.mean(jnp.sum(counts, -1)) / tokens,
-                "h_moe_load_max_over_mean": jnp.max(jnp.max(counts, -1) / mean)}
-
-    return _gated(step, stride, compute)
+def encoder_counters(reduce, counted, tokens: int, step: jax.Array,
+                     stride: int) -> dict[str, jax.Array]:
+    """A token encoder's own counters: what its forward pass sowed (`counted`,
+    by collection), reduced by its family's `health(counted, tokens)`
+    (`models/sdar.py`: expert load; `models/ouro.py`: the loop's progress).
+    Per-device views of the query forward; the caller's metrics pmean
+    averages them."""
+    return _gated(step, stride, lambda: reduce(counted, tokens))
 
 
 def queue_health(queue: jax.Array, step: jax.Array, global_batch: int,

@@ -155,15 +155,36 @@ def sdar_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> f
     return (layers or z["layers"]) * seq_len * per_token
 
 
+def looped_fwd_flops(arch: str, seq_len: int, layers: int = 0) -> float:
+    """Forward matmul FLOPs per VIEW for the looped dense token encoder in
+    models/ouro.py: per layer application the q/k/v/o projections, scores and
+    mix at the causal mask's density and the MLP's three products, times the
+    layers, times the passes over them (a layer is counted once a PASS: the
+    weights are shared, the work is not); excludes norms, rotary, softmax and
+    the embedding lookup. `layers`: 0 is the arch's own number."""
+    from moco_tpu.models.ouro import OURO_SIZES
+
+    z = OURO_SIZES[arch]
+    d, hd = z["hidden"], z["head_dim"]
+    density = (seq_len + 1) / (2.0 * seq_len)
+    per_token = (
+        2.0 * d * (z["heads"] + 2 * z["kv_heads"]) * hd    # q, k, v projections
+        + 2.0 * 2 * seq_len * density * z["heads"] * hd    # scores + mix
+        + 2.0 * z["heads"] * hd * d                        # output projection
+        + 3 * 2.0 * d * z["width"]                         # gate, up, down
+    )
+    return z["ut_steps"] * (layers or z["layers"]) * seq_len * per_token
+
+
 def head_fwd_flops(arch: str, embed_dim: int, mlp_head: bool) -> float:
     """Projection-head dense FLOPs per image (fc, or the v2 2-layer MLP)."""
+    from moco_tpu.models import is_token_encoder, token_sizes
     from moco_tpu.models.resnet import FEATURE_DIMS
-    from moco_tpu.models.sdar import SDAR_SIZES
 
     if arch in _VIT_SPECS:
         feat = _VIT_SPECS[arch][0]
-    elif arch in SDAR_SIZES:
-        feat = SDAR_SIZES[arch]["hidden"]
+    elif is_token_encoder(arch):
+        feat = token_sizes(arch)["hidden"]
     else:
         feat = FEATURE_DIMS[arch]
     if mlp_head:
@@ -176,6 +197,7 @@ def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
                     num_hidden_layers: int = 0, num_experts: int = 0) -> float:
     """Backbone + head forward FLOPs per image (a token encoder: per view of
     `seq_len` tokens) for any supported arch."""
+    from moco_tpu.models.ouro import OURO_SIZES
     from moco_tpu.models.sdar import SDAR_SIZES
 
     if arch in _VIT_SPECS:
@@ -184,6 +206,8 @@ def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
         body = resnet_fwd_flops(arch, image_size, cifar_stem)
     elif arch in SDAR_SIZES:
         body = sdar_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
+    elif arch in OURO_SIZES:
+        body = looped_fwd_flops(arch, seq_len, num_hidden_layers)
     else:
         raise ValueError(f"no analytic FLOPs model for arch {arch!r}")
     return body + head_fwd_flops(arch, embed_dim, mlp_head)

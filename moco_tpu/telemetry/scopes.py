@@ -43,6 +43,15 @@ MOE_EXPERTS = "moe_experts"      # the grouped products and SwiGLU
 EMBED_POOL = "embed_pool"        # token embedding; final norm, mean pool, head
 ENCODER_SCOPES = (ATTN, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, EMBED_POOL)
 
+# inside a looped dense token encoder (`models/ouro.py`), nested the same way:
+# `attn` (there: projections, rotary, scores, output) and `embed_pool` (token
+# embedding; mean pool, head) again, and two siblings of `attn`, never inside it
+MLP = "mlp"                      # the three products and SwiGLU
+NORM = "norm"                    # the four sandwich norms, the closing norm, the residual adds
+# the loop's own hand-over of the residual stream (the scan's counter and stack
+# of layer inputs) is under none of them: it reads under `k_fwd` / `q_fwd_bwd` alone
+LOOPED_SCOPES = (ATTN, MLP, NORM, EMBED_POOL)
+
 # -- host spans -----------------------------------------------------------------
 STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler as
 STEP_ANNOTATION = "train"  # StepTraceAnnotation(STEP_ANNOTATION, step_num=<global step>)

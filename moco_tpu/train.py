@@ -40,7 +40,7 @@ from moco_tpu.data import (
     epoch_loader,
     token_view_config_for,
 )
-from moco_tpu.models.sdar import attention_path, held_vocab, is_sdar
+from moco_tpu.models import attention_path, held_vocab, is_token_encoder
 from moco_tpu.ops.knn import knn_accuracy
 from moco_tpu.parallel.mesh import create_mesh, local_batch_size
 from moco_tpu.resilience import (
@@ -354,9 +354,10 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
         jax.config.update("jax_debug_nans", True)
     n_chips = mesh.size
     local_b = local_batch_size(config.batch_size, mesh)  # validates divisibility
-    # a token encoder (models/sdar.py) is fed int32 rows and lengths where an
-    # image encoder is fed uint8 canvases and extents: same feed, same step
-    tokens = is_sdar(config.arch)
+    # a token encoder (models/sdar.py, models/ouro.py) is fed int32 rows and
+    # lengths where an image encoder is fed uint8 canvases and extents: same
+    # feed, same step
+    tokens = is_token_encoder(config.arch)
     if tokens and config.knn_monitor:
         raise ValueError("knn_monitor reads labelled images; a token encoder has none")
 

@@ -38,8 +38,9 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from moco_tpu.config import PretrainConfig
-from moco_tpu.models import build_resnet
-from moco_tpu.models.sdar import MOE_STATS, is_sdar, router_trainable_mask, router_trains
+from moco_tpu.models import (build_resnet, build_token_encoder, has_router, is_token_encoder,
+                             token_counters)
+from moco_tpu.models.sdar import router_trainable_mask, router_trains
 from moco_tpu.telemetry import health, scopes
 from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import (
@@ -64,12 +65,10 @@ def build_encoder(config: PretrainConfig):
     encoder is backbone→projector (+predictor on the query side), so this
     returns the composite `V3Model`."""
     dtype = jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
-    if is_sdar(config.arch):
+    if is_token_encoder(config.arch):
         if config.variant == "v3":
             raise ValueError("a token encoder trains under the queue-based v2 step")
-        from moco_tpu.models.sdar import build_sdar
-
-        return build_sdar(
+        return build_token_encoder(
             config.arch, num_classes=config.embed_dim, mlp_head=config.mlp_head,
             layers=config.num_hidden_layers, held=config.num_experts,
             vocab=config.vocab_size, dtype=dtype, remat=config.remat)
@@ -168,7 +167,8 @@ def build_optimizer(
         from moco_tpu.v3_step import patch_embed_trainable_mask
 
         tx = optax.masked(tx, patch_embed_trainable_mask)
-    if is_sdar(config.arch) and not router_trains(config.arch, config.num_experts):
+    if (is_token_encoder(config.arch) and has_router(config.arch)
+            and not router_trains(config.arch, config.num_experts)):
         # a share of an expert layer does not train its router (models/sdar.py):
         # the same pattern, stop_gradient in the model and the mask for the decay
         tx = optax.masked(tx, router_trainable_mask)
@@ -243,10 +243,10 @@ def _build_query_loss(config: PretrainConfig, model, temperature: float):
     grad-flow probe (which also differentiates w.r.t. the queue)."""
 
     # what the forward pass hands out beside the embedding: BatchNorm's batch
-    # statistics, and a routed encoder's counts where the counters are on
+    # statistics, and a token encoder's own counts where the counters are on
     mutable = ["batch_stats"]
-    if config.health_stride:
-        mutable.append(MOE_STATS)
+    if config.health_stride and is_token_encoder(config.arch):
+        mutable += token_counters(config.arch)[0]
 
     def query_loss(pq, stats_q, im_q, k, queue):
         q, mut_q = model.apply(
@@ -270,7 +270,7 @@ def _build_query_loss(config: PretrainConfig, model, temperature: float):
             logits,
             labels,
             q,
-            mut_q.get(MOE_STATS, {}),
+            {name: mut_q[name] for name in mutable[1:] if name in mut_q},
         )
 
     return query_loss
@@ -367,7 +367,7 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
         # w.r.t. the device-local view: the grads come out per-device and
         # gradsync's reduce below is the only one (collectives.device_local)
         with jax.named_scope(scopes.Q_FWD_BWD):
-            (loss, (new_stats_q, logits, labels, q, moe_stats)), grads = jax.value_and_grad(
+            (loss, (new_stats_q, logits, labels, q, counted)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(device_local(params_q, DATA_AXIS))
         # DDP-equivalent gradient sync (mean over the data axis) through the
@@ -401,10 +401,10 @@ def build_train_step(config: PretrainConfig, model, tx, mesh,
                 # the SAME metrics pmean below — no new collectives
                 metrics.update(health.region_health(
                     q, k, grads, step, config.health_stride))
-                if moe_stats:
-                    metrics.update(health.expert_load(
-                        moe_stats, im_q.shape[0] * im_q.shape[1], step,
-                        config.health_stride))
+                if counted:     # a token encoder's own counters, reduced by its family
+                    metrics.update(health.encoder_counters(
+                        token_counters(config.arch)[1], counted,
+                        im_q.shape[0] * im_q.shape[1], step, config.health_stride))
             metrics = lax.pmean(metrics, DATA_AXIS)
         return payload, gs_new, gs_probe, k, new_stats_q, new_stats_k, metrics
 

@@ -54,13 +54,13 @@ def lowered_for_tpu(config, mesh) -> str:
     from moco_tpu.data.augment import (aug_config_for, build_token_views_sharded,
                                        build_two_crops_sharded, token_view_config_for,
                                        with_dtype)
-    from moco_tpu.models.sdar import is_sdar
+    from moco_tpu.models import is_token_encoder
     from moco_tpu.train_state import create_train_state
     from moco_tpu.train_step import (build_encoder, build_fused_step, build_optimizer,
                                      build_train_step)
 
     batch, local = config.batch_size, config.batch_size // mesh.size
-    tokens = is_sdar(config.arch)
+    tokens = is_token_encoder(config.arch)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         model = build_encoder(config)
         tx, sched = build_optimizer(config, STEPS_PER_EPOCH)

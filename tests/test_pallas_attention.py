@@ -7,12 +7,14 @@ run interpreted, outside any `shard_map`; inside one they are only traced
 
 import functools
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from moco_tpu import models
 from moco_tpu.models import sdar
 from moco_tpu.ops import pallas_attention as pa
 
@@ -123,12 +125,12 @@ def test_the_dispatch_rule(case, length, head_dim, block_length, backend, path, 
 def test_this_backend_takes_the_einsums_and_the_module_follows_the_rule(monkeypatch):
     """No knob: `Attention` asks the rule, and the rule asks the backend."""
     assert pa.attention_plan(512, 128, 4)["path"] == "einsum"          # the tests' CPU
-    assert sdar.attention_path("sdar_30b_a3b", 512)["path"] == "einsum"
+    assert models.attention_path("sdar_30b_a3b", 512)["path"] == "einsum"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert sdar.attention_path("sdar_30b_a3b", 512) == {
+    assert models.attention_path("sdar_30b_a3b", 512) == {
         "path": "fused", "tiles": 16, "tiles_skipped": 6, "qk_prep": "fused"}
-    assert sdar.attention_path("sdar_tiny", 16)["path"] == "einsum"
-    assert sdar.attention_path("sdar_tiny", 16)["qk_prep"] == "xla"
+    assert models.attention_path("sdar_tiny", 16)["path"] == "einsum"
+    assert models.attention_path("sdar_tiny", 16)["qk_prep"] == "xla"
     calls = []
     monkeypatch.setattr(sdar, "block_causal_attention",
                         lambda q, k, v, **kw: calls.append((q.shape, k.shape, kw)) or q)
@@ -138,7 +140,7 @@ def test_this_backend_takes_the_einsums_and_the_module_follows_the_rule(monkeypa
     h = jnp.zeros((1, 128, 32))
     params = jax.eval_shape(lambda: module.init_with_output(jax.random.key(0), h)[1])
     # where attention is fused the preparation of q and k is too, on flat arrays
-    prep = dict(dtype=jnp.float32, theta=1e6, eps=1e-6)
+    prep = dict(dtype=jnp.float32, theta=1e6, eps=1e-6, head_dim=128)
     assert calls == [((1, 128, 256), (128,), prep), ((1, 128, 128), (128,), prep),
                      ((1, 128, 256), (1, 128, 128), dict(heads=2, kv_heads=1, block_length=4))]
     # and the parameter tree is the einsum path's
@@ -419,3 +421,106 @@ def test_this_backend_prepares_q_and_k_in_xla_and_its_program_is_the_parents():
     text = jax.jit(both).lower(params, h, h).as_text()
     assert "pallas" not in text and "custom_call" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == TINY_ATTENTION_SHA256
+
+
+# -- ISSUE 31: the kernels at a looped encoder's shapes ----------------------------------
+# `block_length` 1 (a plain causal mask), as many key/value heads as query heads
+# (a group of one) and rotary WITHOUT the per-head norm: what `models/ouro.py`
+# asks of `block_causal_attention` and `norm_rotary(y, None, ...)`.
+
+
+def _causal_inputs(dtype, b=1, length=256, heads=16, seed=4):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return [jax.random.normal(k, (b, length, heads, 128), jnp.float32).astype(dtype) for k in keys]
+
+
+def _rotary_attention(which, q, k, v, g, dtype):
+    """q and k as the projections hand them over (float32), through rotary and
+    the cast and causal attention over 16 heads and 16 key/value heads."""
+    def kernels(q, k, v):
+        q, k = (pa.norm_rotary(flat(x), None, dtype=dtype, theta=THETA, head_dim=128,
+                               interpret=True) for x in (q, k))
+        return pa.block_causal_attention(q, k, flat(v), heads=16, kv_heads=16, block_length=1,
+                                         interpret=True).reshape(v.shape)
+
+    def einsums(q, k, v):
+        q, k = (sdar.rotary(x.astype(jnp.float32), THETA).astype(dtype) for x in (q, k))
+        return sdar.einsum_attention(q, k, v, 1)
+
+    o, vjp = jax.vjp({"kernels": kernels, "einsums": einsums}[which], q, k, v)
+    return (o, *vjp(g))
+
+
+_ROTARY: dict = {}
+
+
+def rotary_results(dtype):
+    if dtype not in _ROTARY:
+        q, k, v, g = _causal_inputs(jnp.dtype(dtype))
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        runs = [jax.jit(_rotary_attention, static_argnums=(0, 5))(which, q, k, v, g, jnp.dtype(dtype))
+                for which in ("kernels", "einsums")]
+        _ROTARY[dtype] = [[np.asarray(x, np.float64) for x in run] for run in runs]
+    return _ROTARY[dtype]
+
+
+@pytest.mark.parametrize("which", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_causal_16_over_16_heads_with_rotary_without_norm_agrees_with_the_einsum_oracle(dtype, which):
+    """Interpret mode, forward and gradients, against `rotary` +
+    `einsum_attention` at `block_length` 1."""
+    i = ["o", "dq", "dk", "dv"].index(which)
+    got, want = (run[i] for run in rotary_results(dtype))
+    assert got.shape == want.shape and np.isfinite(got).all() and np.abs(want).max() > 0
+    assert np.abs(got - want).max() <= TOLERANCE[dtype] * np.abs(want).max()
+
+
+def test_rotary_without_norm_keeps_nothing_and_is_its_own_kernel_name():
+    """The same kernel body with the norm switched off: no scale, no gradient
+    for one, no residual of y (rotary is linear), and the trace names it apart."""
+    y = jax.random.normal(jax.random.key(1), (2, 128, 256), jnp.float32)
+    g = jax.random.normal(jax.random.key(2), y.shape).astype(jnp.bfloat16)
+
+    def alone(y):
+        return pa.norm_rotary(y, None, dtype=jnp.bfloat16, theta=THETA, head_dim=128, interpret=True)
+
+    o, vjp = jax.vjp(alone, y)
+    (dx,) = vjp(g)
+    want = sdar.rotary(y.reshape(2, 128, 2, 128), THETA).reshape(y.shape)
+    assert o.dtype == jnp.bfloat16 and dx.dtype == y.dtype
+    np.testing.assert_allclose(np.asarray(o, np.float32), want, atol=2 ** -7 * float(jnp.abs(want).max()))
+    # rotary is a rotation: its transpose undoes it
+    back = sdar.rotary(np.asarray(dx, np.float32).reshape(2, 128, 2, 128), THETA).reshape(y.shape)
+    np.testing.assert_allclose(back, np.asarray(g, np.float32), atol=2 ** -6 * float(jnp.abs(back).max()))
+    # the backward pass keeps no copy of y: its residuals hold no array of y's size
+    residuals = jax.tree.leaves(jax.vjp(alone, y)[1])
+    assert all(r.size < y.size for r in residuals if hasattr(r, "size"))
+    # a norm of ones with eps 0 on unit-size heads is the same numbers, through the other switch
+    unit = y / jnp.sqrt(jnp.mean(jnp.square(y.reshape(2, 128, 2, 128)), -1, keepdims=True)).repeat(128, -1).reshape(y.shape)
+    normed = pa.norm_rotary(unit, jnp.ones((128,)), dtype=jnp.float32, theta=THETA, eps=0.0, interpret=True)
+    plain = pa.norm_rotary(unit, None, dtype=jnp.float32, theta=THETA, head_dim=128, interpret=True)
+    np.testing.assert_allclose(normed, plain, rtol=2e-6, atol=2e-6)
+
+
+def test_the_looped_cells_shapes_lower_for_the_tpu_forward_and_backward():
+    """`[16, 512, 16 * 128]`, q and k float32 in and bfloat16 out of rotary, a
+    group of one: exported for the TPU platform from the CPU, four Mosaic
+    kernels under their own names."""
+    y = jax.ShapeDtypeStruct((16, 512, 16 * 128), jnp.float32)
+    v = g = jax.ShapeDtypeStruct(y.shape, jnp.bfloat16)
+
+    def both(q, k, v, g):
+        def layer(q, k, v):
+            q, k = (pa.norm_rotary(x, None, dtype=g.dtype, theta=THETA, head_dim=128) for x in (q, k))
+            return pa.block_causal_attention(q, k, v, heads=16, kv_heads=16, block_length=1)
+
+        o, vjp = jax.vjp(layer, q, k, v)
+        return (o, *vjp(g))
+
+    exported = jax.export.export(jax.jit(both), platforms=["tpu"])(y, y, v, g)
+    text = exported.mlir_module()
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(set(names)) == ["_bwd_kernel", "_fwd_kernel", "qk_rotary", "qk_rotary_bwd"]
+    assert "qk_norm_rotary" not in text
+    assert [(x.shape, x.dtype) for x in exported.out_avals] == [
+        (y.shape, g.dtype), (y.shape, y.dtype), (y.shape, y.dtype), (y.shape, g.dtype)]

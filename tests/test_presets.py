@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from moco_tpu.config import PRESETS, PretrainConfig, get_preset
+from moco_tpu.models import is_token_encoder
 from moco_tpu.train_step import build_encoder, build_optimizer
 
 
@@ -19,7 +20,7 @@ def test_pretrain_preset_builds(name):
     s = config.image_size
     kwargs = {"predict": True} if config.variant == "v3" else {}
     # a token encoder is fed ids, an image encoder pictures
-    dummy = (jnp.zeros((1, config.seq_len), jnp.int32) if config.arch.startswith("sdar")
+    dummy = (jnp.zeros((1, config.seq_len), jnp.int32) if is_token_encoder(config.arch)
              else jnp.zeros((1, s, s, 3)))
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), dummy, train=False, **kwargs)

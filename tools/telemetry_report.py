@@ -1180,6 +1180,14 @@ def render(summary: dict) -> str:
                 f"a token to held experts · fullest over mean load "
                 f"{last.get('moe_load_max_over_mean', 0):.3f}"
             )
+        if "ut_pass_delta" in last:
+            # a looped token encoder (models/ouro.py): how often the shared
+            # stack ran and how far its last pass still moved the state
+            lines.append(
+                f"  loop: {last.get('ut_passes', 0):.0f} pass(es) over the shared "
+                f"stack · the last moved the state by {last['ut_pass_delta']:.4f} "
+                f"of its norm"
+            )
         inc = health.get("incidents")
         if inc:
             preds = ", ".join(
