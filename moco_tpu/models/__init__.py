@@ -77,6 +77,18 @@ def attention_path(arch: str, length: int) -> dict:
                           qk_norm=z.get("qk_norm", True))
 
 
+def dispatch_path(arch: str, batch: int, length: int, held: int = 0) -> dict | None:
+    """How the encoder's routed layers move their rows on this backend for
+    `batch` views of `length` tokens a device, with `held` experts here (0: the
+    arch's own number), beside the passes' static sizes: the `moe` block of the
+    run's `setup` event. `None` for an encoder without a router."""
+    if not has_router(arch):
+        return None
+    z = token_sizes(arch)
+    return _token_family(arch).dispatch_path(batch * length, z["hidden"], z["top_k"],
+                                             z["experts"], held or z["experts"])
+
+
 def build_token_encoder(arch: str, num_classes=None, **cut):
     """`cut`: `layers`, `held`, `vocab` (0 is the arch's own number) and the
     module's own arguments (`mlp_head`, `remat`, `dtype`)."""
@@ -108,6 +120,7 @@ __all__ = [
     "has_router",
     "token_counters",
     "attention_path",
+    "dispatch_path",
     "build_token_encoder",
     "ARCHS",
     "FEATURE_DIMS",

@@ -46,6 +46,22 @@ backend or shape (`sdar_tiny`, every CPU test) takes `RMSNorm`, `rotary` and
 `einsum_attention` on `[B, L, heads, head_dim]`, the float32 scores through
 memory: the kernels' oracle. One rule, `attention_plan`, on what the code can
 observe; the run's `setup` event says which path was built.
+
+Where the routed layer's rows move between the token order `[tokens, hidden]`
+and the expert-sorted buffer `[n, hidden]`: on a TPU, with `hidden` a multiple
+of 128, every pass's `n` and `tokens` multiples of 128 (the published
+arch at 32 views of 512 tokens), by asynchronous copies inside
+`ops/pallas_dispatch.py`'s two kernels, each the other's transpose: `dispatch`
+fetches a tile of buffer rows by their tokens and masks the rows past the last
+assignment in VMEM; `combine` sums each token's rows, weighted in float32, into
+a float32 `[tokens, hidden]` written once (a gather in token order over a
+second, small sort of the pass's rows: no zero tensor, no float32 `[n, hidden]`
+product in memory). Any other backend or shape takes `ub[token]`, two `where`s,
+`y.astype(float32) * w` and a scatter-add: the kernels' oracle. One rule again,
+`dispatch_plan`, asked of every pass's size (`dispatch_path`: one answer a
+layer); the `setup` event's `moe` block says which. The grouped products, the
+sort by expert and the arithmetic (float32 weights, float32 sums) are the same
+on both paths.
 """
 
 from __future__ import annotations
@@ -59,6 +75,7 @@ from jax import lax
 
 from moco_tpu.ops.pallas_attention import (attention_plan, block_causal_attention,
                                             norm_rotary)
+from moco_tpu.ops.pallas_dispatch import combine, dispatch, dispatch_plan, listing
 from moco_tpu.telemetry import scopes
 
 # the published sizes by arch (config.json's keys in the comments); the
@@ -238,6 +255,29 @@ def held_rows(tokens: int, top_k: int, experts: int, held: int) -> int:
     return tokens * min(top_k, 2 * -(-top_k * held // experts))
 
 
+def pass_sizes(tokens: int, top_k: int, experts: int, held: int) -> tuple[int, int, int]:
+    """The static shape of a call of `Experts`: the first pass's `rows`, the
+    rows of the small pass that what a skewed router sends beyond them meets
+    first (an eighth of `rows`, so that a small spill costs about what it
+    weighs; 0 where the buffer holds every assignment), and how many whole
+    passes can follow that."""
+    rows = held_rows(tokens, top_k, experts, held)
+    spill = -(-rows // 8) if tokens * top_k > rows else 0
+    return rows, spill, -(-max(tokens * top_k - rows - spill, 0) // rows)
+
+
+def dispatch_path(tokens: int, hidden: int, top_k: int, experts: int, held: int,
+                  backend: str | None = None) -> dict:
+    """How the routed layer moves its rows for `tokens` rows a call on this
+    backend (`ops/pallas_dispatch.py::dispatch_plan`, asked of every pass's
+    size: one answer a layer), beside the passes' static sizes: the `moe` block
+    of the run's `setup` event."""
+    rows, spill, passes = pass_sizes(tokens, top_k, experts, held)
+    plans = {dispatch_plan(tokens, hidden, n, backend) for n in (rows, spill) if n}
+    return {"dispatch": "kernels" if plans == {"kernels"} else "xla", "rows": rows,
+            "spill_rows": spill, "passes": passes}
+
+
 class Router(nn.Module):
     """`u -> logits` over every expert, float32 at `highest`; `trains` False
     makes the kernel a constant of the step."""
@@ -277,18 +317,17 @@ class Experts(nn.Module):
         up = self.param("up", init, (self.held, hidden, self.width), jnp.float32)
         down = self.param("down", init, (self.held, self.width, hidden), jnp.float32)
 
-        rows = held_rows(tokens, self.top_k, self.experts, self.held)
-        # what a skewed router sends beyond the buffer first meets a pass an eighth
-        # its size, so that a small spill costs about what it weighs, and whole
-        # passes after that
-        spill = -(-rows // 8) if tokens * self.top_k > rows else 0
-        passes = -(-max(tokens * self.top_k - rows - spill, 0) // rows)
+        path = dispatch_path(tokens, hidden, self.top_k, self.experts, self.held)
+        rows, spill, passes = path["rows"], path["spill_rows"], path["passes"]
+        kernels = path["dispatch"] == "kernels"
         with jax.named_scope(scopes.MOE_DISPATCH):
             # assignments sorted by expert, those of experts held elsewhere last
             flat = jnp.where(expert < self.held, expert, self.held).reshape(-1)
             order = jnp.pad(jnp.argsort(flat, stable=True),
                             (0, rows + spill + passes * rows - flat.size))
-            sizes = jnp.bincount(flat, length=self.held + 1)[: self.held].astype(jnp.int32)
+            # a count by comparison: `bincount` is a scatter-add of 131 072 ones
+            # into 17 bins, 1.1 ms a call on the chip (my chip run, PR 32)
+            sizes = jnp.sum(flat[:, None] == jnp.arange(self.held)[None, :], 0, dtype=jnp.int32)
             ends = jnp.cumsum(sizes)
             assigned = ends[-1]
             ub, flat_weight = u.astype(self.dtype), weight.reshape(-1)
@@ -305,13 +344,21 @@ class Experts(nn.Module):
 
         def one_pass(first, n):
             """The held experts' part for the sorted assignments `first ..
-            first + n`: gather, three grouped products, scatter-add."""
+            first + n`: rows to the buffer, three grouped products, rows back
+            to their tokens summed in float32. The rows move by
+            `ops/pallas_dispatch.py`'s kernels or, where `dispatch_plan` says
+            `xla`, by a gather and a scatter-add: the kernels' oracle."""
             with jax.named_scope(scopes.MOE_DISPATCH):
                 take = lax.dynamic_slice_in_dim(order, first, n)
                 token = take // self.top_k
                 valid = (first + jnp.arange(n) < assigned)[:, None]
-                x = jnp.where(valid, ub[token], 0)
                 w = jnp.where(valid, flat_weight[take][:, None], 0)
+                if kernels:
+                    count = jnp.clip(assigned - first, 0, n)
+                    by_token = listing(token, count, w, tokens)
+                    x = dispatch(ub, token, count, by_token, dtype=self.dtype)
+                else:
+                    x = jnp.where(valid, ub[token], 0)
                 # each group's rows that fall inside this pass. The rows past the
                 # last assignment are zero rows and ride in the last group: every
                 # row of the static buffer lies in a group, so the product is
@@ -325,6 +372,8 @@ class Experts(nn.Module):
                 y = jax.nn.silu(g) * lax.ragged_dot(x, up.astype(self.dtype), hi - lo)
                 y = lax.ragged_dot(y, down.astype(self.dtype), hi - lo)
             with jax.named_scope(scopes.MOE_DISPATCH):
+                if kernels:
+                    return combine(y, w, token, count, by_token)
                 return nothing().at[token].add(y.astype(jnp.float32) * w)
 
         def spilled(first, n):

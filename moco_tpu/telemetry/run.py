@@ -111,6 +111,7 @@ class RunTelemetry:
         self._setup_s: dict = {}
         self._setup_emitted = False
         self._attn: dict | None = None
+        self._moe: dict | None = None
         self.mfu = MFUEstimator.for_config(config, n_chips, device.device_kind)
         self.devices = DeviceMonitor(device)
         self.pod = PodAggregator(self.registry, n_procs, process_index)
@@ -203,11 +204,18 @@ class RunTelemetry:
         nothing."""
         self._attn = dict(plan)
 
+    def set_moe(self, path: dict) -> None:
+        """How a routed encoder's expert layers move their rows (`models/sdar.py::
+        dispatch_path`: `kernels` or `xla`, and the passes' static sizes).
+        Static per program, as `set_attn`."""
+        self._moe = dict(path)
+
     def _emit_setup(self) -> None:
         """Once, with the first step record: every set-up span's seconds, and
-        the `attn` block where the encoder has one."""
+        the `attn` and `moe` blocks where the encoder has them."""
         self._setup_emitted = True
-        fields = {"attn": self._attn} if self._attn is not None else {}
+        fields = {name: block for name, block in (("attn", self._attn), ("moe", self._moe))
+                  if block is not None}
         self.registry.emit(
             "event", event="setup",
             spans={k: round(v, 6) for k, v in self._setup_s.items()}, **fields,

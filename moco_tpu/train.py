@@ -40,7 +40,7 @@ from moco_tpu.data import (
     epoch_loader,
     token_view_config_for,
 )
-from moco_tpu.models import attention_path, held_vocab, is_token_encoder
+from moco_tpu.models import attention_path, dispatch_path, held_vocab, is_token_encoder
 from moco_tpu.ops.knn import knn_accuracy
 from moco_tpu.parallel.mesh import create_mesh, local_batch_size
 from moco_tpu.resilience import (
@@ -496,6 +496,9 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                                    sched, state=state)
     if telemetry is not None and tokens:
         telemetry.set_attn(attention_path(config.arch, config.seq_len))
+        moe = dispatch_path(config.arch, local_b, config.seq_len, config.num_experts)
+        if moe is not None:
+            telemetry.set_moe(moe)
     if telemetry is not None:
         # static comm facts for the record stream: mode, knobs, analytic
         # per-device sync payload (bytes/step) — rendered by

@@ -344,6 +344,57 @@ def test_the_fused_module_agrees_with_the_einsum_module_on_the_same_parameters(l
     assert np.abs(got - want).max() <= 5e-5 * np.abs(want).max()
 
 
+_LAYER: dict = {}
+LAYER_SIZES = dict(hidden=128, heads=2, kv_heads=1, head_dim=128, experts=8, top_k=4,
+                   expert_width=32, rope_theta=THETA, eps=EPS, block_length=BLOCK)
+LAYER_LEAVES = ["out", "dx", "norm1/scale", "norm2/scale", "attn/q/kernel", "attn/k/kernel",
+                "attn/v/kernel", "attn/o/kernel", "attn/q_norm/scale", "attn/k_norm/scale",
+                "moe/router/kernel", "moe/gate", "moe/up", "moe/down"]
+
+
+def layer_results(monkeypatch):
+    """ISSUE 32: the whole `Layer` (attention and the routed experts, all of
+    them held, so the router trains) as the chip builds it, the four attention
+    kernels and `ops/pallas_dispatch.py`'s two interpreted, and as plain XLA,
+    on the same float32 parameters: output, input gradient, every parameter's."""
+    if not _LAYER:
+        from moco_tpu.ops import pallas_dispatch as pd
+
+        module = sdar.Layer(tuple(sorted(LAYER_SIZES.items())), LAYER_SIZES["experts"])
+        kp, kx, kc = jax.random.split(jax.random.key(13), 3)
+        x = jax.random.normal(kx, (2, 128, LAYER_SIZES["hidden"]))
+        params = module.init(kp, x)["params"]
+        ct = jax.random.normal(kc, x.shape)
+
+        def run():
+            out, vjp = jax.vjp(lambda p, x: module.apply({"params": p}, x), params, x)
+            dp, dx = vjp(ct)
+            flat = {"/".join(k.key for k in path): v
+                    for path, v in jax.tree_util.tree_leaves_with_path(dp)}
+            return {"out": out, "dx": dx, **flat}
+
+        _LAYER["xla"] = {k: np.asarray(v) for k, v in run().items()}
+        with monkeypatch.context() as patch:
+            patch.setattr(sdar, "attention_plan", lambda *a: {"path": "fused"})
+            patch.setattr(sdar, "norm_rotary", functools.partial(pa.norm_rotary, interpret=True))
+            patch.setattr(sdar, "block_causal_attention",
+                          functools.partial(pa.block_causal_attention, interpret=True))
+            patch.setattr(sdar, "dispatch_plan", lambda *a, **k: "kernels")
+            patch.setattr(sdar, "dispatch", functools.partial(pd.dispatch, interpret=True))
+            patch.setattr(sdar, "combine", functools.partial(pd.combine, interpret=True))
+            _LAYER["kernels"] = {k: np.asarray(v) for k, v in run().items()}
+    return _LAYER["kernels"], _LAYER["xla"]
+
+
+@pytest.mark.parametrize("leaf", LAYER_LEAVES)
+def test_the_fused_layer_agrees_with_the_xla_layer_on_the_same_parameters(leaf, monkeypatch):
+    kernels, xla = layer_results(monkeypatch)
+    assert sorted(kernels) == sorted(xla) == sorted(LAYER_LEAVES)
+    got, want = kernels[leaf], xla[leaf]
+    assert got.shape == want.shape and np.abs(want).max() > 0
+    assert np.abs(got - want).max() <= 5e-5 * np.abs(want).max()
+
+
 def test_norm_rotary_outputs_carry_vma_under_a_two_device_shard_map_with_the_check_on():
     """Forward and transpose, as the step's region calls them: y varies over
     the data axis, and so does the scale (`collectives.device_local`: the

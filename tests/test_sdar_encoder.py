@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from moco_tpu import models
 from moco_tpu.models import sdar
 from moco_tpu.telemetry import scopes
 
@@ -321,12 +322,30 @@ def test_the_cli_runs_the_text_preset(tmp_path):
 ])
 def test_step_program_lowers_for_tpu(name, devices, mesh8):
     """The token program at the published widths exports for the TPU platform
-    from the CPU and reaches `attn`'s Mosaic kernels 48 times and no other: in
-    each of 4 layers the attention kernel in the key forward, the query forward,
-    its rematerialised twin and the backward (16), and `norm_rotary` before each
-    of them for q and for k (24 forward, 8 backward: ISSUE 30)."""
-    from step_lowering import cell_config, census_for_tpu
+    from the CPU and reaches `attn`'s Mosaic kernels 48 times: in each of 4
+    layers the attention kernel in the key forward, the query forward, its
+    rematerialised twin and the backward (16), and `norm_rotary` before each of
+    them for q and for k (24 forward, 8 backward: ISSUE 30). And the routed
+    layer's row movers (ISSUE 32), each at its three passes' call sites (the
+    first pass, the small spill pass under `cond`, a whole pass under `scan`):
+    `dispatch` in the three forwards (36), `combine` in the two whose result is
+    used (24: the rematerialised one's is dead), their transposes in the
+    backward (12 and 12)."""
+    import unittest.mock as mock
+
+    from step_lowering import cell_config, census_for_tpu, named_config
 
     layers = cell_config("sdar-30b-a3b-ep8").num_hidden_layers
+    config = named_config(name, batch_size=8, num_hidden_layers=layers)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        moe = models.dispatch_path(config.arch, 8 // devices, config.seq_len, config.num_experts)
+    # the preset holds every expert: one pass
+    sites = {"kernels": 1 + (moe["spill_rows"] > 0) + (moe["passes"] > 0), "xla": 0}[
+        moe["dispatch"]]
+    assert (name, devices, sites) in {("text-moco-v2-sdar", 1, 1), ("cell:sdar-30b-a3b-ep8", 1, 3),
+                                      ("cell:sdar-30b-a3b-ep8", 8, 3)}
+    movers = {"moe_gather": 12 * sites, "moe_combine": 8 * sites,
+              "moe_gather_weighted": 4 * sites, "moe_gather_transpose": 4 * sites}
     assert census_for_tpu(name, devices, mesh8, batch_size=8, num_hidden_layers=layers) == {
-        "_fwd_kernel": 12, "_bwd_kernel": 4, "qk_norm_rotary": 24, "qk_norm_rotary_bwd": 8}
+        "_fwd_kernel": 12, "_bwd_kernel": 4, "qk_norm_rotary": 24, "qk_norm_rotary_bwd": 8,
+        **{kernel: n for kernel, n in movers.items() if n}}

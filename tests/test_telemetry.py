@@ -316,6 +316,61 @@ def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):
     assert ("attention" not in lines[0]) if holds is None else lines[0].endswith(holds)
 
 
+MOE_PATHS = {"kernels": {"dispatch": "kernels", "rows": 32768, "spill_rows": 4096, "passes": 3},
+             "xla": {"dispatch": "xla", "rows": 256, "spill_rows": 32, "passes": 1}}
+
+
+@pytest.mark.parametrize("path", [None, "kernels", "xla"])
+def test_the_setup_event_carries_the_moe_block_beside_the_attn_block(tmp_path, mesh8, path):
+    """ISSUE 32: how a routed encoder moves its expert rows rides the same
+    `setup` event (static per program); an encoder without a router has no such
+    block, and the `attn` block stands as it did."""
+    from moco_tpu.telemetry import RunTelemetry
+
+    config = get_preset("cifar10-moco-v1").replace(
+        telemetry_dir=str(tmp_path), heartbeat_secs=0.0, telemetry_stride=0)
+    tel = RunTelemetry(config, n_chips=8, n_procs=1, process_index=0, steps_per_epoch=4)
+    try:
+        with tel.setup_span("build_step"):
+            pass
+        tel.set_attn(ATTN_PLANS["fused"])
+        if path:
+            tel.set_moe(MOE_PATHS[path])
+        thr = Throughput(8, window=4)
+        thr.update(16)
+        for step in (1, 2):
+            tel.on_step(step, {"step_s": 0.01, "data_s": 0.001, "host_s": 0.001}, thr)
+    finally:
+        tel.close(last_step=2)
+    with open(os.path.join(str(tmp_path), "events.jsonl")) as f:
+        setups = [r for r in map(json.loads, f) if r.get("event") == "setup"]
+    assert len(setups) == 1 and setups[0]["attn"] == ATTN_PLANS["fused"]
+    assert setups[0].get("moe") == (MOE_PATHS[path] if path else None)
+
+
+@pytest.mark.parametrize("path, holds", [
+    ("kernels", "q/k prep fused · expert rows by kernels, 32768 a pass, 4096 in the small spill "
+                "pass, 3 whole passes after it"),
+    ("xla", "q/k prep fused · expert rows by xla, 256 a pass, 32 in the small spill pass, "
+            "1 whole passes after it"),
+    (None, "q/k prep fused"),
+])
+def test_the_report_prints_the_moe_block_at_the_end_of_its_setup_line(path, holds):
+    spec = importlib.util.spec_from_file_location(
+        "telemetry_report", os.path.join(os.path.dirname(__file__), "..", "tools",
+                                         "telemetry_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    setup = {"kind": "event", "event": "setup", "spans": {"model_init": 3.5, "build_step": 0.25},
+             "attn": ATTN_PLANS["fused"]}
+    if path:
+        setup["moe"] = MOE_PATHS[path]
+    records = [setup, {"kind": "step", "step": 1, "step_s": 0.5}]
+    lines = [t for t in report.render(report.summarize(records)).splitlines() if "set-up:" in t]
+    assert len(lines) == 1 and lines[0].endswith(holds)
+    assert ("expert rows" in lines[0]) == (path is not None)
+
+
 # ---------------------------------------------------------------------------
 # MFU / analytic FLOPs
 # ---------------------------------------------------------------------------

@@ -295,8 +295,9 @@ def summarize(records: list[dict], skipped: int = 0) -> dict:
               if e.get("event") == "setup" and isinstance(e.get("spans"), dict)]
     if setups:
         summary["setup_s"] = setups[-1]["spans"]
-        if isinstance(setups[-1].get("attn"), dict):
-            summary["attn"] = setups[-1]["attn"]
+        for block in ("attn", "moe"):
+            if isinstance(setups[-1].get(block), dict):
+                summary[block] = setups[-1][block]
     if pods:
         spreads = [
             p["step_s_max"] - p["step_s_min"]
@@ -884,7 +885,7 @@ def render(summary: dict) -> str:
         )
     setup = summary.get("setup_s")
     if setup:
-        attn = summary.get("attn")
+        attn, moe = summary.get("attn"), summary.get("moe")
         lines.append(
             "set-up: " + " · ".join(
                 f"{name} {secs:.2f} s" for name, secs in
@@ -892,6 +893,9 @@ def render(summary: dict) -> str:
             + (f" · attention {attn.get('path')}, {attn.get('tiles_skipped', 0)} of "
                f"{attn.get('tiles', 0)} score tiles skipped, q/k prep "
                f"{attn.get('qk_prep', 'xla')}" if attn else "")
+            + (f" · expert rows by {moe.get('dispatch')}, {moe.get('rows', 0)} a pass, "
+               f"{moe.get('spill_rows', 0)} in the small spill pass, "
+               f"{moe.get('passes', 0)} whole passes after it" if moe else "")
         )
     isv = summary.get("input_servers")
     if isv:
