@@ -26,6 +26,8 @@ class PretrainConfig:
                                       # widths in models/sdar.py::SDAR_SIZES)
                                       # | ouro_2p6b (a looped dense token encoder:
                                       # models/ouro.py::OURO_SIZES)
+                                      # | keye_vl2_30b_a3b (routed, learned sparse
+                                      # attention: models/keye.py::KEYE_SIZES)
     embed_dim: int = 128              # --moco-dim
     num_negatives: int = 65536        # --moco-k (ignored for v3)
     momentum_ema: float = 0.999       # --moco-m (v3: base for cosine ramp, 0.99)
@@ -883,6 +885,21 @@ PRESETS["text-moco-v2-ouro"] = PRESETS["text-moco-v2-sdar"].replace(
     name="text-moco-v2-ouro",
     arch="ouro_2p6b",
     batch_size=16,
+)
+
+
+# 8. The same recipe over LONG documents with the language stack of
+#    Keye-VL-2.0-30B-A3B as the encoder (models/keye.py: SDAR's routed layer
+#    under learned sparse attention, a 16-head indexer picks 2 048 keys a
+#    query): views of 8 192 tokens, 2 documents a chip. The preset is the
+#    published stack whole; `--num-hidden-layers 4 --num-experts 16
+#    --vocab-size 18992` is one of eight expert-parallel chips' share of four
+#    layers (README).
+PRESETS["text-moco-v2-keye"] = PRESETS["text-moco-v2-sdar"].replace(
+    name="text-moco-v2-keye",
+    arch="keye_vl2_30b_a3b",
+    batch_size=2,
+    seq_len=8192,
 )
 
 

@@ -19,7 +19,8 @@ from moco_tpu.models.heads import V3Predictor, V3Projector
 # reduced for the step's `health` block). What the trainer asks of a token
 # encoder it asks here, of the sizes, never of a family's name.
 _TOKEN_FAMILIES = {"sdar": "moco_tpu.models.sdar",    # routed, block-causal GQA
-                   "ouro": "moco_tpu.models.ouro"}    # dense, weight-shared and looped
+                   "ouro": "moco_tpu.models.ouro",    # dense, weight-shared and looped
+                   "keye": "moco_tpu.models.keye"}    # routed, learned sparse attention
 
 
 def _family_module(arch: str) -> str | None:
@@ -58,6 +59,17 @@ def has_router(arch: str) -> bool:
     return "experts" in token_sizes(arch)
 
 
+def constant_modules(arch: str, held: int = 0) -> tuple:
+    """Names of the encoder's modules whose leaves are constants of the step
+    (`stop_gradient` in the model; the optimizer's mask, `models/sdar.py::
+    trainable_mask`, keeps the weight decay off them): the router of a share of
+    the routed layer (`held` under the router's width), and a family's own
+    (`CONSTANT_MODULES`: a sparse-attention indexer)."""
+    z = token_sizes(arch)
+    share = "experts" in z and 0 < held < z["experts"]
+    return ("router",) * share + tuple(getattr(_token_family(arch), "CONSTANT_MODULES", ()))
+
+
 def token_counters(arch: str) -> tuple:
     """The collections the encoder's forward pass sows its counters into, and
     the family's `health(counted, tokens)` that reduces them for the step's
@@ -69,12 +81,19 @@ def token_counters(arch: str) -> tuple:
 def attention_path(arch: str, length: int) -> dict:
     """The path the encoder's attention takes for views of `length` tokens on
     this backend, with its tile counts and who prepares q and k: the `attn`
-    block of the run's `setup` event."""
+    block of the run's `setup` event. An encoder whose attention selects its
+    keys adds `select`: how many a query, and who scores and selects them."""
     from moco_tpu.ops.pallas_attention import attention_plan
 
     z = token_sizes(arch)
-    return attention_plan(length, z["head_dim"], z["block_length"],
-                          qk_norm=z.get("qk_norm", True))
+    topk = z.get("index_topk", 0)
+    plan = attention_plan(length, z["head_dim"], z["block_length"],
+                          qk_norm=z.get("qk_norm", True), masked=bool(topk))
+    if topk:
+        from moco_tpu.ops.pallas_select import select_plan
+
+        plan["select"] = {"topk": topk, "path": select_plan(length, topk, z["index_dim"])}
+    return plan
 
 
 def dispatch_path(arch: str, batch: int, length: int, held: int = 0) -> dict | None:
@@ -119,6 +138,7 @@ __all__ = [
     "held_vocab",
     "has_router",
     "token_counters",
+    "constant_modules",
     "attention_path",
     "dispatch_path",
     "build_token_encoder",

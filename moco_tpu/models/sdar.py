@@ -27,7 +27,7 @@ alone a chip sees the absent ones as experts that add nothing, and Adam then
 walks the held experts' logits down together until the chip stands empty (at
 lr 1e-4 a token met 0.14 held experts by step 17 where 1.0 is uniform). So
 where `held < experts` the router's kernel is a constant of the step
-(`stop_gradient` here, `router_trainable_mask` for the optimizer's decay: the
+(`stop_gradient` here, `trainable_mask` for the optimizer's decay: the
 frozen patch projection's pattern, `v3_step.patch_embed_trainable_mask`); the
 gradient still flows through the logits into the layer's input. The whole
 layer trains its router.
@@ -74,7 +74,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from moco_tpu.ops.pallas_attention import (attention_plan, block_causal_attention,
-                                            norm_rotary)
+                                            masked_attention, norm_rotary)
 from moco_tpu.ops.pallas_dispatch import combine, dispatch, dispatch_plan, listing
 from moco_tpu.telemetry import scopes
 
@@ -128,19 +128,16 @@ def health(counted, tokens: int) -> dict:
 MOE_CHOICES = "moe_choices"
 
 
-def router_trains(arch: str, held: int = 0) -> bool:
-    """Whether the router's kernel is updated: only where the layer is whole
-    (the module's docstring)."""
-    return not held or held >= SDAR_SIZES[arch]["experts"]
-
-
-def router_trainable_mask(params) -> Any:
-    """Optimizer mask: False for every leaf under a `router` module."""
+def trainable_mask(params, constant=("router",)) -> Any:
+    """Optimizer mask: False for every leaf under a module named in `constant`
+    (the modules whose leaves are constants of the step: a share's `router`; a
+    sparse-attention `indexer`, `models/keye.py`)."""
 
     def is_trainable(path, _leaf):
-        return not any(getattr(entry, "key", None) == "router" for entry in path)
+        return not any(getattr(entry, "key", None) in constant for entry in path)
 
     return jax.tree_util.tree_map_with_path(is_trainable, params)
+
 
 
 def block_causal_mask(length: int, block_length: int) -> jax.Array:
@@ -170,18 +167,22 @@ class RMSNorm(nn.Module):
         return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
-def einsum_attention(q: jax.Array, k: jax.Array, v: jax.Array, block_length: int) -> jax.Array:
+def einsum_attention(q: jax.Array, k: jax.Array, v: jax.Array, block_length: int,
+                     live: jax.Array | None = None) -> jax.Array:
     """Block-causal grouped-query attention as plain einsums, the float32
     scores through memory: q `[B, L, heads, D]`, k and v `[B, L, kv_heads, D]`
     -> `[B, L, heads, D]`. What runs wherever `ops/pallas_attention.py` does
-    not, and that kernel's oracle."""
+    not, and those kernels' oracle. `live` (`[B or 1, L, L]`, nonzero where
+    query `t` sees key `s`) stands in for the mask of positions."""
     b, length, heads, dim = q.shape
     kv_heads = k.shape[2]
     # each key/value head serves `group` query heads
     q = q.reshape(b, length, kv_heads, heads // kv_heads, dim)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(dim))
-    s = jnp.where(block_causal_mask(length, block_length), s, -jnp.inf)
+    seen = (block_causal_mask(length, block_length) if live is None
+            else live[:, None, None].astype(bool))
+    s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, -1).astype(q.dtype)
     return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, length, heads, dim)
 
@@ -212,9 +213,14 @@ class Attention(nn.Module):
     qk_norm: bool = True
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, live=None):
+        """`live` (int8 `[B, L, L]`: which pairs a selection keeps,
+        `ops/pallas_attention.py::masked_attention`) stands in for the mask of
+        positions (`models/keye.py`)."""
         b, length, _ = h.shape
-        fused = attention_plan(length, self.head_dim, self.block_length)["path"] == "fused"
+        path = (attention_plan(length, self.head_dim, self.block_length) if live is None else
+                attention_plan(length, self.head_dim, self.block_length, masked=True))["path"]
+        fused = path != "einsum"
 
         def proj(name, n):
             y = nn.Dense(n * self.head_dim, use_bias=False, dtype=self.dtype,
@@ -231,15 +237,22 @@ class Attention(nn.Module):
                                 dtype=self.dtype, theta=self.rope_theta, eps=self.eps,
                                 head_dim=self.head_dim)
                     for x, name in ((q, "q_norm"), (k, "k_norm")))
-            o = block_causal_attention(q, k, v, heads=self.heads, kv_heads=self.kv_heads,
-                                       block_length=self.block_length)
+            if path == "fused":
+                o = block_causal_attention(q, k, v, heads=self.heads, kv_heads=self.kv_heads,
+                                           block_length=self.block_length)
+            else:
+                # a view longer than a VMEM row of scores: the mask of positions
+                # as the operand that a selection is, one for every batch row
+                if live is None:
+                    live = block_causal_mask(length, self.block_length).astype(jnp.int8)[None]
+                o = masked_attention(q, k, v, live, heads=self.heads, kv_heads=self.kv_heads)
         else:
             def prep(x, name):
                 x = RMSNorm(self.eps, name=name)(x) if self.qk_norm else x.astype(jnp.float32)
                 return rotary(x, self.rope_theta).astype(self.dtype)
 
             q, k = prep(q, "q_norm"), prep(k, "k_norm")
-            o = einsum_attention(q, k, v, self.block_length)
+            o = einsum_attention(q, k, v, self.block_length, live)
             o = o.reshape(b, length, self.heads * self.head_dim)
         return nn.Dense(h.shape[-1], use_bias=False, dtype=self.dtype,
                         param_dtype=jnp.float32, name="o")(o)
@@ -399,9 +412,15 @@ class Experts(nn.Module):
 
 
 class Layer(nn.Module):
+    """`attention`: `None` for `Attention`, or a family's own `(sizes, dtype, h)
+    -> Attn(h)`, which builds its modules in this layer and opens its own scopes
+    (`models/keye.py`: an indexer and a selection beside `attn`, never inside
+    it); the routed layer is this one."""
+
     sizes: Any            # an SDAR_SIZES entry as a tuple of items (hashable)
     held: int
     dtype: Any = jnp.float32
+    attention: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -409,8 +428,14 @@ class Layer(nn.Module):
         b, length, hidden = x.shape
         with jax.named_scope(scopes.ATTN):
             h = RMSNorm(z["eps"], name="norm1")(x).astype(self.dtype)
-            x = x + Attention(z["heads"], z["kv_heads"], z["head_dim"], z["block_length"],
+        if self.attention is None:
+            with jax.named_scope(scopes.ATTN):
+                a = Attention(z["heads"], z["kv_heads"], z["head_dim"], z["block_length"],
                               z["rope_theta"], z["eps"], self.dtype, name="attn")(h)
+        else:
+            a = self.attention(z, self.dtype, h)
+        with jax.named_scope(scopes.ATTN):
+            x = x + a
         with jax.named_scope(scopes.MOE_ROUTER):
             u = RMSNorm(z["eps"], name="norm2")(x).reshape(b * length, hidden)
         y = Experts(z["experts"], self.held, z["top_k"], z["expert_width"], self.dtype,
@@ -433,6 +458,7 @@ class SDAREncoder(nn.Module):
     mlp_head: bool = True
     remat: bool = False
     dtype: Any = jnp.float32
+    attention: Any = None      # `Layer`'s
 
     @nn.compact
     def __call__(self, ids, train: bool = True):
@@ -443,7 +469,8 @@ class SDAREncoder(nn.Module):
                 ids.astype(jnp.int32))
         layer_cls = nn.remat(Layer) if self.remat else Layer
         for i in range(self.layers):
-            x = layer_cls(self.sizes, self.held, self.dtype, name=f"layer_{i}")(x)
+            x = layer_cls(self.sizes, self.held, self.dtype, self.attention,
+                          name=f"layer_{i}")(x)
         with jax.named_scope(scopes.EMBED_POOL):
             feat = jnp.mean(RMSNorm(z["eps"], name="norm")(x), axis=1)
             if self.num_classes is None:

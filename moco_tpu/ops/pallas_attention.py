@@ -63,23 +63,49 @@ HEADS_PER_PROGRAM = 8
 # rows of a batch row that a program of `norm_rotary` takes, at most
 PREP_ROWS = 4 * TILE
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
+# the longest view the whole-row kernel takes: a program's q and o blocks, twice
+# each for the pipeline, are 8 MB of VMEM there
+WHOLE_ROW_MAX = 1024
+# the tiled pair's blocks: q rows a program, keys a grid step
+Q_TILE, KEY_CHUNK = 256, 512
+# a masked score: finite, so that a row whose keys so far are all masked has a
+# running maximum to subtract; what it gathers meanwhile is multiplied by
+# exp(MASKED - m) = 0 when the row's first live key arrives
+MASKED = -1e30
+# dk and dv of a key/value head at 8 192 tokens: two float32 scratches of 4 MB
+# and two output blocks of 2 MB, twice for the pipeline, beside the tiles
+_TILED_BWD_VMEM = 48 * 2 ** 20
 _PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel"))
 
 
 def attention_plan(length: int, head_dim: int, block_length: int,
-                   backend: str | None = None, qk_norm: bool = True) -> dict:
+                   backend: str | None = None, qk_norm: bool = True,
+                   masked: bool = False) -> dict:
     """Which path attention takes for these shapes, how many `TILE` x `TILE`
     score tiles it computes and skips, and who prepares q and k for it
-    (`qk_prep`: `norm_rotary` where attention is fused, `fused` with the
+    (`qk_prep`: `norm_rotary` where attention runs in a kernel, `fused` with the
     per-head norm and `rotary` for an encoder that has none; XLA elsewhere): the
     `attn` block of the `setup` event. The kernels need a TPU, whole lane tiles
-    for a head, whole q tiles, and blocks that do not straddle a tile."""
+    for a head, whole q tiles, and blocks that do not straddle a tile. `fused`
+    is the whole-row kernel, for views of up to `WHOLE_ROW_MAX` tokens whose
+    mask is a function of positions; a longer view, or a mask that is data
+    (`masked`: the caller hands the pairs that are live), takes the `tiled` pair
+    where the view is whole key chunks."""
     side = -(-length // TILE)
-    fused = ((backend or jax.default_backend()) == "tpu" and head_dim % TILE == 0
-             and length % TILE == 0 and TILE % block_length == 0)
-    return {"path": "fused" if fused else "einsum", "tiles": side * side,
-            "tiles_skipped": side * (side - 1) // 2 if fused else 0,
-            "qk_prep": ("fused" if qk_norm else "rotary") if fused else "xla"}
+    aligned = ((backend or jax.default_backend()) == "tpu" and head_dim % TILE == 0
+               and length % TILE == 0 and TILE % block_length == 0)
+    if aligned and not masked and length <= WHOLE_ROW_MAX:
+        path, skipped = "fused", side * (side - 1) // 2
+    elif aligned and all(length % n == 0 for n in _tiling(length)):
+        # every key chunk that reaches under the diagonal of a q tile, whole
+        tq, tk = _tiling(length)
+        computed = sum((tq // TILE) * (tk // TILE) * (_last_chunk(t, tq, tk) + 1)
+                       for t in range(length // tq))
+        path, skipped = "tiled", side * side - computed
+    else:
+        path, skipped = "einsum", 0
+    return {"path": path, "tiles": side * side, "tiles_skipped": skipped,
+            "qk_prep": "xla" if path == "einsum" else "fused" if qk_norm else "rotary"}
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -421,3 +447,241 @@ def norm_rotary(y: jax.Array, scale: jax.Array | None, *, dtype, theta: float, e
     path's backward pass has there too."""
     dim = int(scale.shape[0]) if scale is not None else int(head_dim)
     return _norm_rotary(y, scale, dim, jnp.dtype(dtype), float(theta), float(eps), interpret)
+
+
+# ---------------------------------------------------------------------------
+# views longer than a VMEM row of scores, and masks that are data: the tiled pair
+# ---------------------------------------------------------------------------
+#
+# At 8 192 tokens a row of float32 scores of one head is 32 KB and a q tile's is
+# 8 MB: the keys come by chunks of `KEY_CHUNK` on the innermost grid axis, and a
+# program keeps the running maximum, the running sum and the unnormalised mix of
+# its `Q_TILE` rows in VMEM between them (online softmax). WHICH pairs are live
+# is an operand: `live`, int8 `[B or 1, L, L]`, nonzero where query `t` sees key
+# `s`, zero above the diagonal (the caller's: a selection that is per batch row,
+# or a mask of positions that every row shares). The kernels know of the
+# diagonal only that a key chunk wholly above it holds no live pair: those grid
+# steps do nothing, and their blocks' index maps repeat the step before, so
+# nothing is fetched for them. Every chunk that reaches under the diagonal is
+# computed whole, whatever `live` holds there.
+#
+# The arithmetic is the whole-row kernel's but for the order that online softmax
+# forces: the weights meet v as `exp(s - m)` rounded to `dtype`, with `m` the
+# maximum SO FAR, and the mix is divided by the sum at the end (there, the
+# weights are whole before they are rounded). The backward pass is one kernel on
+# the same grid: scores `[keys, queries]` as `_bwd_kernel`, dq summed over a q
+# tile's chunks in VMEM, dk and dv over a key/value head's q tiles and query
+# heads in a float32 `[L, D]` scratch each.
+
+
+def _tiling(length: int) -> tuple[int, int]:
+    """Rows of a q tile and keys of a chunk for a view of `length` tokens."""
+    return min(Q_TILE, length), min(KEY_CHUNK, length)
+
+
+def _last_chunk(t, tq: int, tk: int):
+    """The last key chunk that reaches under the diagonal of q tile `t`."""
+    return ((t + 1) * tq - 1) // tk
+
+
+def _bias(live_ref):
+    """0 where the pair is live, `MASKED` elsewhere: float32, a q tile's rows by a chunk's keys."""
+    return jnp.where(live_ref[...].astype(jnp.int32) != 0, 0.0, MASKED)
+
+
+def _lane_row(column):
+    """A column of row statistics `[n, 1]` as the lane row `[1, n]`."""
+    n = column.shape[0]
+    return jnp.broadcast_to(column, (n, n)).T[:1, :]
+
+
+def _tiled_fwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, *rest, dim):
+    """One batch row, one key/value head, `n` query heads of its group side by
+    side on the lanes, one q tile, one key chunk: q/o refs `[Q_TILE, n * D]`,
+    k/v refs `[KEY_CHUNK, D]`, live `[Q_TILE, KEY_CHUNK]`, lse `[n, 1, Q_TILE]`
+    (a differentiated call's), then the scratch: the running maximum and sum
+    `[n, Q_TILE, 1]` and the mix `[Q_TILE, n * D]`, float32."""
+    *lse_ref, m_scr, l_scr, acc_scr = rest
+    t, c = pl.program_id(3), pl.program_id(4)
+    last = _last_chunk(t, *live_ref.shape)
+    scale = 1.0 / math.sqrt(dim)
+    heads = [slice(j * dim, (j + 1) * dim) for j in range(q_ref.shape[1] // dim)]
+
+    @pl.when(c == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, MASKED, jnp.float32)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(c <= last)
+    def _():
+        bias = _bias(live_ref)
+        k, v = k_ref[...], v_ref[...]
+        for j, head in enumerate(heads):
+            s = _dot(q_ref[:, head], k, _NT) * scale + bias
+            m_old = m_scr[j]
+            m = jnp.maximum(m_old, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m)
+            alpha = jnp.exp(m_old - m)
+            l_scr[j] = alpha * l_scr[j] + jnp.sum(p, -1, keepdims=True)
+            acc_scr[:, head] = alpha * acc_scr[:, head] + _dot(p.astype(v.dtype), v)
+            m_scr[j] = m
+
+    @pl.when(c == last)
+    def _():
+        for j, head in enumerate(heads):
+            total = l_scr[j]
+            o_ref[:, head] = (acc_scr[:, head] * (1.0 / total)).astype(o_ref.dtype)
+            if lse_ref:
+                lse_ref[0][j] = _lane_row(m_scr[j] + jnp.log(total))
+
+
+def _tiled_bwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta_scr, *, dim):
+    """As `_tiled_fwd_kernel`; dk/dv refs `[L, D]` a key/value head, written once
+    its last q tile of its last program is done. Scores are `[keys, queries]`."""
+    g, t, c = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    tk = live_ref.shape[1]
+    last = _last_chunk(t, *live_ref.shape)
+    scale = 1.0 / math.sqrt(dim)
+    heads = [slice(j * dim, (j + 1) * dim) for j in range(q_ref.shape[1] // dim)]
+
+    @pl.when((g == 0) & (t == 0) & (c == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(c == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for j, head in enumerate(heads):
+            delta = jnp.sum(o_ref[:, head].astype(jnp.float32)
+                            * do_ref[:, head].astype(jnp.float32), -1, keepdims=True)
+            delta_scr[j] = _lane_row(delta)
+
+    @pl.when(c <= last)
+    def _():
+        bias = _bias(live_ref).T
+        k, v = k_ref[...], v_ref[...]
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dv = jnp.zeros(v.shape, jnp.float32)
+        for j, head in enumerate(heads):
+            q, do = q_ref[:, head], do_ref[:, head]
+            p = jnp.exp(_dot(k, q, _NT) * scale + bias - lse_ref[j])
+            ds = (p * (_dot(v, do, _NT) - delta_scr[j])).astype(q.dtype)
+            dv = dv + _dot(p.astype(do.dtype), do)
+            dk = dk + _dot(ds, q)
+            dq_acc[:, head] += _dot(ds.T, k)
+        keys = pl.ds(pl.multiple_of(c * tk, tk), tk)
+        dk_acc[keys, :] += dk
+        dv_acc[keys, :] += dv
+
+    @pl.when(c == last)
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when((g == pl.num_programs(2) - 1) & (t == pl.num_programs(3) - 1) & (c == last))
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _tiled_grid_and_specs(q, live, heads, kv_heads):
+    """The grid `(batch, kv_head, programs a group, q tiles, key chunks)` and its
+    block specs. A chunk above the diagonal repeats the block before it."""
+    b, length, _ = q.shape
+    dim = q.shape[2] // heads
+    n = math.gcd(heads // kv_heads, HEADS_PER_PROGRAM)
+    per = heads // kv_heads // n
+    tq, tk = _tiling(length)
+    shared = live.shape[0] == 1       # one mask for every batch row
+
+    def chunk(t, c):
+        return jnp.minimum(c, _last_chunk(t, tq, tk))
+
+    q_spec = pl.BlockSpec((None, tq, n * dim), lambda b, h, g, t, c: (b, t, h * per + g))
+    kv_spec = pl.BlockSpec((None, tk, dim), lambda b, h, g, t, c: (b, chunk(t, c), h))
+    live_spec = pl.BlockSpec((None, tq, tk),
+                             lambda b, h, g, t, c: (0 if shared else b, t, chunk(t, c)))
+    row_spec = pl.BlockSpec((None, n, 1, tq), lambda b, h, g, t, c: (b, h * per + g, 0, t))
+    whole_spec = pl.BlockSpec((None, length, dim), lambda b, h, g, t, c: (b, 0, h))
+    grid = (b, kv_heads, per, length // tq, length // tk)
+    return grid, (n, tq), q_spec, kv_spec, live_spec, row_spec, whole_spec
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "interpret", "with_lse"))
+def _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse):
+    grid, (n, tq), q_spec, kv_spec, live_spec, row_spec, _ = _tiled_grid_and_specs(
+        q, live, heads, kv_heads)
+    b, length, _ = q.shape
+    dim = q.shape[2] // heads
+    n_out = 2 if with_lse else 1
+    return pl.pallas_call(
+        functools.partial(_tiled_fwd_kernel, dim=dim),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, live_spec],
+        out_specs=[q_spec, row_spec][:n_out],
+        out_shape=_out_shapes((q, k, v, live), (q.shape, q.dtype),
+                              ((b, heads, 1, length), jnp.float32))[:n_out],
+        scratch_shapes=[pltpu.VMEM((n, tq, 1), jnp.float32)] * 2
+        + [pltpu.VMEM((tq, n * dim), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="masked_attention_fwd",
+    )(q, k, v, live)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "interpret"))
+def _tiled_backward(q, k, v, live, o, lse, do, heads, kv_heads, interpret):
+    grid, (n, tq), q_spec, kv_spec, live_spec, row_spec, whole_spec = _tiled_grid_and_specs(
+        q, live, heads, kv_heads)
+    length, dim = q.shape[1], q.shape[2] // heads
+    return pl.pallas_call(
+        functools.partial(_tiled_bwd_kernel, dim=dim),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, live_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, whole_spec, whole_spec],
+        out_shape=_out_shapes((q, k, v, live, do), *((x.shape, x.dtype) for x in (q, k, v))),
+        scratch_shapes=[pltpu.VMEM((tq, n * dim), jnp.float32),
+                        pltpu.VMEM((length, dim), jnp.float32),
+                        pltpu.VMEM((length, dim), jnp.float32),
+                        pltpu.VMEM((n, 1, tq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_TILED_BWD_VMEM),
+        interpret=interpret,
+        name="masked_attention_bwd",
+    )(q, k, v, live, o, do, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _masked(q, k, v, live, heads, kv_heads, interpret):
+    return _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse=False)[0]
+
+
+def _masked_fwd(q, k, v, live, heads, kv_heads, interpret):
+    o, lse = _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse=True)
+    return o, (q, k, v, live, o, lse)
+
+
+def _masked_bwd(heads, kv_heads, interpret, residuals, do):
+    dq, dk, dv = _tiled_backward(*residuals, do, heads=heads, kv_heads=kv_heads,
+                                 interpret=interpret)
+    return dq, dk, dv, None     # which pairs are live is no function of a float
+
+
+_masked.defvjp(_masked_fwd, _masked_bwd)
+
+
+def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array, live: jax.Array, *, heads: int,
+                     kv_heads: int, interpret: bool = False) -> jax.Array:
+    """softmax(q k^T / sqrt(D) over the live pairs) v, for views of any number of
+    `KEY_CHUNK`s: the tiled pair above.
+
+    q, k, v and the result as `block_causal_attention`'s. `live` is int8
+    `[B, L, L]`, or `[1, L, L]` for a mask that every batch row shares: nonzero
+    where query `t` sees key `s`. It must be zero above the diagonal (a chunk
+    wholly above it is never read) and every query must see a key. No gradient
+    passes into it."""
+    return _masked(q, k, v, live, heads, kv_heads, interpret)

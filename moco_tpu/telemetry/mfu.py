@@ -130,6 +130,20 @@ def vit_fwd_flops(arch: str, image_size: int) -> float:
     return flops + depth * per_block
 
 
+def _routed_token_flops(z: dict, held: int) -> float:
+    """What a token of a routed block costs whatever its attention attends to:
+    the q/k/v/o projections, the router over all experts, and the held experts'
+    three products at the assignments uniform routing sends here."""
+    d, hd = z["hidden"], z["head_dim"]
+    return (
+        2.0 * d * (z["heads"] + 2 * z["kv_heads"]) * hd    # q, k, v projections
+        + 2.0 * z["heads"] * hd * d                        # output projection
+        + 2.0 * d * z["experts"]                           # router
+        + z["top_k"] * (held or z["experts"]) / z["experts"]
+        * 3 * 2.0 * d * z["expert_width"]                  # gate, up, down
+    )
+
+
 def sdar_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> float:
     """Forward matmul FLOPs per VIEW (one document's `seq_len` tokens) for
     the routed token encoder in models/sdar.py: per block the q/k/v/o
@@ -141,17 +155,10 @@ def sdar_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> f
     from moco_tpu.models.sdar import SDAR_SIZES
 
     z = SDAR_SIZES[arch]
-    d, hd = z["hidden"], z["head_dim"]
     blocks = -(-seq_len // z["block_length"])
     density = (blocks + 1) / (2.0 * blocks)
-    per_token = (
-        2.0 * d * (z["heads"] + 2 * z["kv_heads"]) * hd    # q, k, v projections
-        + 2.0 * 2 * seq_len * density * z["heads"] * hd    # scores + mix
-        + 2.0 * z["heads"] * hd * d                        # output projection
-        + 2.0 * d * z["experts"]                           # router
-        + z["top_k"] * (held or z["experts"]) / z["experts"]
-        * 3 * 2.0 * d * z["expert_width"]                  # gate, up, down
-    )
+    per_token = (_routed_token_flops(z, held)
+                 + 2.0 * 2 * seq_len * density * z["heads"] * z["head_dim"])    # scores + mix
     return (layers or z["layers"]) * seq_len * per_token
 
 
@@ -192,11 +199,29 @@ def head_fwd_flops(arch: str, embed_dim: int, mlp_head: bool) -> float:
     return 2.0 * feat * embed_dim
 
 
+def selecting_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> float:
+    """Forward matmul FLOPs per VIEW for the routed token encoder with learned
+    sparse attention in models/keye.py: `sdar_fwd_flops`' count with the
+    scores and mix at the SELECTED pairs (`sum_t min(t + 1, topk)` a view, not
+    the causal half), plus the indexer's three projections and its scores over
+    every causal pair."""
+    from moco_tpu.models.keye import KEYE_SIZES
+
+    z = KEYE_SIZES[arch]
+    ih, idim, n = z["index_heads"], z["index_dim"], min(z["index_topk"], seq_len)
+    selected = n * (n + 1) / 2 + (seq_len - n) * z["index_topk"]     # pairs a view
+    causal = seq_len * (seq_len + 1) / 2
+    per_token = _routed_token_flops(z, held) + 2.0 * z["hidden"] * (ih * idim + idim + ih)
+    per_view = 2.0 * 2 * selected * z["heads"] * z["head_dim"] + 2.0 * causal * ih * idim
+    return (layers or z["layers"]) * (seq_len * per_token + per_view)
+
+
 def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
                     embed_dim: int = 128, mlp_head: bool = False, seq_len: int = 512,
                     num_hidden_layers: int = 0, num_experts: int = 0) -> float:
     """Backbone + head forward FLOPs per image (a token encoder: per view of
     `seq_len` tokens) for any supported arch."""
+    from moco_tpu.models.keye import KEYE_SIZES
     from moco_tpu.models.ouro import OURO_SIZES
     from moco_tpu.models.sdar import SDAR_SIZES
 
@@ -208,6 +233,8 @@ def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
         body = sdar_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
     elif arch in OURO_SIZES:
         body = looped_fwd_flops(arch, seq_len, num_hidden_layers)
+    elif arch in KEYE_SIZES:
+        body = selecting_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
     else:
         raise ValueError(f"no analytic FLOPs model for arch {arch!r}")
     return body + head_fwd_flops(arch, embed_dim, mlp_head)

@@ -52,6 +52,14 @@ NORM = "norm"                    # the four sandwich norms, the closing norm, th
 # of layer inputs) is under none of them: it reads under `k_fwd` / `q_fwd_bwd` alone
 LOOPED_SCOPES = (ATTN, MLP, NORM, EMBED_POOL)
 
+# inside a routed token encoder with learned sparse attention (`models/keye.py`),
+# beside `ENCODER_SCOPES`' five: two more siblings of `attn`, never inside it
+# (`attn` keeps the four projections, q and k's norm and rotary, and the
+# attention kernels)
+INDEX = "index"                  # the indexer's projections, LayerNorm, rotary and scores
+SELECT = "select"                # the top-k, and the selection as the kernels are handed it
+SPARSE_SCOPES = (INDEX, SELECT)
+
 # -- host spans -----------------------------------------------------------------
 STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler as
 STEP_ANNOTATION = "train"  # StepTraceAnnotation(STEP_ANNOTATION, step_num=<global step>)

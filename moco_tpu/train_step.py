@@ -38,9 +38,9 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from moco_tpu.config import PretrainConfig
-from moco_tpu.models import (build_resnet, build_token_encoder, has_router, is_token_encoder,
-                             token_counters)
-from moco_tpu.models.sdar import router_trainable_mask, router_trains
+from moco_tpu.models import (build_resnet, build_token_encoder, constant_modules,
+                             is_token_encoder, token_counters)
+from moco_tpu.models.sdar import trainable_mask
 from moco_tpu.telemetry import health, scopes
 from moco_tpu.ops.ema import ema_update, momentum_schedule
 from moco_tpu.ops.losses import (
@@ -167,11 +167,13 @@ def build_optimizer(
         from moco_tpu.v3_step import patch_embed_trainable_mask
 
         tx = optax.masked(tx, patch_embed_trainable_mask)
-    if (is_token_encoder(config.arch) and has_router(config.arch)
-            and not router_trains(config.arch, config.num_experts)):
-        # a share of an expert layer does not train its router (models/sdar.py):
-        # the same pattern, stop_gradient in the model and the mask for the decay
-        tx = optax.masked(tx, router_trainable_mask)
+    constant = (constant_modules(config.arch, config.num_experts)
+                if is_token_encoder(config.arch) else ())
+    if constant:
+        # a share of an expert layer does not train its router (models/sdar.py),
+        # nor a selecting attention its indexer (models/keye.py): the same
+        # pattern, stop_gradient in the model and the mask for the decay
+        tx = optax.masked(tx, lambda params: trainable_mask(params, constant))
     return tx, sched
 
 

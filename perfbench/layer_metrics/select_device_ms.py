@@ -1,0 +1,11 @@
+"""Device milliseconds a traced step spends in events whose innermost nested
+scope is `select` (the exact top-k of every query's index scores and the
+selection as the attention kernels are handed it, with its two counters:
+`jax.named_scope` in `moco_tpu/models/keye.py`; key and query encoder, the
+rematerialised forward too; read by `perfbench/sparse_spans.py`)."""
+
+from perfbench import sparse_spans
+
+
+def read(run):
+    return sparse_spans.scope_ms(run, "select")

@@ -197,13 +197,15 @@ def _package_sources(*subdirs):
             yield path.relative_to(root).as_posix(), path.read_text(encoding="utf-8")
 
 
-def test_the_package_has_three_files_of_mosaic_kernels():
+def test_the_package_has_four_files_of_mosaic_kernels():
     """PERF.md's layers name them (the blur kernel's own layer; `token encoder`
-    for the attention kernels and, since PR 32, the routed layer's row movers);
+    for the attention kernels and, since PR 32, the routed layer's row movers;
+    since PR 33 the sparse attention, index score and selection kernels' own);
     a `pallas_call` anywhere else in the package is a new layer and says so
     there first."""
     holders = {name for name, text in _package_sources("") if "pallas_call" in text}
-    assert holders == {"ops/pallas_blur.py", "ops/pallas_attention.py", "ops/pallas_dispatch.py"}
+    assert holders == {"ops/pallas_blur.py", "ops/pallas_attention.py", "ops/pallas_dispatch.py",
+                       "ops/pallas_select.py"}
 
 
 def test_no_switch_is_read_from_the_environment_in_models_and_ops():

@@ -58,36 +58,38 @@ def test_block_causal_is_causal_at_block_one_and_full_at_block_length(length):
     assert two[0, 1] and not two[1, 2] and (two >= causal).all()
 
 
-def _experts(held, dtype=jnp.float32):
-    return sdar.Experts(Z["experts"], held, Z["top_k"], Z["expert_width"], dtype)
+def _experts(held, dtype=jnp.float32, z=Z):
+    return sdar.Experts(z["experts"], held, z["top_k"], z["expert_width"], dtype)
 
 
-def _layer_params(seed=0):
+def _layer_params(seed=0, z=Z):
     """A whole expert layer's parameters (all 16 experts held) and tokens."""
-    u = jax.random.normal(jax.random.key(seed), (96, Z["hidden"]))
-    params = _experts(Z["experts"]).init(jax.random.key(seed + 1), u)["params"]
+    u = jax.random.normal(jax.random.key(seed), (96, z["hidden"]))
+    params = _experts(z["experts"], z=z).init(jax.random.key(seed + 1), u)["params"]
     return params, u
 
 
-def _share(params, j, held):
+def _share(params, j, held, z=Z):
     """The layer as the chip that holds experts `j*held .. (j+1)*held` sees it:
     the program holds the FIRST experts, so that chip's experts are renumbered
     to the front, the router's columns with them."""
-    n = Z["experts"]
+    n = z["experts"]
     mine = np.arange(j * held, (j + 1) * held)
     perm = np.concatenate([mine, np.setdiff1d(np.arange(n), mine)])
     return {"router": {"kernel": params["router"]["kernel"][:, perm]},
             **{k: params[k][mine] for k in ("gate", "up", "down")}}
 
 
-@pytest.mark.parametrize("held", [2, 4])
-def test_the_shares_expert_outputs_add_up_to_the_uncut_layers(held):
+@pytest.mark.parametrize("arch, held", [("sdar_tiny", 2), ("sdar_tiny", 4), ("keye_tiny", 2)])
+def test_the_shares_expert_outputs_add_up_to_the_uncut_layers(arch, held):
     """16 experts over 8 (or 4) chips: what every chip's share gives, added up,
-    is the whole layer's result; and a share is smaller than the whole."""
-    params, u = _layer_params()
-    whole = _experts(Z["experts"]).apply({"params": params}, u)
-    parts = [_experts(held).apply({"params": _share(params, j, held)}, u)
-             for j in range(Z["experts"] // held)]
+    is the whole layer's result; and a share is smaller than the whole. Every
+    routed family's sizes (`model-configs` section 4): the layer is one module."""
+    z = models.token_sizes(arch)
+    params, u = _layer_params(z=z)
+    whole = _experts(z["experts"], z=z).apply({"params": params}, u)
+    parts = [_experts(held, z=z).apply({"params": _share(params, j, held, z)}, u)
+             for j in range(z["experts"] // held)]
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-6)
     assert float(jnp.abs(parts[0]).max()) > 0
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-3

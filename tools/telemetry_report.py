@@ -893,6 +893,8 @@ def render(summary: dict) -> str:
             + (f" · attention {attn.get('path')}, {attn.get('tiles_skipped', 0)} of "
                f"{attn.get('tiles', 0)} score tiles skipped, q/k prep "
                f"{attn.get('qk_prep', 'xla')}" if attn else "")
+            + (f", top-{attn['select'].get('topk')} selection by {attn['select'].get('path')}"
+               if attn and attn.get("select") else "")
             + (f" · expert rows by {moe.get('dispatch')}, {moe.get('rows', 0)} a pass, "
                f"{moe.get('spill_rows', 0)} in the small spill pass, "
                f"{moe.get('passes', 0)} whole passes after it" if moe else "")
@@ -1191,6 +1193,14 @@ def render(summary: dict) -> str:
                 f"  loop: {last.get('ut_passes', 0):.0f} pass(es) over the shared "
                 f"stack · the last moved the state by {last['ut_pass_delta']:.4f} "
                 f"of its norm"
+            )
+        if "sel_keys_per_query" in last:
+            # a token encoder whose attention selects its keys (models/keye.py):
+            # how many a query kept, and what a tile-skipping kernel could not skip
+            lines.append(
+                f"  sparse: {last['sel_keys_per_query']:.1f} key(s) a query selected · "
+                f"{100 * last.get('sel_live_tile_share', 0):.1f} % of the causal "
+                f"score tiles hold a selected pair (worst layer)"
             )
         inc = health.get("incidents")
         if inc:
