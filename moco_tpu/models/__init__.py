@@ -78,11 +78,15 @@ def token_counters(arch: str) -> tuple:
     return family.STAT_COLLECTIONS, family.health
 
 
-def attention_path(arch: str, length: int) -> dict:
+def attention_path(arch: str, length: int, batch: int = 0, remat: bool = False,
+                   dtype="float32") -> dict:
     """The path the encoder's attention takes for views of `length` tokens on
     this backend, with its tile counts and who prepares q and k: the `attn`
     block of the run's `setup` event. An encoder whose attention selects its
-    keys adds `select`: how many a query, and who scores and selects them."""
+    keys adds `select`: how many a query, and who scores and selects them. Under
+    `remat`, a family whose layers keep named values from the query forward to
+    the backward pass (`kept_by_remat`) adds `kept`: the names this program
+    carries and their bytes a layer for `batch` views a device in `dtype`."""
     from moco_tpu.ops.pallas_attention import attention_plan
 
     z = token_sizes(arch)
@@ -93,7 +97,9 @@ def attention_path(arch: str, length: int) -> dict:
         from moco_tpu.ops.pallas_select import select_plan
 
         plan["select"] = {"topk": topk, "path": select_plan(length, topk, z["index_dim"])}
-    return plan
+    kept_by_remat = getattr(_token_family(arch), "kept_by_remat", None)
+    kept = kept_by_remat(z, plan["path"], batch, length, dtype) if remat and kept_by_remat else None
+    return {**plan, "kept": kept} if kept else plan
 
 
 def dispatch_path(arch: str, batch: int, length: int, held: int = 0) -> dict | None:

@@ -52,9 +52,13 @@ Where the work happens. Nothing of shape `[.., L, L]` a head reaches memory on
 a TPU at the published sizes: the indexer's per-head products and the attention
 scores live in VMEM (`ops/pallas_select.py::index_scores`,
 `ops/pallas_attention.py::masked_attention`, an online-softmax pair that takes
-WHICH pairs are live as an int8 operand). What does reach memory, one layer at a
-time under remat: the head-summed index scores `[B, L, L]` float32 and the
-selection `[B, L, L]` int8. The selection itself is exact and sorts nothing
+WHICH pairs are live as an int8 operand). What does reach memory: the
+head-summed index scores `[B, L, L]` float32, one layer at a time under remat,
+and the selection `[B, L, L]` int8, which a rematerialised layer KEEPS from the
+query forward to the backward pass with the attention kernel's output and
+log-sum-exp (it carries `KEPT_LIVE`, one of `sdar.KEPT`'s names: the backward
+pass runs no indexer and no selection again, and holds a byte a pair a layer for
+it; the key forward keeps nothing). The selection itself is exact and sorts nothing
 (`ops/pallas_select.py::select_top_k`: bisection on the scores' bits). Any other
 backend or shape (`keye_tiny`, every CPU test) takes an einsum, `lax.top_k` and
 `sdar.einsum_attention` under the same mask: the kernels' oracle. The rules are
@@ -73,10 +77,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from moco_tpu.models import sdar
-from moco_tpu.models.sdar import MOE_STATS, Attention, SDAREncoder, dispatch_path, rotary  # noqa: F401
-from moco_tpu.ops.pallas_attention import TILE
+from moco_tpu.models.sdar import (MOE_STATS, Attention, SDAREncoder, dispatch_path,  # noqa: F401
+                                  kept_by_remat, rotary)
+from moco_tpu.ops.pallas_attention import KEPT_LIVE, TILE
 from moco_tpu.ops.pallas_select import index_scores, select_plan, select_top_k
 from moco_tpu.telemetry import scopes
 
@@ -195,7 +201,11 @@ class Indexer(nn.Module):
             q, k, w = lax.stop_gradient((q, k, proj(self.heads, "w")))
             scores = index_scores(q, k, w) if kernels else causal_scores(q, k, w)
         with jax.named_scope(scopes.SELECT):
-            live = (select_top_k if kernels else top_k_selection)(scores, self.topk)
+            # by name, for a rematerialised layer's policy to keep (`sdar.KEPT`): the
+            # counters below and the attention read the named value, so nothing of a
+            # backward pass asks for the scores or the selection again
+            live = checkpoint_name((select_top_k if kernels else top_k_selection)(
+                scores, self.topk), KEPT_LIVE)
             for name, value in (("keys_per_query", jnp.sum(live, dtype=jnp.float32) / (b * length)),
                                 ("live_tile_share", live_tile_share(live))):
                 self.sow(SEL_STATS, name, value, reduce_fn=lambda _, new: new,

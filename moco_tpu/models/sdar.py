@@ -73,8 +73,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from moco_tpu.ops.pallas_attention import (attention_plan, block_causal_attention,
-                                            masked_attention, norm_rotary)
+from moco_tpu.ops.pallas_attention import (KEPT_LIVE, KEPT_LSE, KEPT_OUT, attention_plan,
+                                            block_causal_attention, masked_attention, norm_rotary)
 from moco_tpu.ops.pallas_dispatch import combine, dispatch, dispatch_plan, listing
 from moco_tpu.telemetry import scopes
 
@@ -445,10 +445,10 @@ class Layer(nn.Module):
 
 
 class SDAREncoder(nn.Module):
-    """Token ids `[B, L]` -> the pooled feature (`num_classes=None`) or the v2
-    MLP head's embedding. `layers`, `held` and `vocab` are the cut: how deep,
-    which experts (the first `held`) and which slice of the vocabulary (its
-    first `vocab` ids) live here."""
+    """Token ids `[B, L]` -> the pooled feature (`num_classes=None`) or the v2 MLP head's
+    embedding. `layers`, `held` and `vocab` are the cut: how deep, which experts (the first
+    `held`) and which slice of the vocabulary (its first `vocab` ids) live here. `remat`: the
+    backward pass makes each layer again from its input and from what `KEPT` (below) names."""
 
     sizes: Any
     layers: int
@@ -467,7 +467,7 @@ class SDAREncoder(nn.Module):
             x = nn.Embed(self.vocab, z["hidden"], dtype=self.dtype, param_dtype=jnp.float32,
                          embedding_init=nn.initializers.normal(1.0), name="embed")(
                 ids.astype(jnp.int32))
-        layer_cls = nn.remat(Layer) if self.remat else Layer
+        layer_cls = nn.remat(Layer, policy=kept_policy()) if self.remat else Layer
         for i in range(self.layers):
             x = layer_cls(self.sizes, self.held, self.dtype, self.attention,
                           name=f"layer_{i}")(x)
@@ -479,6 +479,47 @@ class SDAREncoder(nn.Module):
                 feat = nn.relu(nn.Dense(z["hidden"], param_dtype=jnp.float32,
                                         name="fc_hidden")(feat))
             return nn.Dense(self.num_classes, param_dtype=jnp.float32, name="fc")(feat)
+
+
+# What a rematerialised layer keeps from the query forward to the backward pass,
+# beside its input. The rule: a value stays if it is dear to make again and cheap
+# to hold, by the milliseconds of a step that a GB held buys. In the long-document
+# cell (2 views of 8 192 tokens a pass, 4 layers, a v5e; `PERF.md` section 6, PR 34:
+# each row kept alone on the chip, a step of 646.7 ms without either):
+#
+#   kept a layer                          bytes     not run again     ms a step  ms a GB
+#   the tiled attention's output and      134.2 MB  4 forward kernels    62.5      115
+#     log-sum-exp (`KEPT_OUT`, `_LSE`)   + 2.1 MB
+#   the selection, int8 (`KEPT_LIVE`)     134.2 MB  the indexer and      37.5       70
+#                                                   4 + 4 of its kernels
+#   not kept: q, k, v (reckoned)          167.8 MB  3 projections,       12         18
+#                                                   8 `norm_rotary`
+#
+# The names are `ops/pallas_attention.py`'s: its tiled pair names its two results,
+# `models/keye.py::Indexer` its selection. A program that builds neither (the
+# whole-row kernel, every einsum path) carries no such name and keeps its input
+# alone, as under a plain `nn.remat`. No setting: the `setup` event's `attn.kept`
+# says what a built program keeps, `kept_by_remat` from the shapes. The backward
+# pass then reads the forward pass's own bits of the three, not a second making's.
+KEPT = (KEPT_OUT, KEPT_LSE, KEPT_LIVE)
+
+
+def kept_policy():
+    """`nn.remat`'s policy for a layer: what carries a name of `KEPT` stays."""
+    return jax.checkpoint_policies.save_only_these_names(*KEPT)
+
+
+def kept_by_remat(z: dict, path: str, batch: int, length: int, dtype) -> dict | None:
+    """The names of `KEPT` that a rematerialised layer carries and their bytes a
+    layer, from the shapes: the arch's sizes `z`, its attention on `path` (the
+    `attn` block's) over `batch` views of `length` tokens in `dtype`. `None`
+    where the layer carries none."""
+    sizes = {KEPT_LIVE: batch * length * length} if "index_topk" in z else {}
+    if path == "tiled":
+        sizes[KEPT_OUT] = batch * length * z["heads"] * z["head_dim"] * jnp.dtype(dtype).itemsize
+        sizes[KEPT_LSE] = batch * z["heads"] * length * 4
+    names = [name for name in KEPT if name in sizes]
+    return {"names": names, "bytes_per_layer": sum(sizes[n] for n in names)} if names else None
 
 
 def build_sdar(arch: str, num_classes: int | None = None, *, layers: int = 0, held: int = 0,

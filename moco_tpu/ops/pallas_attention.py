@@ -472,6 +472,11 @@ def norm_rotary(y: jax.Array, scale: jax.Array | None, *, dtype, theta: float, e
 # the same grid: scores `[keys, queries]` as `_bwd_kernel`, dq summed over a q
 # tile's chunks in VMEM, dk and dv over a key/value head's q tiles and query
 # heads in a float32 `[L, D]` scratch each.
+#
+# The differentiated call's forward rule hands `o` and the log-sum-exp on BY NAME
+# (`_masked_fwd`): a rematerialised caller whose policy keeps those names runs
+# one backward kernel, where a plain `jax.checkpoint` runs the forward kernel a
+# second time to have the two again. The whole-row pair above names nothing.
 
 
 def _tiling(length: int) -> tuple[int, int]:
@@ -660,8 +665,20 @@ def _masked(q, k, v, live, heads, kv_heads, interpret):
     return _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse=False)[0]
 
 
+# What the backward kernel reads beyond q, k and v, by the names under which a
+# rematerialised caller's policy (`save_only_these_names`; `models/sdar.py::KEPT`
+# has the rule) can keep it: the forward kernel's two results, named below, and
+# the operand `live`, named by the caller that made it (`models/keye.py::Indexer`).
+# `checkpoint_name` is the identity to every caller without such a policy.
+KEPT_OUT, KEPT_LSE, KEPT_LIVE = ("masked_attention_o", "masked_attention_lse",
+                                 "masked_attention_live")
+
+
 def _masked_fwd(q, k, v, live, heads, kv_heads, interpret):
+    from jax.ad_checkpoint import checkpoint_name   # no attribute of `jax`
+
     o, lse = _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse=True)
+    o, lse = checkpoint_name(o, KEPT_OUT), checkpoint_name(lse, KEPT_LSE)
     return o, (q, k, v, live, o, lse)
 
 

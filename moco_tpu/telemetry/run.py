@@ -199,7 +199,8 @@ class RunTelemetry:
     def set_attn(self, plan: dict) -> None:
         """How a token encoder's attention was built (`ops/pallas_attention.py::
         attention_plan`: the path, the score tiles computed and skipped, and who
-        prepares q and k: `qk_prep`).
+        prepares q and k: `qk_prep`; under remat, what a layer keeps from the
+        query forward to the backward pass: `kept`, names and bytes a layer).
         Static per program, so it rides the `setup` event and costs the step
         nothing."""
         self._attn = dict(plan)

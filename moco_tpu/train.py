@@ -495,7 +495,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
         step_fn = build_train_step(config, model, tx, mesh, steps_per_epoch,
                                    sched, state=state)
     if telemetry is not None and tokens:
-        telemetry.set_attn(attention_path(config.arch, config.seq_len))
+        telemetry.set_attn(attention_path(config.arch, config.seq_len, local_b, config.remat,
+                                          config.compute_dtype))
         moe = dispatch_path(config.arch, local_b, config.seq_len, config.num_experts)
         if moe is not None:
             telemetry.set_moe(moe)

@@ -7,6 +7,7 @@ indexer's and a share's router's leaves are constants of the step; the scopes
 `index` and `select` are siblings of `attn`."""
 
 import re
+import unittest.mock as mock
 
 import jax
 import jax.numpy as jnp
@@ -272,6 +273,57 @@ def test_the_step_counts_the_selection(two_steps):
     assert 0.6 < float(metrics["h_moe_assign_per_token"]) < 1.6
 
 
+def _one_step(config, kept=sdar.KEPT):
+    """The loss, the updated parameters and the queue after one fused step, and
+    how often the step's jaxpr holds the indexer's `lax.top_k` (the router's has
+    another `k`); `kept` stands in for `sdar.KEPT` while the step is built."""
+    with mock.patch.object(sdar, "KEPT", kept):
+        fused, state, rows, lengths = build_fused(config, jax.devices()[:1])
+        jaxpr = str(jax.make_jaxpr(fused)(state, rows, lengths, 0))
+        state, metrics = fused(state, rows, lengths, 0)
+    selections = len(re.findall(rf"top_k\[[^\]]*\bk={Z['index_topk']}\b", jaxpr))
+    return jax.device_get((metrics["loss"], state.params_q, state.queue)), selections
+
+
+def test_a_rematerialised_layer_keeps_the_selection_and_changes_no_number():
+    """`remat=True` under the policy against `KEPT` emptied (a plain `nn.remat`,
+    the program before ISSUE 34): the same loss, parameters and enqueued keys bit
+    for bit, from a gradient that selects twice a layer (key forward, query
+    forward) and not a third time inside the backward pass; and against
+    `remat=False` to float32 rounding. (On this backend attention takes the
+    einsums, which name nothing: of `KEPT` the selection alone is carried here;
+    the kernels' pair is `test_pallas_attention.py`'s.)"""
+    kept, picks = _one_step(tiny_config(remat=True))
+    plain, picks_plain = _one_step(tiny_config(remat=True), kept=())
+    whole, picks_whole = _one_step(tiny_config(remat=False))
+    assert (picks, picks_plain, picks_whole) == (2 * Z["layers"], 3 * Z["layers"], 2 * Z["layers"])
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(whole)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_the_setup_events_attn_block_says_what_a_rematerialised_layer_keeps(monkeypatch):
+    """`attn.kept`: the names of `sdar.KEPT` that the built program carries and
+    their bytes a layer from the shapes; absent without remat, for a path that
+    names nothing, and for a family whose remat has no policy."""
+    live = {"names": [pa.KEPT_LIVE], "bytes_per_layer": 4 * LENGTH * LENGTH}
+    assert models.attention_path("keye_tiny", LENGTH, 4, True)["kept"] == live
+    assert "kept" not in models.attention_path("keye_tiny", LENGTH, 4, False)
+    assert "kept" not in models.attention_path("sdar_tiny", 16, 4, True)       # einsums: no name
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = models.attention_path("keye_vl2_30b_a3b", 8192, 2, True, "bfloat16")
+    assert sdar.KEPT == (pa.KEPT_OUT, pa.KEPT_LSE, pa.KEPT_LIVE)
+    # o in bfloat16, the log-sum-exp of 32 heads in float32, a byte a pair: 270 532 608
+    assert cell["kept"] == {"names": list(sdar.KEPT),
+                            "bytes_per_layer": 2 * 8192 * (4096 * 2 + 32 * 4 + 8192)}
+    assert "kept" not in models.attention_path("sdar_30b_a3b", 512, 32, True, "bfloat16")  # whole-row
+    # a mask of positions on the tiled pair: the kernel's two results, no selection
+    assert models.attention_path("sdar_30b_a3b", 8192, 2, True, "bfloat16")["kept"] == {
+        "names": [pa.KEPT_OUT, pa.KEPT_LSE], "bytes_per_layer": 2 * 8192 * (4096 * 2 + 32 * 4)}
+    assert "kept" not in models.attention_path("ouro_2p6b", 4096, 2, True, "bfloat16")   # no policy
+
+
 def components(op_name):
     return re.findall(r"[A-Za-z_0-9]+", op_name)
 
@@ -323,20 +375,28 @@ def test_the_cli_runs_the_long_text_preset(tmp_path):
         expected_sizes(64, 16).mean())
     setup = next(r for r in records if r.get("event") == "setup")
     assert setup["attn"]["select"] == {"topk": 16, "path": "xla"}
+    # the preset rematerialises its layers: the selection of a device's views of 64 stays
+    # (8 views over the devices this process came up with, whatever `--fake-devices` asks)
+    assert setup["attn"]["kept"] == {"names": [pa.KEPT_LIVE],
+                                     "bytes_per_layer": 8 // jax.device_count() * 64 * 64}
 
 
 def test_step_program_lowers_for_tpu_with_its_kernels():
     """The long-view program at the published widths exports for the TPU platform
     from the CPU: in each of 4 layers the scores, the selection and the attention
-    kernel in the key forward, the query forward and its rematerialised twin
-    (12 each), the attention's backward (4), `norm_rotary` as SDAR's (24 and 8),
-    and the routed layer's row movers at their three call sites."""
+    kernel in the key forward and the query forward (8 each). Their rematerialised
+    twin inside the backward pass is gone since the layer keeps the attention's
+    output and log-sum-exp and the selection by name (`sdar.KEPT`; 12 each under a
+    plain `nn.remat`); the attention's backward (4), `norm_rotary` as SDAR's (24
+    and 8: q and k are made again) and the routed layer's row movers at their
+    three call sites stay. `test_sdar_encoder.py`'s census is the control: the
+    policy touches no other family."""
     from step_lowering import census_for_tpu
 
     census = census_for_tpu("cell:keye-vl2-30b-a3b-ep8", 1, None, batch_size=2)
     assert {k: census[k] for k in ("index_scores", "select_top_k", "masked_attention_fwd",
                                    "masked_attention_bwd", "qk_norm_rotary",
                                    "qk_norm_rotary_bwd")} == {
-        "index_scores": 12, "select_top_k": 12, "masked_attention_fwd": 12,
+        "index_scores": 8, "select_top_k": 8, "masked_attention_fwd": 8,
         "masked_attention_bwd": 4, "qk_norm_rotary": 24, "qk_norm_rotary_bwd": 8}
     assert census["moe_gather"] == 36 and "_fwd_kernel" not in census

@@ -575,3 +575,43 @@ def test_the_looped_cells_shapes_lower_for_the_tpu_forward_and_backward():
     assert "qk_norm_rotary" not in text
     assert [(x.shape, x.dtype) for x in exported.out_avals] == [
         (y.shape, g.dtype), (y.shape, y.dtype), (y.shape, y.dtype), (y.shape, g.dtype)]
+
+
+# -- what a rematerialised caller keeps of the tiled pair (ISSUE 34) ------------------
+
+
+def _pallas_calls(jaxpr, name):
+    """Mosaic calls of the kernel `name` in a jaxpr and every jaxpr inside it."""
+    here = sum(eqn.params["name"] == name for eqn in jaxpr.eqns
+               if eqn.primitive.name == "pallas_call")
+    return here + sum(_pallas_calls(sub, name) for eqn in jaxpr.eqns
+                      for sub in jax.core.jaxprs_in_params(eqn.params))
+
+
+def test_a_checkpointed_masked_attention_keeps_its_results_by_name_and_runs_one_forward():
+    """Under `save_only_these_names` of the tiled pair's names the gradient of a
+    `jax.checkpoint`ed call holds ONE forward kernel where a plain
+    `jax.checkpoint` holds two (the second makes `o` and the log-sum-exp again
+    for the backward kernel), and dq, dk, dv are the same bits; without a policy
+    the names change nothing."""
+    b, length, heads, kv, dim = 1, 256, 8, 2, 128
+    q, k, v, g = (jax.random.normal(jax.random.key(i), (b, length, n * dim)) for i, n in
+                  enumerate((heads, kv, kv, heads)))
+    live = jnp.tril(jnp.ones((length, length), jnp.int8))[None]
+
+    def loss(q, k, v):     # the product stands for what a layer does before the kernel
+        return jnp.sum(pa.masked_attention(q * 1.5, k, v, live, heads=heads, kv_heads=kv,
+                                           interpret=True) * g)
+
+    policy = jax.checkpoint_policies.save_only_these_names(pa.KEPT_OUT, pa.KEPT_LSE)
+    grads, forwards = {}, {}
+    for label, f in (("kept", jax.checkpoint(loss, policy=policy)),
+                     ("plain", jax.checkpoint(loss)), ("whole", loss)):
+        grad = jax.grad(f, (0, 1, 2))
+        jaxpr = jax.make_jaxpr(grad)(q, k, v).jaxpr
+        assert _pallas_calls(jaxpr, "masked_attention_bwd") == 1, label
+        forwards[label], grads[label] = _pallas_calls(jaxpr, "masked_attention_fwd"), grad(q, k, v)
+    assert forwards == {"kept": 1, "plain": 2, "whole": 1}
+    for other in ("plain", "whole"):
+        for a, b_ in zip(grads["kept"], grads[other]):
+            np.testing.assert_array_equal(a, b_)

@@ -332,7 +332,10 @@ def test_step_program_lowers_for_tpu(name, devices, mesh8):
     first pass, the small spill pass under `cond`, a whole pass under `scan`):
     `dispatch` in the three forwards (36), `combine` in the two whose result is
     used (24: the rematerialised one's is dead), their transposes in the
-    backward (12 and 12)."""
+    backward (12 and 12). Since ISSUE 34 also the control of `sdar.KEPT`: the
+    whole-row kernel names nothing, so the layer's `nn.remat` keeps what it
+    kept and the rematerialised twin stays (the tiled pair's is gone:
+    `test_keye_encoder.py`)."""
     import unittest.mock as mock
 
     from step_lowering import cell_config, census_for_tpu, named_config

@@ -263,10 +263,16 @@ def test_close_preempted_marks_heartbeat_phase(tmp_path, mesh8):
 
 
 ATTN_PLANS = {"fused": {"path": "fused", "tiles": 16, "tiles_skipped": 6, "qk_prep": "fused"},
-              "einsum": {"path": "einsum", "tiles": 1, "tiles_skipped": 0, "qk_prep": "xla"}}
+              "einsum": {"path": "einsum", "tiles": 1, "tiles_skipped": 0, "qk_prep": "xla"},
+              # ISSUE 34: what a rematerialised layer keeps rides the same block
+              "tiled": {"path": "tiled", "tiles": 4096, "tiles_skipped": 1920, "qk_prep": "fused",
+                        "select": {"topk": 2048, "path": "kernels"},
+                        "kept": {"names": ["masked_attention_o", "masked_attention_lse",
+                                           "masked_attention_live"],
+                                 "bytes_per_layer": 270532608}}}
 
 
-@pytest.mark.parametrize("path", [None, "fused", "einsum"])
+@pytest.mark.parametrize("path", [None, "fused", "einsum", "tiled"])
 def test_the_setup_event_carries_the_attn_block_beside_its_spans(tmp_path, mesh8, path):
     """ISSUE 28: how a token encoder's attention was built rides the one `setup`
     event (static per program: no step record pays for it); a run without a
@@ -298,6 +304,8 @@ def test_the_setup_event_carries_the_attn_block_beside_its_spans(tmp_path, mesh8
     ("einsum", "attention einsum, 0 of 1 score tiles skipped, q/k prep xla"),
     # a record from before ISSUE 30 has no `qk_prep`: XLA prepared q and k then
     ("fused_without_qk_prep", "attention fused, 6 of 16 score tiles skipped, q/k prep xla"),
+    ("tiled", "attention tiled, 1920 of 4096 score tiles skipped, q/k prep fused, top-2048 "
+              "selection by kernels, remat keeps 3 named values, 270.5 MB a layer"),
     (None, None),
 ])
 def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):

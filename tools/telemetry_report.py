@@ -895,6 +895,9 @@ def render(summary: dict) -> str:
                f"{attn.get('qk_prep', 'xla')}" if attn else "")
             + (f", top-{attn['select'].get('topk')} selection by {attn['select'].get('path')}"
                if attn and attn.get("select") else "")
+            + (f", remat keeps {len(attn['kept'].get('names', ()))} named values, "
+               f"{attn['kept'].get('bytes_per_layer', 0) / 1e6:.1f} MB a layer"
+               if attn and attn.get("kept") else "")
             + (f" · expert rows by {moe.get('dispatch')}, {moe.get('rows', 0)} a pass, "
                f"{moe.get('spill_rows', 0)} in the small spill pass, "
                f"{moe.get('passes', 0)} whole passes after it" if moe else "")
