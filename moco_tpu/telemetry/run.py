@@ -35,10 +35,23 @@ from moco_tpu.telemetry.registry import (
 )
 from moco_tpu.data.stats import InputPipelineStats
 from moco_tpu.telemetry import scopes
-from moco_tpu.telemetry.timing import StepPhaseTimer
-from moco_tpu.telemetry.trace import SlowSampleDetector, Tracer, null_tracer
+from moco_tpu.telemetry.timing import (
+    LOOP_FIELD,
+    PHASE_FIELDS,
+    GcWatch,
+    StepPhaseTimer,
+)
+from moco_tpu.telemetry.trace import (
+    SlowSampleDetector,
+    StallDetector,
+    Tracer,
+    null_tracer,
+)
 from moco_tpu.utils import logging as mlog
 from moco_tpu.utils.cache import CompileCounters
+
+#: the loop span that a step record's field times (`scopes.STEP_PHASES`)
+_SPAN_OF_FIELD = {field: name for name, field in scopes.STEP_PHASES.items()}
 
 
 class RunTelemetry:
@@ -54,7 +67,9 @@ class RunTelemetry:
         # span layer (ISSUE 8): process 0 only, like every file sink. The
         # tracer exists even at trace_mode="off" — that is what makes the
         # SIGUSR1 / trigger-file / anomaly capture windows reachable on a
-        # run that wasn't started with tracing on.
+        # run that wasn't started with tracing on. `lookback`: at `off` the
+        # staging threads' coarse spans are held in memory, so that a
+        # `stall` (below) can write them beside the slow step (ISSUE 35).
         self.tracer = (
             Tracer(
                 run_dir,
@@ -62,6 +77,7 @@ class RunTelemetry:
                 proc="driver",
                 capture_steps=getattr(config, "trace_capture_steps", 50),
                 capture_budget=getattr(config, "trace_capture_budget", 3),
+                lookback=True,
             )
             if is_main else null_tracer()
         )
@@ -87,6 +103,12 @@ class RunTelemetry:
         k = getattr(config, "trace_slow_step_k", 3.0)
         self._slow_step = SlowSampleDetector(k=k, floor_s=0.005, skip=3)
         self._input_stall = SlowSampleDetector(k=k, floor_s=0.25, skip=3)
+        # the stall rule (ISSUE 35; `trace.is_stall`): its event says in
+        # which phase the time went and has the look-back ring written.
+        # It arms no capture: the k × p95 detectors above do, as before.
+        self._stall = StallDetector(PHASE_FIELDS + (LOOP_FIELD,))
+        # collections of the interpreter, counted where they happen
+        self.gc = GcWatch()
         self.registry = MetricsRegistry(
             self.events_path if is_main else None,
             flush_every=config.telemetry_flush_steps,
@@ -196,6 +218,18 @@ class RunTelemetry:
                 self._setup_s[name] = (self._setup_s.get(name, 0.0)
                                        + time.perf_counter() - t0)
 
+    def _stalled_step(self, step: int, phases: dict) -> dict | None:
+        """The step the timer closed last as `Tracer.dump_lookback` takes
+        it: its window, its record's phases, and the interval of each phase
+        under its span's name (`scopes.STEP_PHASES`)."""
+        if self.timer.last_step is None:
+            return None
+        t0, t1, booked = self.timer.last_step
+        return {"window": (t0, t1),
+                "attrs": dict({k: round(float(v), 6)
+                               for k, v in phases.items()}, step=int(step)),
+                "spans": [(_SPAN_OF_FIELD[f], a, b) for f, a, b in booked]}
+
     def set_attn(self, plan: dict) -> None:
         """How a token encoder's attention was built (`ops/pallas_attention.py::
         attention_plan`: the path, the score tiles computed and skipped, and who
@@ -235,11 +269,26 @@ class RunTelemetry:
         report's `health:` section read exactly that shape.
 
         Everything this method does — record building, span recording,
-        capture-window ticks, detector checks — is measured and booked
-        back into the phase timer as the `telemetry` sub-phase, so the
+        capture-window ticks, detector checks, a stall's dump of the
+        look-back ring — runs inside the driver's `telemetry` span and
+        phase, which book it as the NEXT record's `telemetry_s`, so the
         phase-share report never blames the input pipeline for the span
         layer's own cost (ISSUE 8 satellite)."""
-        t_tel0 = time.perf_counter()
+        gc_seen = self.gc.drain()
+        stall = self._stall.observe(phases)
+        if stall is not None:
+            dump = self.tracer.can_dump()
+            self.registry.emit(
+                "event", event="stall", step=int(step),
+                step_s=phases["step_s"], **stall,
+                phases={k: round(float(v), 6) for k, v in phases.items()},
+                gc_s=gc_seen.get("gc_s", 0.0), gc2_n=gc_seen.get("gc2_n", 0),
+                starved=int(phases.get("starved", 0)),
+                queue_depth=self.input_stats.queue_depth_last,
+                dump=dump,
+            )
+            if dump:
+                self.tracer.dump_lookback(self._stalled_step(step, phases))
         # anomaly → capture window (budgeted): check BEFORE the step span
         # records, so the capture's full-detail window starts as early as
         # the step after the anomaly
@@ -283,6 +332,7 @@ class RunTelemetry:
         record["compile"] = compiles
         for key, value in phases.items():
             record[key] = round(value, 6)
+        record.update(gc_seen)
         if phases.get("step_s"):
             # the data-stall share, stamped per record (ISSUE 12): the
             # SLO rules and the live tail key on it directly instead of
@@ -334,12 +384,6 @@ class RunTelemetry:
                 last_step_ms=round(phases["step_s"] * 1e3, 1),
                 trace=self.tracer.capture_state(),
             )
-        # book everything this method cost (the tracer's tick/flush work
-        # ran inside this window, so the measurement already covers it;
-        # span flushes on the STAGING threads are concurrent with the
-        # step and deliberately not booked — they are not main-thread
-        # time) into the explicit telemetry sub-phase
-        self.timer.note_telemetry(time.perf_counter() - t_tel0)
         return flushed
 
     # -- pod sync (piggybacks on the resilience_sync_steps allgather) --------
@@ -359,6 +403,7 @@ class RunTelemetry:
         self._closed = True
         mlog.remove_event_sink(self._on_event)
         self.compiles.close()
+        self.gc.close()
         summary = dict(
             steps=self._step_hist.count,
             incidents=self._incidents.value,

@@ -65,6 +65,13 @@ STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler 
 STEP_ANNOTATION = "train"  # StepTraceAnnotation(STEP_ANNOTATION, step_num=<global step>)
 LOOP_SPANS = ("data_wait", "dispatch", "fence", "sentinel", "loss_readback",
               "telemetry", "checkpoint")
+# the loop's spans that are also a field of the step record (`timing.py`): the
+# driver opens each with `timer.phase(<field>)` as the same `with`'s next item,
+# so span and field are one interval; what is under none of them is the
+# record's `loop_s`
+STEP_PHASES = {"data_wait": "data_s", "dispatch": "host_s", "sentinel": "wait_s",
+               "fence": "fence_s", "loss_readback": "readback_s",
+               "telemetry": "telemetry_s"}
 SETUP_SPANS = ("create_train_state", "model_init", "opt_init", "place_state",
                "build_step", "first_batch", "restore")
 INPUT_SPANS = ("stage_batch", "decode_slice", "gather", "h2d_shard")

@@ -17,9 +17,9 @@ actually happens. This module is the span layer every process shares:
     inside its per-launch span, the child's Tracer picks the parent up at
     construction, and thread-side spans (staging workers) continue a
     coordinator span through an explicit `parent=span.context()`.
-  - `trace_mode` knob, off by default: `off` records nothing, `steps`
-    records the coarse spans (one per step / staged batch / serve flush /
-    supervisor launch), `full` additionally records the detail spans
+  - `trace_mode` knob, off by default: `off` writes nothing, `steps`
+    writes the coarse spans (one per step / staged batch / serve flush /
+    supervisor launch), `full` additionally writes the detail spans
     (the step's data_wait / dispatch / telemetry children, worker decode
     slices, per-shard H2D puts, engine calls). `trace_mode` governs
     `spans.jsonl` ONLY: where an `annotation_factory` is installed (the
@@ -28,6 +28,18 @@ actually happens. This module is the span layer every process shares:
     annotation, at every mode including `off`, so a profiler session —
     a capture window's device trace, the benchmark's traced steps —
     holds the program's spans on the profiler's own clock (ISSUE 25).
+  - A LOOK-BACK ring (ISSUE 35): a tracer built with `lookback=True` (the
+    train driver's) holds, at `off`, the coarse spans of every thread but
+    its owner's (the staging threads' `stage_batch`) in a second ring in
+    memory (a `deque(maxlen=...)`, overwritten, never flushed by count).
+    It reaches `spans.jsonl` only through `dump_lookback()`, which
+    `RunTelemetry.on_step` calls after a `stall` (`is_stall`, below) with
+    the stalled step's own clock reads: the file then holds the slow step
+    ITSELF, written from the step record's intervals, beside what the
+    staging threads did before and during it. The owner's spans stay
+    annotations alone at `off` (the step record already holds their
+    intervals), so the loop pays nothing for the ring. A capture window
+    opens at the step AFTER an anomaly; the ring is what was already there.
   - On-demand and anomaly-triggered CAPTURE: SIGUSR1 or a
     `<telemetry_dir>/trace.trigger` file arms a bounded window during
     which the effective mode is `full` (and, when hooks are installed, a
@@ -47,8 +59,10 @@ into one Chrome-trace/Perfetto JSON.
 
 from __future__ import annotations
 
+import itertools
 import os
 import signal
+import statistics
 import threading
 import time
 import uuid
@@ -69,7 +83,10 @@ _LEVEL = {"off": 0, "steps": 1, "full": 2}
 
 def new_id() -> str:
     """16-hex-char id (64 random bits): short enough to read in a report,
-    long enough that a run's span set never collides."""
+    long enough that a run's span set never collides. For ids made once a
+    process (run, trace, a tracer's prefix): a span's id is the tracer's
+    prefix and a counter (`Tracer._span_id`), no random draw, no system
+    call."""
     return uuid.uuid4().hex[:16]
 
 
@@ -136,6 +153,96 @@ class SlowSampleDetector:
         )
         self._window.append(value)
         return anomalous
+
+
+#: the stall rule's numbers (`perfbench/step_phases.py` keeps a copy, held
+#: to these by a test): a step is a stall when it exceeds what it is
+#: expected to take by at least `STALL_MIN_EXCESS_S` seconds AND
+#: `STALL_MIN_SHARE` of the rolling median of the last `STALL_WINDOW` records
+STALL_WINDOW = 64
+STALL_MIN_EXCESS_S = 0.1
+STALL_MIN_SHARE = 0.25
+#: a step that reads its OWN loss back (the stride-gated fence, the print's
+#: and the health block's read-backs) waits for the step before and for
+#: itself: two steps of the device are its routine (and the step after it,
+#: dispatched to an idle device, is short by as much)
+STALL_DRAIN_FIELDS = ("fence_s", "readback_s")
+#: so does a step whose DISPATCH took a whole step or more: it waited for
+#: the device inside the call (a caller that blocks on the result there, as
+#: the benchmark's harness does to close its window)
+STALL_SYNC_FIELD = "host_s"
+
+
+def drained(phases: dict, median_s: float) -> bool:
+    """Whether the step waited for its own result: by a read-back of its
+    loss, or inside its dispatch."""
+    return (any(phases.get(f) for f in STALL_DRAIN_FIELDS)
+            or phases.get(STALL_SYNC_FIELD, 0.0) >= median_s > 0.0)
+
+
+def stall_expected_s(median_s: float, drained: bool = False) -> float:
+    """What a step is expected to take: the rolling median, twice it where
+    the step drained the queue itself."""
+    return (2.0 if drained else 1.0) * median_s
+
+
+def is_stall(step_s: float, median_s: float, drained: bool = False) -> bool:
+    """The one rule (ISSUE 35). `k` × p95 (`SlowSampleDetector`, which arms
+    the capture window) never sees a 0.9 s stall of a 0.67 s step (2.3
+    times the usual); this does, and a routine longest step (0.689 s
+    against 0.672) does not meet it. `drained`: the step waited for its own
+    result, so it is held against twice the median (on the chip every fenced
+    and every printing step of the R50 cell reads 263 ms against a median
+    of 131: a rule that took those for stalls fired 54 times a window)."""
+    excess = step_s - stall_expected_s(median_s, drained)
+    return excess >= STALL_MIN_EXCESS_S and excess >= STALL_MIN_SHARE * median_s
+
+
+class StallDetector:
+    """`observe(phases)` → None, or on a stall what `RunTelemetry` puts in
+    the `stall` event: the rolling median of `step_s`, what the step was
+    expected to take (twice that where it drained the queue itself:
+    `drained`), its excess over that, every phase's excess over
+    ITS OWN rolling median (a phase a record leaves out counts as 0), and
+    the phase that holds most of it (the field's name without `_s`). The
+    first `skip` records are dropped (the compiling steps) and
+    `min_samples` prior ones are needed, as in `SlowSampleDetector`; every
+    sample joins the window (one outlier in 64 does not move a median).
+    Not thread-safe: one caller owns it."""
+
+    def __init__(self, fields: tuple, window: int = STALL_WINDOW,
+                 min_samples: int = 8, skip: int = 3):
+        self.fields = tuple(fields)
+        self.min_samples = int(min_samples)
+        self._skip = int(skip)
+        self._window: deque = deque(maxlen=int(window))
+        # the window's `step_s` alone: the one median every step needs
+        self._steps: deque = deque(maxlen=int(window))
+
+    def observe(self, phases: dict) -> dict | None:
+        if self._skip > 0:
+            self._skip -= 1
+            return None
+        found = None
+        step_s = float(phases["step_s"])
+        if len(self._steps) >= self.min_samples:
+            median_s = statistics.median(self._steps)
+            waited = drained(phases, median_s)
+            if is_stall(step_s, median_s, waited):
+                expected_s = stall_expected_s(median_s, waited)
+                excess = {
+                    f[:-2]: round(
+                        float(phases.get(f, 0.0)) - statistics.median(
+                            float(p.get(f, 0.0)) for p in self._window), 6)
+                    for f in self.fields}
+                found = {"median_s": round(median_s, 6),
+                         "expected_s": round(expected_s, 6),
+                         "excess_s": round(step_s - expected_s, 6),
+                         "excess": excess,
+                         "phase": max(excess, key=excess.get)}
+        self._window.append(phases)
+        self._steps.append(step_s)
+        return found
 
 
 class SpikeDetector:
@@ -230,20 +337,21 @@ class Span:
 
     __slots__ = ("_tracer", "name", "cat", "trace_id", "span_id",
                  "parent_id", "attrs", "_t_wall", "_t0", "_entered",
-                 "_annotation")
+                 "_annotation", "_held")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  parent: tuple[str, str] | None, attrs: dict,
-                 annotation=None):
+                 annotation=None, held: bool = False):
         self._tracer = tracer
         self._annotation = annotation
+        self._held = held  # for the look-back ring, not for the file
         self.name = name
         self.cat = cat
         if parent is None:
             parent = tracer.current_context()
         self.trace_id = parent[0] if parent else tracer.trace_id
         self.parent_id = parent[1] if parent else tracer.root_parent
-        self.span_id = new_id()
+        self.span_id = tracer._span_id()
         self.attrs = attrs
         self._t_wall = 0.0
         self._t0 = 0.0
@@ -276,6 +384,7 @@ class Span:
         self._tracer._record(
             self.name, self.cat, self._t_wall, dur_s,
             self.trace_id, self.span_id, self.parent_id, self.attrs,
+            self._held,
         )
         return False
 
@@ -292,6 +401,7 @@ class _NullTracer:
     capture_budget = 0
     spans_recorded = 0
     spans_written = 0
+    lookback = False
     profiler_hooks = None
     annotation_factory = None
 
@@ -331,6 +441,12 @@ class _NullTracer:
     def flush(self):
         pass
 
+    def can_dump(self):
+        return False
+
+    def dump_lookback(self, step=None):
+        return None
+
     def close(self):
         pass
 
@@ -365,6 +481,12 @@ class Tracer:
     `RunTelemetry.on_step`, whose own clock books them as the step's
     `telemetry` sub-phase.
 
+    `lookback=True` (the train driver's) keeps, at `off`, the coarse spans
+    of the threads other than the one that built the tracer in a second
+    ring of `ring_size` spans, which overwrites itself and is written by
+    `dump_lookback()` alone, at most `dump_budget` times a run (module
+    docstring).
+
     `annotation_factory` (None, or `factory(name, attrs) -> context
     manager`) is injected like `profiler_hooks`, because this module stays
     jax-free: when set, every span enters the annotation it makes, whether
@@ -375,7 +497,8 @@ class Tracer:
                  parent: tuple[str, str] | None = None,
                  capture_steps: int = 50, capture_budget: int = 3,
                  ring_size: int = 4096, flush_every: int = 256,
-                 trigger_poll_secs: float = 1.0):
+                 trigger_poll_secs: float = 1.0, lookback: bool = False,
+                 dump_budget: int = 8):
         if mode not in TRACE_MODES:
             raise ValueError(
                 f"unknown trace_mode {mode!r}; choose from {TRACE_MODES}"
@@ -401,6 +524,16 @@ class Tracer:
         self._pending_reason: str | None = None
         self._denied_reported = False
         self._ring: deque = deque(maxlen=max(int(ring_size), 2))
+        # the other threads' coarse spans at `off`, for `dump_lookback`
+        # (never flushed by count; the deque drops its oldest)
+        self._lookback: deque = deque(maxlen=max(int(ring_size), 2))
+        self._owner = threading.get_ident()
+        self.dumps = 0
+        self.dump_budget = max(int(dump_budget), 0)
+        # a span's id: this process's random prefix and a counter
+        # (`next` of an `itertools.count` is one GIL-atomic call)
+        self._id_prefix = new_id()[:8]
+        self._id_counter = itertools.count(1)
         self._flush_every = max(int(flush_every), 1)
         self._io_lock = threading.Lock()
         self._tls = threading.local()
@@ -419,6 +552,10 @@ class Tracer:
             self._path = os.path.join(telemetry_dir, SPANS_FILENAME)
             self._trigger_path = os.path.join(telemetry_dir, TRIGGER_FILENAME)
             self._traces_dir = os.path.join(telemetry_dir, TRACES_DIRNAME)
+        self.lookback = bool(lookback) and self._path is not None
+
+    def _span_id(self) -> str:
+        return f"{self._id_prefix}{next(self._id_counter) & 0xFFFFFFFF:08x}"
 
     # -- levels --------------------------------------------------------------
     def _level(self) -> int:
@@ -433,13 +570,18 @@ class Tracer:
              parent: tuple[str, str] | None = None, **attrs):
         """Open one span as a context manager. `detail=True` marks a
         fine-grained span recorded only at `full` level (or inside a
-        capture window); coarse spans record from `steps` up. A span that
-        is not recorded still enters the profiler annotation, where a
-        factory is installed."""
+        capture window); coarse spans record from `steps` up. At `off` a
+        coarse span of another thread than the tracer's owner is held for
+        the look-back ring, where one is kept. A span that is not recorded
+        still enters the profiler annotation, where a factory is
+        installed."""
         factory = self.annotation_factory
         annotation = factory(name, attrs) if factory is not None else None
         lvl = self._level()
         if lvl == 0 or (detail and lvl < 2):
+            if (self.lookback and lvl == 0 and not detail
+                    and threading.get_ident() != self._owner):
+                return Span(self, name, cat, parent, attrs, annotation, True)
             if annotation is None:
                 return NULL_SPAN
             return _AnnotationSpan(annotation)
@@ -467,7 +609,7 @@ class Tracer:
             return None
         if parent is None:
             parent = self.current_context()
-        sid = span_id or new_id()
+        sid = span_id or self._span_id()
         self._record(
             name, cat, t_start_wall, dur_s,
             trace_id or (parent[0] if parent else self.trace_id),
@@ -538,7 +680,7 @@ class Tracer:
 
     # -- recording / flushing ------------------------------------------------
     def _record(self, name, cat, t_wall, dur_s, trace_id, span_id,
-                parent_id, attrs) -> None:
+                parent_id, attrs, held: bool = False) -> None:
         thread = threading.current_thread()
         rec = {
             "v": SCHEMA_VERSION,
@@ -559,6 +701,9 @@ class Tracer:
             rec["parent"] = parent_id
         if attrs:
             rec["attrs"] = attrs
+        if held:  # at `off`: the look-back ring's, which overwrites itself
+            self._lookback.append(rec)
+            return
         self._ring.append(rec)  # lock-free fast path (GIL-atomic append)
         self.spans_recorded += 1
         if len(self._ring) >= self._flush_every:
@@ -568,20 +713,54 @@ class Tracer:
         """Drain the ring to spans.jsonl (one O_APPEND write of all
         pending lines — safe to interleave with other processes appending
         to the same file)."""
+        self._write(self._ring)
+
+    def _write(self, *rings) -> int:
         if self._path is None:
-            return
+            return 0
         with self._io_lock:
             lines = []
-            while True:
-                try:
-                    rec = self._ring.popleft()
-                except IndexError:
-                    break
-                lines.append(_dumps(rec))
+            for ring in rings:
+                while True:
+                    try:
+                        rec = ring.popleft()
+                    except IndexError:
+                        break
+                    lines.append(_dumps(rec))
             if lines:
                 with open(self._path, "a", encoding="utf-8") as f:
                     f.write("\n".join(lines) + "\n")
                 self.spans_written += len(lines)
+        return len(lines)
+
+    def can_dump(self) -> bool:
+        """Whether `dump_lookback()` would write: a ring is kept and the
+        run's budget of dumps is not spent."""
+        return self.lookback and self.dumps < self.dump_budget
+
+    def dump_lookback(self, step: dict | None = None) -> int | None:
+        """Write the look-back ring (and what the mode has pending, so the
+        file stays whole) to spans.jsonl: ordinary span lines, which
+        `tools/trace_report.py` renders like any other. `step`
+        (`StepPhaseTimer.last_step`: the stalled step's window and the
+        intervals of its phases, on `perf_counter`) is written with them
+        at `off`, where the owner's spans are annotations alone: a `step`
+        span with `attrs` and one child for each interval. Returns the
+        spans written, or None where the run's `dump_budget` is spent (the
+        caller's event then stands alone) or no ring is kept."""
+        if not self.can_dump():
+            return None
+        self.dumps += 1
+        if step is not None and self._level() == 0:
+            to_wall = time.time() - time.perf_counter()
+            t0, t1 = step["window"]
+            parent = self._span_id()
+            self._record("step", "step", to_wall + t0, t1 - t0, self.trace_id,
+                         parent, self.root_parent, step["attrs"], True)
+            for name, a, b in step["spans"]:
+                self._record(name, "span", to_wall + a, b - a, self.trace_id,
+                             self._span_id(), parent, {}, True)
+        return self._write(self._lookback, self._ring)
 
     # -- capture windows -----------------------------------------------------
     def request_capture(self, reason: str) -> None:

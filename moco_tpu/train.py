@@ -429,6 +429,10 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
     # telemetry on each also enters the profiler's trace, at every trace_mode
     tracer = telemetry.tracer if telemetry is not None else null_tracer()
     setup_span = telemetry.setup_span if telemetry is not None else no_span
+    # a phase of the main thread's step: entered next to the tracer's span of
+    # that phase (`scopes.STEP_PHASES`), it books the same interval into the
+    # step record's field
+    phase = telemetry.timer.phase if telemetry is not None else no_span
 
     if (config.input_cache_mb and not config.input_prestage
             and dataset is not None):
@@ -717,6 +721,7 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
     preempted = False
     resized = False
     first_batch_pending = True  # the run's first loader wait is set-up's
+    prev_loss = None  # the loss of the step before, for the `starved` query
     _resilience = contextlib.ExitStack()
     preempt = _resilience.enter_context(PreemptionHandler())
     # elastic resize (ISSUE 11): SIGUSR2 or a <telemetry_dir>/resize.request
@@ -791,30 +796,32 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                     # one `step` span per iteration (the profiler's
                     # StepTraceAnnotation); its children are where the main
                     # thread's time goes, what is under none of them is the
-                    # loop's own (`loop_unspanned_ms_per_step`)
+                    # loop's own (the record's `loop_s`)
                     with tracer.span(scopes.STEP_SPAN, cat="step",
                                      step=global_step + 1):
-                        with tracer.span("data_wait", detail=True):
+                        with tracer.span("data_wait", detail=True), \
+                                phase("data_s"):
                             if first_batch_pending:
                                 first_batch_pending = False
                                 with setup_span("first_batch"):
                                     batch = next(batches, None)
                             else:
                                 batch = next(batches, None)
-                            # the span's end and the record's data_s: one read
-                            if telemetry is not None:
-                                telemetry.timer.mark_data()
                         if batch is None:  # the loader ended early
                             break
                         imgs, _labels, extents = batch
                         data_time.update(time.perf_counter() - end)
                         profiler.maybe_toggle(global_step)
-                        with tracer.span("dispatch", detail=True):
+                        if telemetry is not None:
+                            # is the step before done already? Then nothing
+                            # is queued and this one goes to an idle device
+                            # (the record's `starved`; a query, no wait)
+                            telemetry.timer.probe_idle(prev_loss)
+                        with tracer.span("dispatch", detail=True), \
+                                phase("host_s"):
                             state, metrics = fused_step(
                                 state, imgs, extents, global_step)
-                            # the span's end and the record's host_s: one read
-                            if telemetry is not None:
-                                telemetry.timer.mark_dispatch()
+                        prev_loss = metrics["loss"]
                         global_step += 1
                         # comm-phase probes (ISSUE 6): device scalars marking
                         # grads-ready / grads-reduced, popped so meters and the
@@ -839,7 +846,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                                 and telemetry.timer.fence_due(global_step)):
                             # stride-gated device fence: off-stride steps stay
                             # fully async (the overhead contract)
-                            with tracer.span("fence", detail=True):
+                            with tracer.span("fence", detail=True), \
+                                    phase("fence_s"):
                                 telemetry.timer.maybe_fence(
                                     global_step, metrics["loss"],
                                     comm_pre=gs_pre, comm_post=gs_post,
@@ -851,7 +859,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                         if sentinel is not None or collapse is not None:
                             # both read a device value of the step BEFORE
                             # (one-step lag): the wait for that step is here
-                            with tracer.span("sentinel", detail=True):
+                            with tracer.span("sentinel", detail=True), \
+                                    phase("wait_s"):
                                 if sentinel is not None:
                                     sentinel.observe(global_step,
                                                      metrics["loss"],
@@ -930,7 +939,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                         step_loss = None  # host-synced loss, when printing pulls it
                         if i % config.print_freq == 0:
                             # pull metrics (host sync) only when printing
-                            with tracer.span("loss_readback", detail=True):
+                            with tracer.span("loss_readback", detail=True), \
+                                    phase("readback_s"):
                                 last_metrics = {k: float(v)
                                                 for k, v in metrics.items()}
                             step_loss = last_metrics["loss"]
@@ -977,7 +987,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                                 pull["_neg_sim"] = neg_sim
                                 pull["_pos_sim"] = metrics["pos_sim"]
                                 pull["_acc1"] = metrics["acc1"]
-                            with tracer.span("loss_readback", detail=True):
+                            with tracer.span("loss_readback", detail=True), \
+                                    phase("readback_s"):
                                 host = jax.device_get(pull)
                             health_rec = {
                                 k[2:]: round(float(v), 6)
@@ -995,7 +1006,8 @@ def _train_once_impl(config: PretrainConfig, mesh, max_steps: int | None = None,
                                     float(host["_acc1"]), 4)
                         if telemetry is not None:
                             phases = telemetry.timer.finish_step()
-                            with tracer.span("telemetry", detail=True):
+                            with tracer.span("telemetry", detail=True), \
+                                    phase("telemetry_s"):
                                 flushed = telemetry.on_step(
                                     global_step, phases, throughput,
                                     loss=step_loss, health=health_rec)

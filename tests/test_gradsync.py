@@ -464,20 +464,20 @@ def test_timer_comm_phase():
 
     timer = StepPhaseTimer(stride=2)
     timer.epoch_start()
-    timer.mark_data()
-    timer.mark_dispatch()
+    with timer.phase("host_s"):
+        pass
     # off-stride: no fence, no comm sample
     assert timer.maybe_fence(1, 1.0, comm_pre=0.5, comm_post=0.7) is None
     assert "comm_s" not in timer.finish_step()
-    timer.mark_data()
-    timer.mark_dispatch()
+    with timer.phase("host_s"):
+        pass
     assert timer.maybe_fence(2, 1.0, comm_pre=0.5, comm_post=0.7) is not None
     phases = timer.finish_step()
     assert "comm_s" in phases and phases["comm_s"] >= 0.0
     assert "device_s" in phases
     # probes absent (a non-gradsync caller): fence still works, no comm key
-    timer.mark_data()
-    timer.mark_dispatch()
+    with timer.phase("host_s"):
+        pass
     assert timer.maybe_fence(4, 1.0) is not None
     assert "comm_s" not in timer.finish_step()
 

@@ -456,8 +456,10 @@ def test_phase_timer_monotonic_and_stride_fencing():
     records = []
     timer.epoch_start()
     for step in range(1, 10):
-        timer.mark_data()
-        timer.mark_dispatch()
+        with timer.phase("data_s"):
+            pass
+        with timer.phase("host_s"):
+            pass
         fenced = timer.maybe_fence(step, sync)
         phases = timer.finish_step()
         records.append((step, fenced, phases))
@@ -476,8 +478,10 @@ def test_phase_timer_monotonic_and_stride_fencing():
 def test_phase_timer_stride_zero_never_fences():
     timer = StepPhaseTimer(stride=0)
     timer.epoch_start()
-    timer.mark_data()
-    timer.mark_dispatch()
+    with timer.phase("data_s"):
+        pass
+    with timer.phase("host_s"):
+        pass
     # sync object deliberately un-blockable: stride 0 must never touch it
     assert timer.maybe_fence(1, object()) is None
     assert timer.fences == 0

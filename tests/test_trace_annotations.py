@@ -116,7 +116,7 @@ def test_without_a_factory_off_is_still_the_null_span_singleton(tmp_path):
 
 
 def test_self_time_accounting_is_gone():
-    """`note_telemetry` books the same window from `on_step`'s own clock."""
+    """The driver's `telemetry` phase books `on_step`'s window."""
     for name in ("consume_" + "self_time", "_note_self", "_self_lock", "_self_s"):
         assert not hasattr(Tracer(None), name) and not hasattr(null_tracer(), name)
 
@@ -147,9 +147,13 @@ def test_run_telemetry_installs_the_profilers_annotations(tmp_path, mesh8):
         assert isinstance(step, jax.profiler.StepTraceAnnotation)
         other = factory("dispatch", {})
         assert type(other) is jax.profiler.TraceAnnotation
-        with tel.tracer.span("dispatch", detail=True) as sp:   # trace_mode off: annotation only
+        # trace_mode off: the main thread's spans are annotations only, the
+        # coarse `step` span too (the look-back ring is the other threads')
+        with tel.tracer.span("dispatch", detail=True) as sp:
             assert sp is not NULL_SPAN and sp.context() is None
-        assert tel.tracer.spans_recorded == 0
+        with tel.tracer.span(scopes.STEP_SPAN, cat="step", step=1) as sp:
+            assert sp is not NULL_SPAN and sp.context() is None
+        assert tel.tracer.spans_recorded == 0 and len(tel.tracer._lookback) == 0
     finally:
         tel.close()
 
@@ -159,9 +163,9 @@ def test_fence_due_says_when_maybe_fence_would_block():
 
     timer = StepPhaseTimer(stride=4)
     timer.epoch_start()
-    assert not timer.fence_due(4)              # no dispatch mark yet
-    timer.mark_data()
-    timer.mark_dispatch()
+    assert not timer.fence_due(4)              # no dispatch yet
+    with timer.phase("host_s"):          # its end is the dispatch's return
+        pass
     assert timer.fence_due(4) and not timer.fence_due(5)
     assert timer.maybe_fence(5, 1.0) is None and timer.maybe_fence(4, 1.0) is not None
     assert not StepPhaseTimer(stride=0).fence_due(4)

@@ -211,7 +211,49 @@ def summarize(records: list[dict], skipped: int = 0) -> dict:
             "data": round(sum(data_s) / total, 4) if total else 0.0,
             "host": round(sum(host_s) / total, 4) if total else 0.0,
         }
+        # the main thread's other phases (ISSUE 35), where the records
+        # carry them: the wait for the step before, the two drains, the
+        # telemetry's own time and the loop's
+        for name in ("wait", "fence", "readback", "telemetry", "loop"):
+            seconds = sum(r.get(name + "_s", 0.0) for r in steps)
+            if seconds and total:
+                summary["phase_share"][name] = round(seconds / total, 4)
         summary["steps_span"] = [steps[0].get("step"), steps[-1].get("step")]
+        # drains (ISSUE 35): steps dispatched to an idle device (`starved`)
+        # and the share of the time their dispatch (`host_s`) took: what the
+        # main thread needed to hand over the next step, no bound on the
+        # device's idle share
+        starved = [r for r in steps if r.get("starved")]
+        if starved and total:
+            summary["drains"] = {
+                "steps": len(starved),
+                "share": round(len(starved) / len(steps), 4),
+                "dispatch_share": round(
+                    sum(r.get("host_s", 0.0) for r in starved) / total, 5),
+            }
+        # the interpreter's collections that ended inside the steps
+        gc_n = sum(r.get("gc_n", 0) for r in steps)
+        if gc_n:
+            gc_s = sum(r.get("gc_s", 0.0) for r in steps)
+            summary["gc"] = {
+                "collections": gc_n,
+                "full": sum(r.get("gc2_n", 0) for r in steps),
+                "ms_each": round(1e3 * gc_s / gc_n, 3),
+                "ms_per_step": round(1e3 * gc_s / len(steps), 3),
+                "worst_step_ms": round(
+                    1e3 * max(r.get("gc_s", 0.0) for r in steps), 3),
+            }
+    # stalls (ISSUE 35): the `stall` events, each with the phase that held
+    # most of its excess over the rolling median
+    stalls = [e for e in events if e.get("event") == "stall"]
+    if stalls:
+        summary["stalls"] = {
+            "count": len(stalls),
+            "total_ms": round(1e3 * sum(e.get("excess_s", 0.0) for e in stalls), 1),
+            "each": [{k: e.get(k) for k in
+                      ("step", "phase", "excess_s", "gc2_n", "starved",
+                       "queue_depth", "dump")} for e in stalls[:16]],
+        }
     if device_s:
         summary["device_time_ms"] = {
             "samples": len(device_s),
@@ -762,9 +804,41 @@ def render(summary: dict) -> str:
             f"· p99 {pct['p99']:.1f} ms"
         )
         share = summary.get("phase_share", {})
+        more = "".join(
+            f" · {name} {100 * share[name]:.1f}%"
+            for name in ("wait", "fence", "readback", "telemetry", "loop")
+            if name in share)
         lines.append(
             f"  phase share: data {100 * share.get('data', 0):.1f}% · "
-            f"host {100 * share.get('host', 0):.1f}% (rest: async device/meters)"
+            f"host {100 * share.get('host', 0):.1f}%"
+            + (more if more else " (rest: async device/meters)")
+        )
+        drains = summary.get("drains")
+        if drains:
+            lines.append(
+                f"  drains: {drains['steps']} steps dispatched to an idle "
+                f"device ({100 * drains['share']:.1f}% of steps) · their "
+                f"dispatch took {100 * drains['dispatch_share']:.2f}% of the "
+                f"time"
+            )
+        collected = summary.get("gc")
+        if collected:
+            lines.append(
+                f"  gc: {collected['collections']} collections "
+                f"({collected['full']} full) · {collected['ms_each']:.3f} ms "
+                f"each · {collected['ms_per_step']:.3f} ms a step · worst "
+                f"step {collected['worst_step_ms']:.1f} ms"
+            )
+    stalls = summary.get("stalls")
+    if stalls:
+        each = ", ".join(
+            f"step {e['step']} +{1e3 * (e['excess_s'] or 0.0):.0f} ms in "
+            f"{e['phase']} (gc2 {e['gc2_n']}, "
+            f"{'starved' if e['starved'] else 'queued'}, queue depth "
+            f"{e['queue_depth']})" for e in stalls["each"])
+        lines.append(
+            f"stalls: {stalls['count']} · {stalls['total_ms']:.0f} ms over "
+            f"the rolling median · {each}"
         )
     dev = summary.get("device_time_ms")
     if dev:
@@ -1281,7 +1355,8 @@ def render_record(rec: dict) -> str | None:
         if "step_s" in rec:
             parts.append(f"{1e3 * rec['step_s']:8.1f} ms")
         share = []
-        for phase in ("data_s", "host_s", "telemetry_s"):
+        for phase in ("data_s", "host_s", "telemetry_s", "wait_s",
+                      "fence_s", "readback_s", "loop_s"):
             if phase in rec and rec.get("step_s"):
                 share.append(
                     f"{phase[:-2]} {100 * rec[phase] / rec['step_s']:.0f}%"
