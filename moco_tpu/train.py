@@ -302,8 +302,13 @@ def _train_once(config: PretrainConfig, mesh, max_steps: int | None = None,
     summary close wins when both run."""
     open_telemetry: list = []
     try:
-        return _train_once_impl(config, mesh, max_steps, dataset,
-                                data_advance, poison_pos, open_telemetry)
+        # under its mesh, everything the pass makes (the initial state, and
+        # whatever a wrapper of the step makes inside its first call) carries
+        # the mesh in its type, as the step's outputs do: jit's tracing cache
+        # then finds step 1's program again from step 2 on
+        with jax.set_mesh(mesh):
+            return _train_once_impl(config, mesh, max_steps, dataset,
+                                    data_advance, poison_pos, open_telemetry)
     finally:
         for tel in open_telemetry:
             tel.close()

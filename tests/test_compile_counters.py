@@ -192,7 +192,9 @@ def test_a_second_train_in_the_process_counts_from_its_own_zero(tmp_path):
         assert end["compile"]["n"] >= ns[-1]
         blocks.append(steps[-1]["compile"])
     first, second = blocks
-    assert first["fused_step_n"] == 2           # uncommitted, then committed state: two programs
+    # the pass runs under its mesh, so the initial state is typed as the step's
+    # outputs are: step 2 finds step 1's program
+    assert first["fused_step_n"] == 1
     # what is left beside them since ISSUE 26: the two programs of the initial
     # state (`test_the_initial_state_is_two_programs`) and the seed's key
     assert 4 <= first["n"] < 12, first
@@ -207,3 +209,59 @@ def test_a_second_train_in_the_process_counts_from_its_own_zero(tmp_path):
     # nothing compiled after step 2 in either run: no `compile` event
     for name in ("a", "b"):
         assert not [r for r in step_records(tmp_path / name) if r.get("event") == "compile"]
+
+
+def swapping_builder(real_builder):
+    """A wrapper of the step as the benchmark's `StepHook` is: in its first call
+    it puts in parameters and a queue made by `jax.jit`s that name no mesh."""
+    calls = {"n": 0}
+
+    def build(step_fn, two_crops_fn, data_key):
+        fused = real_builder(step_fn, two_crops_fn, data_key)
+
+        def call(state, imgs, extents, step):
+            if calls["n"] == 0:
+                fresh = jax.jit(lambda t: jax.tree.map(lambda x: x * 0.5 + 0.01, t))
+                state = state.replace(
+                    params_q=fresh(state.params_q),
+                    params_k=jax.jit(lambda t: jax.tree.map(jnp.copy, t))(state.params_k),
+                    queue=jax.jit(jnp.copy)(state.queue))
+            calls["n"] += 1
+            return fused(state, imgs, extents, step)
+        return call
+    return build
+
+
+@pytest.mark.parametrize("case, loads", [("swapped_in_first_call", 1), ("v3", 1), ("fsdp_2", 1),
+                                         ("zero_2", 2)])
+def test_the_step_program_is_loaded_once(tmp_path, monkeypatch, case, loads):
+    """ISSUE 36: under the pass's mesh the first call of the fused step sees the
+    state type every later call sees, so jit traces, lowers and loads it once:
+    with leaves a wrapper makes inside the first call, for the v3 step and with
+    the state placed on two devices by fsdp. ZeRO-1 on two devices loads it
+    twice: the step returns `params_q` sharded over `data` like the optimizer
+    state it went in beside, where they went in replicated, so step 2 brings
+    another placement."""
+    from moco_tpu import train_step
+    from moco_tpu.config import get_preset
+    from moco_tpu.parallel.mesh import create_mesh
+    from moco_tpu.train import train
+
+    config = tiny(tmp_path, case).replace(steps_per_epoch=3)
+    n_devices = 2 if case.endswith("_2") else 1
+    if case == "swapped_in_first_call":
+        monkeypatch.setattr(train_step, "build_fused_step",
+                            swapping_builder(train_step.build_fused_step))
+    elif case == "v3":
+        config = config.replace(variant="v3")
+    elif case == "fsdp_2":
+        config = get_preset("imagenet-moco-v3-vits").replace(
+            arch="vit_tiny", compute_dtype="float32", image_size=32, batch_size=16, embed_dim=16,
+            dataset="synthetic", warmup_epochs=0, epochs=1, steps_per_epoch=3, sharding="fsdp",
+            knn_monitor=False, ckpt_dir="", print_freq=100, telemetry_dir=config.telemetry_dir)
+    else:
+        config = config.replace(zero_sharding=True)
+    train(config, create_mesh(devices=jax.devices()[:n_devices]))
+    steps = [r for r in step_records(tmp_path / case) if r.get("kind") == "step"]
+    assert [r["step"] for r in steps] == [1, 2, 3]
+    assert [r["compile"]["fused_step_n"] for r in steps] == [1, loads, loads]
