@@ -455,8 +455,13 @@ def norm_rotary(y: jax.Array, scale: jax.Array | None, *, dtype, theta: float, e
 #
 # At 8 192 tokens a row of float32 scores of one head is 32 KB and a q tile's is
 # 8 MB: the keys come by chunks of `KEY_CHUNK` on the innermost grid axis, and a
-# program keeps the running maximum, the running sum and the unnormalised mix of
-# its `Q_TILE` rows in VMEM between them (online softmax). WHICH pairs are live
+# forward program keeps the running maximum, the running sum and the unnormalised
+# mix of its `FWD_Q_TILE` rows in VMEM between them (online softmax). The two row
+# statistics are held as `[rows, 128]` with a row's value on every lane, the
+# layout of the scores and of a head's mix: as `[rows, 1]` columns every use
+# relays them across the lanes, and the kernel takes 15.9 ms a call for 9.0 at
+# the long-document cell's shapes on a TPU v5e (37 % of the MXU's peak on the
+# pairs it computes for 66 %; the same bits out). WHICH pairs are live
 # is an operand: `live`, int8 `[B or 1, L, L]`, nonzero where query `t` sees key
 # `s`, zero above the diagonal (the caller's: a selection that is per batch row,
 # or a mask of positions that every row shares). The kernels know of the
@@ -478,10 +483,20 @@ def norm_rotary(y: jax.Array, scale: jax.Array | None, *, dtype, theta: float, e
 # one backward kernel, where a plain `jax.checkpoint` runs the forward kernel a
 # second time to have the two again. The whole-row pair above names nothing.
 
+# The forward's q tile is twice the backward's (`Q_TILE`): a program of 512 rows
+# takes 8.14 ms a call where one of 256 takes 9.00 (the long-document cell's
+# shapes on a TPU v5e), and computes the same pairs, its tile on the diagonal
+# being one key chunk. Its three float32 scratches of 2 MB and its q and o
+# blocks of 1 MB, twice for the pipeline, beside a head's `[512, 512]` float32
+# scores, are 19.3 MB at 8 192 tokens by the TPU compiler, over the 16 MB it
+# allows by default.
+FWD_Q_TILE = 512
+_TILED_FWD_VMEM = 32 * 2 ** 20
 
-def _tiling(length: int) -> tuple[int, int]:
+
+def _tiling(length: int, q_tile: int = Q_TILE) -> tuple[int, int]:
     """Rows of a q tile and keys of a chunk for a view of `length` tokens."""
-    return min(Q_TILE, length), min(KEY_CHUNK, length)
+    return min(q_tile, length), min(KEY_CHUNK, length)
 
 
 def _last_chunk(t, tq: int, tk: int):
@@ -500,14 +515,21 @@ def _lane_row(column):
     return jnp.broadcast_to(column, (n, n)).T[:1, :]
 
 
+def _lanes(rows, width: int):
+    """Row statistics held on 128 lanes `[r, 128]` as `[r, width]`, `width` a multiple of 128."""
+    return rows if rows.shape[1] == width else jnp.tile(rows, (1, width // rows.shape[1]))
+
+
 def _tiled_fwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, *rest, dim):
     """One batch row, one key/value head, `n` query heads of its group side by
-    side on the lanes, one q tile, one key chunk: q/o refs `[Q_TILE, n * D]`,
-    k/v refs `[KEY_CHUNK, D]`, live `[Q_TILE, KEY_CHUNK]`, lse `[n, 1, Q_TILE]`
-    (a differentiated call's), then the scratch: the running maximum and sum
-    `[n, Q_TILE, 1]` and the mix `[Q_TILE, n * D]`, float32."""
+    side on the lanes, one q tile, one key chunk: q/o refs `[FWD_Q_TILE, n * D]`,
+    k/v refs `[KEY_CHUNK, D]`, live `[FWD_Q_TILE, KEY_CHUNK]`, lse
+    `[n, 1, FWD_Q_TILE]` (a differentiated call's), then the scratch: the running
+    maximum and sum `[n, FWD_Q_TILE, 128]`, a row's value on every lane, and the
+    mix `[FWD_Q_TILE, n * D]`, float32."""
     *lse_ref, m_scr, l_scr, acc_scr = rest
     t, c = pl.program_id(3), pl.program_id(4)
+    tk = live_ref.shape[1]
     last = _last_chunk(t, *live_ref.shape)
     scale = 1.0 / math.sqrt(dim)
     heads = [slice(j * dim, (j + 1) * dim) for j in range(q_ref.shape[1] // dim)]
@@ -526,19 +548,20 @@ def _tiled_fwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, *rest, dim):
             s = _dot(q_ref[:, head], k, _NT) * scale + bias
             m_old = m_scr[j]
             m = jnp.maximum(m_old, jnp.max(s, -1, keepdims=True))
-            p = jnp.exp(s - m)
+            p = jnp.exp(s - _lanes(m, tk))
             alpha = jnp.exp(m_old - m)
             l_scr[j] = alpha * l_scr[j] + jnp.sum(p, -1, keepdims=True)
-            acc_scr[:, head] = alpha * acc_scr[:, head] + _dot(p.astype(v.dtype), v)
+            acc_scr[:, head] = _lanes(alpha, dim) * acc_scr[:, head] + _dot(p.astype(v.dtype), v)
             m_scr[j] = m
 
     @pl.when(c == last)
     def _():
         for j, head in enumerate(heads):
             total = l_scr[j]
-            o_ref[:, head] = (acc_scr[:, head] * (1.0 / total)).astype(o_ref.dtype)
+            o_ref[:, head] = (acc_scr[:, head] * _lanes(1.0 / total, dim)).astype(o_ref.dtype)
             if lse_ref:
-                lse_ref[0][j] = _lane_row(m_scr[j] + jnp.log(total))
+                # every lane holds the row's value: a transpose makes the lane row
+                lse_ref[0][j] = (m_scr[j] + jnp.log(total)).T[:1, :]
 
 
 def _tiled_bwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, do_ref, lse_ref,
@@ -591,14 +614,14 @@ def _tiled_bwd_kernel(q_ref, k_ref, v_ref, live_ref, o_ref, do_ref, lse_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _tiled_grid_and_specs(q, live, heads, kv_heads):
+def _tiled_grid_and_specs(q, live, heads, kv_heads, q_tile):
     """The grid `(batch, kv_head, programs a group, q tiles, key chunks)` and its
     block specs. A chunk above the diagonal repeats the block before it."""
     b, length, _ = q.shape
     dim = q.shape[2] // heads
     n = math.gcd(heads // kv_heads, HEADS_PER_PROGRAM)
     per = heads // kv_heads // n
-    tq, tk = _tiling(length)
+    tq, tk = _tiling(length, q_tile)
     shared = live.shape[0] == 1       # one mask for every batch row
 
     def chunk(t, c):
@@ -617,7 +640,7 @@ def _tiled_grid_and_specs(q, live, heads, kv_heads):
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "interpret", "with_lse"))
 def _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse):
     grid, (n, tq), q_spec, kv_spec, live_spec, row_spec, _ = _tiled_grid_and_specs(
-        q, live, heads, kv_heads)
+        q, live, heads, kv_heads, FWD_Q_TILE)
     b, length, _ = q.shape
     dim = q.shape[2] // heads
     n_out = 2 if with_lse else 1
@@ -628,10 +651,11 @@ def _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse):
         out_specs=[q_spec, row_spec][:n_out],
         out_shape=_out_shapes((q, k, v, live), (q.shape, q.dtype),
                               ((b, heads, 1, length), jnp.float32))[:n_out],
-        scratch_shapes=[pltpu.VMEM((n, tq, 1), jnp.float32)] * 2
+        scratch_shapes=[pltpu.VMEM((n, tq, TILE), jnp.float32)] * 2
         + [pltpu.VMEM((tq, n * dim), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_TILED_FWD_VMEM),
         interpret=interpret,
         name="masked_attention_fwd",
     )(q, k, v, live)
@@ -640,7 +664,7 @@ def _tiled_forward(q, k, v, live, heads, kv_heads, interpret, with_lse):
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "interpret"))
 def _tiled_backward(q, k, v, live, o, lse, do, heads, kv_heads, interpret):
     grid, (n, tq), q_spec, kv_spec, live_spec, row_spec, whole_spec = _tiled_grid_and_specs(
-        q, live, heads, kv_heads)
+        q, live, heads, kv_heads, Q_TILE)
     length, dim = q.shape[1], q.shape[2] // heads
     return pl.pallas_call(
         functools.partial(_tiled_bwd_kernel, dim=dim),

@@ -8,7 +8,8 @@ attention_plan`), and exports it with `platforms=["tpu"]`: the program the
 chip would be handed, without the chip. A tracing or typing break of a step
 program fails in tier-1, not in a chip call. `kernel_census` counts its
 Mosaic calls by kernel name; `census_for_tpu` is the two for a configuration
-named as the tests' cases name it.
+named as the tests' cases name it; `kernel_bodies` says which kernel bodies a
+program holds.
 """
 
 import json
@@ -114,6 +115,29 @@ def kernel_census(module_text: str) -> Counter:
         return total
 
     return reached("main")
+
+
+def kernel_bodies(module_text: str) -> dict[str, set[str]]:
+    """The sha256 of every Mosaic call's kernel body, by kernel name: the body is
+    MLIR bytecode (base64 in the call's `backend_config`), printed here without
+    its debug locations, which name the call site's file and line."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    bodies: dict[str, set[str]] = {}
+    for attrs in re.findall(r"@tpu_custom_call\([^)]*\) \{([^\n]*)", module_text):
+        config = re.search(r'backend_config = "((?:[^"\\]|\\.)*)"', attrs).group(1)
+        config = json.loads(re.sub(r"\\([0-9A-Fa-f]{2})", lambda m: chr(int(m.group(1), 16)), config))
+        with mlir.make_ir_context() as context:
+            context.allow_unregistered_dialects = True
+            body = ir.Module.parse(base64.b64decode(config["custom_call_config"]["body"]))
+            text = body.operation.get_asm(enable_debug_info=False)
+        name = re.search(r'kernel_name = "([^"]+)"', attrs).group(1)
+        bodies.setdefault(name, set()).add(hashlib.sha256(text.encode()).hexdigest())
+    return bodies
 
 
 def census_for_tpu(name: str, devices: int, mesh8, **over) -> Counter:

@@ -120,20 +120,54 @@ def _mask(kind, b, length, seed):
     causal = np.tril(np.ones((length, length), bool))
     if kind == "causal":
         return jnp.asarray(causal[None].astype(np.int8))             # one for every row
-    live = causal & np.asarray(jax.random.bernoulli(jax.random.key(seed), 0.3, (b, length, length)))
+    live = causal & np.asarray(jax.random.bernoulli(jax.random.key(seed), 0.3,
+                                                    (1 if kind == "shared" else b, length, length)))
     if kind == "dead_tiles":       # the later half never looks at the second quarter
         live[:, length // 2:, length // 4: length // 2] = False
+    if kind == "dead_start":       # the last chunk's rows see nothing in the chunks before it
+        live[:, -pa.KEY_CHUNK:, :-pa.KEY_CHUNK] = False
     at = np.arange(length)
     live[:, at, np.maximum(at - 3, 0)] = True                        # every query sees a key
+    if kind == "late_max":
+        live[:, at, at] = True                                       # the planted key, below
     return jnp.asarray(live.astype(np.int8))
 
 
+def _planted(q, k, heads, kv, dim):
+    """k where each query's own position holds 0.6 times its first head of the
+    group: that head's largest live score (about 6.8 where the others are about
+    N(0, 1)) comes in the last key chunk the row reaches, after every other."""
+    group = heads // kv
+    firsts = q.reshape(*q.shape[:2], heads, dim)[:, :, ::group]         # [b, L, kv, dim]
+    return 0.6 * firsts.reshape(k.shape)
+
+
+def _lse_oracle(q, k, live, heads, kv, dim):
+    """log sum exp(q k^T / sqrt(D)) over each row's live keys: `[b, heads, 1, L]`."""
+    b, length = q.shape[:2]
+    qh = q.reshape(b, length, kv, heads // kv, dim)
+    s = jnp.einsum("btgnd,bsgd->bgnts", qh, k.reshape(b, length, kv, dim),
+                   precision="highest") / np.sqrt(dim)
+    s = jnp.where(live[:, None, None].astype(bool), s, -jnp.inf)
+    return jax.nn.logsumexp(s, -1).reshape(b, heads, 1, length)
+
+
 @pytest.mark.parametrize("length, kind", [(256, "random"), (1024, "random"),
-                                          (1024, "dead_tiles"), (1024, "causal")])
+                                          (1024, "dead_tiles"), (1024, "causal"),
+                                          (512, "random"),          # one key chunk
+                                          (1024, "shared"),         # one random mask for every row
+                                          (1536, "late_max"), (1536, "dead_start")])
 def test_masked_attention_is_the_einsum_under_the_same_mask(length, kind):
+    """The output, its log-sum-exp (what the backward kernel reads) and the
+    gradients. `late_max`: each row's largest score of one head arrives in the
+    last key chunk, so the running statistics are rescaled there; `dead_start`:
+    the last chunk's rows see no key of the chunks before theirs, so their
+    running maximum is `MASKED` until the chunk on the diagonal."""
     b, heads, kv, dim = 2, 32, 4, 128
     q, k, v, g = (jax.random.normal(jax.random.key(i), (b, length, n * dim)) for i, n in
                   enumerate((heads, kv, kv, heads)))
+    if kind == "late_max":
+        k = _planted(q, k, heads, kv, dim)
     live = _mask(kind, b, length, 7)
 
     def kernel(q, k, v):
@@ -150,6 +184,14 @@ def test_masked_attention_is_the_einsum_under_the_same_mask(length, kind):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     for a, o in zip(vjp(g), vjp_oracle(g)):
         np.testing.assert_allclose(a, o, rtol=2e-4, atol=2e-4)
+    lse = pa._tiled_forward(q, k, v, live, heads, kv, True, True)[1]
+    want_lse = _lse_oracle(q, k, live, heads, kv, dim)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-6, atol=2e-5)
+    if kind == "late_max":   # a group's first head finds each row's largest score on its own position
+        firsts = q.reshape(b, length, heads, dim)[:, :, ::heads // kv]
+        s = jnp.einsum("btgd,bsgd->bgts", firsts, k.reshape(b, length, kv, dim))
+        s = jnp.where(live[:, None].astype(bool), s, -jnp.inf)
+        assert (jnp.argmax(s, -1) == jnp.arange(length)).all()
     if kind == "causal":     # the mask of positions as an operand is the mask of positions
         np.testing.assert_allclose(got, oracle(q, k, v, None), rtol=2e-5, atol=2e-5)
 

@@ -615,3 +615,66 @@ def test_a_checkpointed_masked_attention_keeps_its_results_by_name_and_runs_one_
     for other in ("plain", "whole"):
         for a, b_ in zip(grads["kept"], grads[other]):
             np.testing.assert_array_equal(a, b_)
+
+
+# -- the whole-row pair in the token cells' steps ----------------------------------------
+
+WHOLE_ROW = ("_fwd_kernel", "_bwd_kernel", "qk_norm_rotary", "qk_norm_rotary_bwd", "qk_rotary",
+             "qk_rotary_bwd")
+
+
+def _public_entries(arch, batch, length):
+    """A layer's whole-row calls through the module's public entries at an
+    encoder's shapes, exported for the TPU platform: the key encoder's forward
+    (no log-sum-exp) and the query encoder's forward and transpose, q and k from
+    float32 projections through `norm_rotary` (with the encoder's per-head norm
+    where it has one)."""
+    from jax.sharding import PartitionSpec as P
+
+    from moco_tpu.parallel.collectives import device_local
+    from moco_tpu.parallel.mesh import DATA_AXIS, create_mesh
+
+    z = models.token_sizes(arch)
+    heads, kv, dim, norm = z["heads"], z["kv_heads"], z["head_dim"], z.get("qk_norm", True)
+    q, g = (jax.ShapeDtypeStruct((batch, length, heads * dim), d) for d in (jnp.float32, jnp.bfloat16))
+    k = jax.ShapeDtypeStruct((batch, length, kv * dim), jnp.float32)
+    v = jax.ShapeDtypeStruct(k.shape, jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((dim,), jnp.float32)
+
+    def layer(q, k, v, scale_q, scale_k):
+        q, k = (pa.norm_rotary(x, s if norm else None, dtype=v.dtype, theta=z["rope_theta"],
+                               eps=z["eps"], head_dim=dim) for x, s in ((q, scale_q), (k, scale_k)))
+        return pa.block_causal_attention(q, k, v, heads=heads, kv_heads=kv,
+                                         block_length=z["block_length"])
+
+    def both(q, k, v, scale_q, scale_k, g):
+        # the step differentiates each device's own view of the parameters
+        scale_q, scale_k = device_local((scale_q, scale_k), DATA_AXIS)
+        o, vjp = jax.vjp(layer, q, k, v, scale_q, scale_k)
+        return layer(q, k, v, scale_q, scale_k), o, vjp(g)
+
+    # inside a one-device `shard_map`, as the step calls them: the calls' values
+    # vary over the data axis there, which the lowering of a body sees
+    rows, whole = P(DATA_AXIS), P()
+    region = jax.shard_map(both, mesh=create_mesh(1), in_specs=(rows, rows, rows, whole, whole, rows),
+                           out_specs=(rows, rows, (rows, rows, rows, rows, rows)))
+    return jax.export.export(jax.jit(region), platforms=["tpu"])(q, k, v, scale, scale, g).mlir_module()
+
+
+@pytest.mark.parametrize("cell", ["sdar-30b-a3b-ep8", "ouro-2.6b-l6"])
+def test_the_token_cells_steps_hold_the_whole_row_kernels_of_the_public_entries(cell):
+    """The routed and the looped cell's step programs, lowered for the TPU from
+    the CPU, hold the whole-row kernels (`block_causal_attention`, `norm_rotary`)
+    and no other attention kernel, and each body (printed without its call
+    sites' locations) is one that a call of the module's public entries at the
+    same shapes makes: nothing the tiled pair's forward does reaches them."""
+    from moco_tpu.parallel.mesh import create_mesh
+    from step_lowering import cell_config, kernel_bodies, lowered_for_tpu
+
+    config = cell_config(cell, batch_size=8)
+    step = kernel_bodies(lowered_for_tpu(config, create_mesh(1)))
+    alone = kernel_bodies(_public_entries(config.arch, 8, config.seq_len))
+    assert set(alone) <= set(WHOLE_ROW) and not {"masked_attention_fwd", "masked_attention_bwd"} & set(step)
+    assert {name: step[name] for name in step if name in WHOLE_ROW} == alone
+    # two forward bodies, with and without the log-sum-exp; q's and k's blocks of norm_rotary
+    assert len(alone["_fwd_kernel"]) == 2 and len(alone["_bwd_kernel"]) == 1
