@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from moco_tpu import models
 from moco_tpu.models import keye, sdar
@@ -61,18 +62,68 @@ def test_every_query_selects_its_best_causal_keys_and_ties_go_to_the_lower_posit
         assert set(np.flatnonzero(live[b, t])) == set(best)
 
 
+@pytest.mark.parametrize("length", [1024, 2048])
 @pytest.mark.parametrize("topk", [256, 200, 1024])
-def test_the_selection_kernel_is_the_oracle_bit_for_bit(topk):
+def test_the_selection_kernel_is_the_oracle_bit_for_bit(topk, length):
     """`select_top_k` in interpret mode against `lax.top_k`: random scores, scores
     with many equal values (ties inside and across the threshold, zeros of both
-    signs), `topk` a multiple of the block, not one, and the view's length."""
-    length = 1024
+    signs), `topk` a multiple of the block, not one, and the view's length; at
+    2 048 tokens a block reaches under the diagonal by one to four key chunks.
+    The second batch row's last block rates every key alike: its threshold ties
+    past its room, and the tie-break takes each row's lowest positions."""
     raw = jax.random.normal(jax.random.key(1), (2, length, length)) * 3
     tied = (jnp.round(raw) / 2).at[:, :, ::7].set(-0.0).at[:, 700:, 5::11].set(0.0)
+    tied = tied.at[1, -ps.ROWS:].set(0.5)
     for scores in (raw, tied):
         got = np.asarray(ps.select_top_k(scores, topk, interpret=True))
         assert (got == np.asarray(keye.top_k_selection(scores, topk))).all()
         assert (got.sum(-1) == expected_sizes(length, topk)).all()
+    for t in range(length - ps.ROWS, length):
+        assert (np.flatnonzero(got[1, t]) == np.arange(min(topk, t + 1))).all()
+
+
+@pytest.mark.parametrize("test", ["not_under", "over", "equal_before"])
+def test_the_selections_count_reduces_once_a_pass_as_once_a_chunk(test):
+    """`_count` adds a pass's hits into lane partial sums and reduces across the
+    lanes after the pass's last chunk: the same counts as reduced after every
+    chunk, and as numpy's, on a block of `ROWS` queries by 4 key chunks of small
+    keys (many equal), thresholds that differ by row, over the first 1 to 4
+    chunks (a program each, so the chunks counted are traced, as in the kernel)."""
+    rows, chunks, lanes = ps.ROWS, 4, ps.TILE
+    keys = jax.random.randint(jax.random.key(2), (chunks, rows, ps.CHUNK), -8, 8, jnp.int32)
+    at_row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    thr = jnp.broadcast_to(at_row % 16 - 8, (rows, lanes))
+    cand = jnp.broadcast_to(at_row * 13 % (chunks * ps.CHUNK), (rows, lanes))
+    preds = {"not_under": lambda key, at, thr, cand: key >= thr,
+             "over": lambda key, at, thr, cand: key > thr,
+             "equal_before": lambda key, at, thr, cand: (key == thr) & (at < cand)}
+    pred = preds[test]
+
+    def kernel(key_ref, thr_ref, cand_ref, o_ref):
+        under = pl.program_id(0) + 1
+
+        def hit(key, at):
+            return pred(key, at, thr_ref[...], cand_ref[...])
+
+        once = ps._count(key_ref, hit, 0, under)
+        every = ps._count(key_ref, hit, 0, 1)
+        for c in range(1, chunks):
+            every = every + jnp.where(c < under, ps._count(key_ref, hit, c, c + 1), 0)
+        o_ref[0, 0], o_ref[0, 1] = once, every
+
+    whole = pl.BlockSpec((chunks, rows, ps.CHUNK), lambda i: (0, 0, 0))
+    lane_block = pl.BlockSpec((rows, lanes), lambda i: (0, 0))
+    got = np.asarray(pl.pallas_call(
+        kernel, grid=(chunks,), in_specs=[whole, lane_block, lane_block],
+        out_specs=pl.BlockSpec((1, 2, rows, lanes), lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((chunks, 2, rows, lanes), jnp.float32),
+        interpret=True)(keys, thr, cand))
+    at = (np.arange(chunks)[:, None, None] * ps.CHUNK + np.arange(ps.CHUNK)).repeat(rows, 1)
+    hits = np.asarray(pred(keys, at, thr[:, :1][None], cand[:, :1][None]))
+    for under in range(1, chunks + 1):
+        want = hits[:under].sum((0, 2))[:, None]
+        assert (got[under - 1] == want).all()          # both forms, on every lane
+    assert len({int(got[i, 0].sum()) for i in range(chunks)}) > 1
 
 
 def test_the_scores_kernel_is_the_einsum_under_the_diagonal():
