@@ -903,6 +903,21 @@ PRESETS["text-moco-v2-keye"] = PRESETS["text-moco-v2-sdar"].replace(
 )
 
 
+# 9. The same recipe with kanana-2-30b-a3b's published stack as the encoder
+#    (models/kanana.py: latent attention, a leading dense layer, then shared
+#    and routed experts under a sigmoid router): views of 4 096 tokens, 4
+#    documents a chip. The preset is the published stack whole;
+#    `--num-hidden-layers 5 --num-experts 8 --vocab-size 16032` is one of 16
+#    expert-parallel chips' share of the dense layer and four routed ones
+#    (README).
+PRESETS["text-moco-v2-kanana"] = PRESETS["text-moco-v2-sdar"].replace(
+    name="text-moco-v2-kanana",
+    arch="kanana_2_30b_a3b",
+    batch_size=4,
+    seq_len=4096,
+)
+
+
 # 3. Same recipe, ShuffleBN across 8 chips (v3-8) — identical step program by
 # construction (derived, so the two can never silently fork); the mesh size
 # comes from the hardware.

@@ -20,7 +20,8 @@ from moco_tpu.models.heads import V3Predictor, V3Projector
 # encoder it asks here, of the sizes, never of a family's name.
 _TOKEN_FAMILIES = {"sdar": "moco_tpu.models.sdar",    # routed, block-causal GQA
                    "ouro": "moco_tpu.models.ouro",    # dense, weight-shared and looped
-                   "keye": "moco_tpu.models.keye"}    # routed, learned sparse attention
+                   "keye": "moco_tpu.models.keye",    # routed, learned sparse attention
+                   "kanana": "moco_tpu.models.kanana"}  # latent attention, shared + routed experts
 
 
 def _family_module(arch: str) -> str | None:
@@ -86,13 +87,17 @@ def attention_path(arch: str, length: int, batch: int = 0, remat: bool = False,
     keys adds `select`: how many a query, and who scores and selects them. Under
     `remat`, a family whose layers keep named values from the query forward to
     the backward pass (`kept_by_remat`) adds `kept`: the names this program
-    carries and their bytes a layer for `batch` views a device in `dtype`."""
+    carries and their bytes a layer for `batch` views a device in `dtype`. A
+    family whose attention is its own (`attention_block(sizes, length)`: latent
+    attention) gives its own block, with its widths."""
     from moco_tpu.ops.pallas_attention import attention_plan
 
     z = token_sizes(arch)
     topk = z.get("index_topk", 0)
-    plan = attention_plan(length, z["head_dim"], z["block_length"],
-                          qk_norm=z.get("qk_norm", True), masked=bool(topk))
+    own = getattr(_token_family(arch), "attention_block", None)
+    plan = own(z, length) if own else attention_plan(
+        length, z["head_dim"], z["block_length"], qk_norm=z.get("qk_norm", True),
+        masked=bool(topk))
     if topk:
         from moco_tpu.ops.pallas_select import select_plan
 

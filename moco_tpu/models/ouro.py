@@ -45,7 +45,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from moco_tpu.models.sdar import Attention, RMSNorm
+from moco_tpu.models.sdar import Attention, RMSNorm, SwiGLU
 from moco_tpu.telemetry import scopes
 
 # the published sizes by arch (config.json's keys in the comments); the cut to
@@ -91,20 +91,6 @@ def health(counted, tokens: int) -> dict:
             "h_ut_passes": jax.lax.pcast(passes, vma, to="varying") if vma else passes}
 
 
-class MLP(nn.Module):
-    width: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, u):
-        def dense(n, name):
-            return nn.Dense(n, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
-                            name=name)
-
-        y = nn.silu(dense(self.width, "gate")(u)) * dense(self.width, "up")(u)
-        return dense(u.shape[-1], "down")(y)
-
-
 class Layer(nn.Module):
     sizes: Any            # an OURO_SIZES entry as a tuple of items (hashable)
     dtype: Any = jnp.float32
@@ -126,7 +112,7 @@ class Layer(nn.Module):
             x = x + norm("norm2", h)
             h = norm("norm3", x)
         with jax.named_scope(scopes.MLP):
-            h = MLP(z["width"], self.dtype, name="mlp")(h)
+            h = SwiGLU(z["width"], self.dtype, name="mlp")(h)
         with jax.named_scope(scopes.NORM):
             return x + norm("norm4", h)
 

@@ -16,7 +16,8 @@ out; on one chip of an expert-parallel deployment that is this chip's share
 of the layer, without the exchange. No capacity factor: the groups are of
 uneven size (`lax.ragged_dot` over rows sorted by expert) and no assignment
 to a held expert is dropped: a pass works on a static buffer of twice the rows
-that uniform routing sends here (the grouped product runs over all of it, the
+that uniform routing sends here, a row a token at least (`held_rows`; the
+grouped product runs over all of it, the
 unassigned rows as zeros); what a router sends beyond it first meets a pass
 an eighth that size, and whole passes after that.
 
@@ -75,7 +76,7 @@ from jax import lax
 
 from moco_tpu.ops.pallas_attention import (KEPT_LIVE, KEPT_LSE, KEPT_OUT, attention_plan,
                                             block_causal_attention, masked_attention, norm_rotary)
-from moco_tpu.ops.pallas_dispatch import combine, dispatch, dispatch_plan, listing
+from moco_tpu.ops.pallas_dispatch import ROW_TILE, combine, dispatch, dispatch_plan, listing
 from moco_tpu.telemetry import scopes
 
 # the published sizes by arch (config.json's keys in the comments); the
@@ -260,12 +261,20 @@ class Attention(nn.Module):
 
 def held_rows(tokens: int, top_k: int, experts: int, held: int) -> int:
     """Rows of the buffer that the first pass of the held experts works on:
-    twice what uniform routing sends here, or every assignment where the
-    layer is whole. What a skewed router sends beyond it takes further,
-    smaller passes (`Experts`): nothing is dropped."""
+    twice what uniform routing sends here in the whole pass (`2 * tokens *
+    top_k * held / experts`, not rounded per token) and never fewer than one
+    row a token, rounded up to the dispatch kernels' `ROW_TILE`; or every
+    assignment where the layer is whole. The floor: a frequent token type (the
+    mask id is a tenth of a view) sends all of its tokens to the experts it
+    picks, so a skewed batch brings lumps that are shares of the TOKENS; where
+    a token sends under half a row here (6 of 128 experts with 8 held: 0.375)
+    twice uniform leaves room for three lumps of a tenth, one row a token for
+    six. What a skewed router sends beyond it takes further, smaller passes
+    (`Experts`): nothing is dropped."""
     if held == experts:
         return tokens * top_k
-    return tokens * min(top_k, 2 * -(-top_k * held // experts))
+    reserve = max(-(-2 * tokens * top_k * held // experts), tokens)
+    return min(tokens * top_k, -(-reserve // ROW_TILE) * ROW_TILE)
 
 
 def pass_sizes(tokens: int, top_k: int, experts: int, held: int) -> tuple[int, int, int]:
@@ -293,10 +302,15 @@ def dispatch_path(tokens: int, hidden: int, top_k: int, experts: int, held: int,
 
 class Router(nn.Module):
     """`u -> logits` over every expert, float32 at `highest`; `trains` False
-    makes the kernel a constant of the step."""
+    makes the kernel a constant of the step. `correction_bias` adds a leaf of
+    that name, `[experts]` float32, and hands it out beside the logits: the
+    selection bias of the `sigmoid` rule (`Experts`), a constant of the step
+    wherever it lives (its gradient is nought: it moves the choice and never
+    a weight)."""
 
     experts: int
     trains: bool = True
+    correction_bias: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -304,25 +318,65 @@ class Router(nn.Module):
                             (u.shape[-1], self.experts), jnp.float32)
         if not self.trains:
             kernel = lax.stop_gradient(kernel)
-        return jnp.matmul(u.astype(jnp.float32), kernel, precision=lax.Precision.HIGHEST)
+        logits = jnp.matmul(u.astype(jnp.float32), kernel, precision=lax.Precision.HIGHEST)
+        if not self.correction_bias:
+            return logits
+        bias = self.param("correction_bias", nn.initializers.normal(0.01), (self.experts,),
+                          jnp.float32)
+        return logits, lax.stop_gradient(bias)
+
+
+class SwiGLU(nn.Module):
+    """`(silu(u Wg) * (u Wu)) Wd` of width `width`, no bias, out in `dtype`: a
+    dense layer's feed-forward (`models/ouro.py`, `models/kanana.py`), or the
+    shared experts beside the routed ones."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        def dense(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
+                            name=name)
+
+        y = jax.nn.silu(dense(self.width, "gate")(u)) * dense(self.width, "up")(u)
+        return dense(u.shape[-1], "down")(y)
 
 
 class Experts(nn.Module):
-    """The routed expert layer's share: see the module's docstring."""
+    """The routed expert layer's share: see the module's docstring. `rule` is
+    the router's: `softmax` (softmax over every expert, the `top_k` largest,
+    renormalised over those), or `sigmoid` (DeepSeek-V3's `noaux_tc` with one
+    group: `s = sigmoid(logits)`, the `top_k` largest of `s + b` with `b` the
+    router's `correction_bias`, weights `scale * s / sum` over the chosen from
+    `s` alone). `shared` is the width of the shared experts' one SwiGLU, which
+    every token meets unweighted and every share computes whole (0: none)."""
 
     experts: int
     held: int
     top_k: int
     width: int
     dtype: Any = jnp.float32
+    rule: str = "softmax"
+    scale: float = 1.0
+    shared: int = 0
 
     @nn.compact
     def __call__(self, u):
         tokens, hidden = u.shape
         with jax.named_scope(scopes.MOE_ROUTER):
-            logits = Router(self.experts, self.held == self.experts, name="router")(u)
-            weight, expert = lax.top_k(jax.nn.softmax(logits, -1), self.top_k)
-            weight = weight / jnp.sum(weight, -1, keepdims=True)
+            if self.rule == "sigmoid":
+                logits, bias = Router(self.experts, self.held == self.experts, True,
+                                      name="router")(u)
+                score = jax.nn.sigmoid(logits)
+                _, expert = lax.top_k(score + bias, self.top_k)
+                weight = jnp.take_along_axis(score, expert, -1)
+                weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20) * self.scale
+            else:
+                logits = Router(self.experts, self.held == self.experts, name="router")(u)
+                weight, expert = lax.top_k(jax.nn.softmax(logits, -1), self.top_k)
+                weight = weight / jnp.sum(weight, -1, keepdims=True)
 
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2,
                                                 out_axis=-1, batch_axis=0)
@@ -408,24 +462,43 @@ class Experts(nn.Module):
                     assigned > rows,
                     lambda y: lax.scan(overflow, y + spilled(jnp.int32(rows), spill), firsts)[0],
                     lambda y: y, y)
+        if self.shared:
+            with jax.named_scope(scopes.MOE_SHARED):
+                y = y + SwiGLU(self.shared, self.dtype, name="shared")(ub).astype(jnp.float32)
         return y
+
+
+def routed_ffn(z: dict, dtype, held: int, index: int, x: jax.Array, **rule) -> jax.Array:
+    """`x + MoE(N2(x))`: the routed expert layer under its scopes, in the calling
+    layer. `rule` is `Experts`' router rule and shared experts; the layer's
+    `index` does not matter here."""
+    b, length, hidden = x.shape
+    with jax.named_scope(scopes.MOE_ROUTER):
+        u = RMSNorm(z["eps"], name="norm2")(x).reshape(b * length, hidden)
+    y = Experts(z["experts"], held, z["top_k"], z["expert_width"], dtype, name="moe", **rule)(u)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        return x + y.reshape(b, length, hidden).astype(x.dtype)
 
 
 class Layer(nn.Module):
     """`attention`: `None` for `Attention`, or a family's own `(sizes, dtype, h)
     -> Attn(h)`, which builds its modules in this layer and opens its own scopes
     (`models/keye.py`: an indexer and a selection beside `attn`, never inside
-    it); the routed layer is this one."""
+    it). `ffn`: `None` for `routed_ffn`, or a family's own `(sizes, dtype, held,
+    index, x) -> x + F(N2(x))` (`models/kanana.py`: a leading dense layer, then
+    routed layers with shared experts), built in this layer the same way;
+    `index` is the layer's depth."""
 
     sizes: Any            # an SDAR_SIZES entry as a tuple of items (hashable)
     held: int
     dtype: Any = jnp.float32
     attention: Any = None
+    ffn: Any = None
+    index: int = 0
 
     @nn.compact
     def __call__(self, x):
         z = dict(self.sizes)
-        b, length, hidden = x.shape
         with jax.named_scope(scopes.ATTN):
             h = RMSNorm(z["eps"], name="norm1")(x).astype(self.dtype)
         if self.attention is None:
@@ -436,12 +509,7 @@ class Layer(nn.Module):
             a = self.attention(z, self.dtype, h)
         with jax.named_scope(scopes.ATTN):
             x = x + a
-        with jax.named_scope(scopes.MOE_ROUTER):
-            u = RMSNorm(z["eps"], name="norm2")(x).reshape(b * length, hidden)
-        y = Experts(z["experts"], self.held, z["top_k"], z["expert_width"], self.dtype,
-                    name="moe")(u)
-        with jax.named_scope(scopes.MOE_DISPATCH):
-            return x + y.reshape(b, length, hidden).astype(x.dtype)
+        return (self.ffn or routed_ffn)(z, self.dtype, self.held, self.index, x)
 
 
 class SDAREncoder(nn.Module):
@@ -459,6 +527,7 @@ class SDAREncoder(nn.Module):
     remat: bool = False
     dtype: Any = jnp.float32
     attention: Any = None      # `Layer`'s
+    ffn: Any = None            # `Layer`'s
 
     @nn.compact
     def __call__(self, ids, train: bool = True):
@@ -469,7 +538,7 @@ class SDAREncoder(nn.Module):
                 ids.astype(jnp.int32))
         layer_cls = nn.remat(Layer, policy=kept_policy()) if self.remat else Layer
         for i in range(self.layers):
-            x = layer_cls(self.sizes, self.held, self.dtype, self.attention,
+            x = layer_cls(self.sizes, self.held, self.dtype, self.attention, self.ffn, i,
                           name=f"layer_{i}")(x)
         with jax.named_scope(scopes.EMBED_POOL):
             feat = jnp.mean(RMSNorm(z["eps"], name="norm")(x), axis=1)

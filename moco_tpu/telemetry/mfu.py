@@ -216,11 +216,37 @@ def selecting_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0)
     return (layers or z["layers"]) * (seq_len * per_token + per_view)
 
 
+def latent_fwd_flops(arch: str, seq_len: int, layers: int = 0, held: int = 0) -> float:
+    """Forward matmul FLOPs per VIEW for the token encoder with latent attention
+    and shared experts in models/kanana.py: per layer the latent attention's
+    five projections and its scores (`nope + rope` wide) and mix (`v_dim`) at
+    the causal mask's density; the leading dense layers' SwiGLU; every other
+    layer's router over all experts, shared SwiGLU and held experts at the
+    assignments uniform routing sends here. Excludes norms, rotary, softmax
+    and the embedding lookup. `layers` / `held`: 0 is the arch's own number."""
+    from moco_tpu.models.kanana import KANANA_SIZES
+
+    z = KANANA_SIZES[arch]
+    d, h, rank = z["hidden"], z["heads"], z["kv_rank"]
+    qk, v = z["nope"] + z["rope"], z["v_dim"]
+    density = (seq_len + 1) / (2.0 * seq_len)
+    attention = (2.0 * d * h * qk + 2.0 * d * (rank + z["rope"])    # q, kv_a
+                 + 2.0 * rank * h * (z["nope"] + v) + 2.0 * h * v * d   # kv_b, o
+                 + 2.0 * seq_len * density * h * (qk + v))          # scores + mix
+    dense = 3 * 2.0 * d * z["dense_width"]
+    routed = (2.0 * d * z["experts"] + 3 * 2.0 * d * z["shared_width"]
+              + z["top_k"] * (held or z["experts"]) / z["experts"] * 3 * 2.0 * d * z["expert_width"])
+    layers = layers or z["layers"]
+    n_dense = min(z["dense_layers"], layers)
+    return seq_len * (layers * attention + n_dense * dense + (layers - n_dense) * routed)
+
+
 def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
                     embed_dim: int = 128, mlp_head: bool = False, seq_len: int = 512,
                     num_hidden_layers: int = 0, num_experts: int = 0) -> float:
     """Backbone + head forward FLOPs per image (a token encoder: per view of
     `seq_len` tokens) for any supported arch."""
+    from moco_tpu.models.kanana import KANANA_SIZES
     from moco_tpu.models.keye import KEYE_SIZES
     from moco_tpu.models.ouro import OURO_SIZES
     from moco_tpu.models.sdar import SDAR_SIZES
@@ -235,6 +261,8 @@ def model_fwd_flops(arch: str, image_size: int, *, cifar_stem: bool = False,
         body = looped_fwd_flops(arch, seq_len, num_hidden_layers)
     elif arch in KEYE_SIZES:
         body = selecting_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
+    elif arch in KANANA_SIZES:
+        body = latent_fwd_flops(arch, seq_len, num_hidden_layers, num_experts)
     else:
         raise ValueError(f"no analytic FLOPs model for arch {arch!r}")
     return body + head_fwd_flops(arch, embed_dim, mlp_head)

@@ -60,6 +60,13 @@ INDEX = "index"                  # the indexer's projections, LayerNorm, rotary 
 SELECT = "select"                # the top-k, and the selection as the kernels are handed it
 SPARSE_SCOPES = (INDEX, SELECT)
 
+# inside a routed token encoder with latent attention and shared experts
+# (`models/kanana.py`): one scope nested INSIDE `attn`, one sibling of the
+# `moe_*` scopes; a leading dense layer's SwiGLU and its pre-norm go under `mlp`
+MLA_LATENT = "mla_latent"        # the latent's down-projection, its RMSNorm, up-projection; k's rotary
+MOE_SHARED = "moe_shared"        # the shared experts' SwiGLU
+LATENT_SCOPES = (MLA_LATENT, MOE_SHARED)
+
 # -- host spans -----------------------------------------------------------------
 STEP_SPAN = "step"         # one per driver-loop iteration; enters the profiler as
 STEP_ANNOTATION = "train"  # StepTraceAnnotation(STEP_ANNOTATION, step_num=<global step>)

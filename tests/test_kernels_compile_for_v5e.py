@@ -70,3 +70,18 @@ def test_the_index_scores_and_the_selection_compile_at_the_cells_size(one_chip):
              ((B, L, 16), jnp.float32))
     memory = _compile(lambda s: select_top_k(s, 2048), one_chip, ((B, L, L), jnp.float32))
     assert memory.output_size_in_bytes == B * L * L      # one byte a pair
+
+
+def test_the_latent_attention_kernels_compile_at_the_cells_size(one_chip):
+    """q.k 192 wide in two parts, v 128, one rotary key head, 4 096 tokens, 4 views:
+    the forward with its log-sum-exp and the backward, in VMEM's limits."""
+    from moco_tpu.ops.pallas_mla import causal_latent_attention
+
+    def both(qn, qp, kn, kp, v, g):
+        o, vjp = jax.vjp(lambda *a: causal_latent_attention(*a, heads=HEADS), qn, qp, kn, kp, v)
+        return o, vjp(g)
+
+    b, length = 4, 4096
+    wide, rope = ((b, length, HEADS * 128), jnp.bfloat16), ((b, length, HEADS * 64), jnp.bfloat16)
+    memory = _compile(both, one_chip, wide, rope, wide, ((b, length, 64), jnp.bfloat16), wide, wide)
+    assert memory.temp_size_in_bytes < 2 ** 28      # no `[heads, L, L]` tensor beside the operands

@@ -202,10 +202,10 @@ def test_the_package_has_four_files_of_mosaic_kernels():
     for the attention kernels and, since PR 32, the routed layer's row movers;
     since PR 33 the sparse attention, index score and selection kernels' own);
     a `pallas_call` anywhere else in the package is a new layer and says so
-    there first."""
+    there first. The latent attention kernels have a file of their own, a fifth."""
     holders = {name for name, text in _package_sources("") if "pallas_call" in text}
     assert holders == {"ops/pallas_blur.py", "ops/pallas_attention.py", "ops/pallas_dispatch.py",
-                       "ops/pallas_select.py"}
+                       "ops/pallas_select.py", "ops/pallas_mla.py"}
 
 
 def test_no_switch_is_read_from_the_environment_in_models_and_ops():
