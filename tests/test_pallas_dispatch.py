@@ -196,14 +196,14 @@ def test_the_dispatch_rule(case, tokens, hidden, n, backend, plan):
 def test_this_backend_takes_the_xla_body_and_the_layer_follows_the_rule(monkeypatch):
     """No knob: `Experts` asks the rule, and the rule asks the backend and the
     shapes. The `moe` block of the `setup` event is the same answer."""
-    cell = dict(dispatch="xla", rows=32768, spill_rows=4096, passes=3)
+    cell = dict(dispatch="xla", rows=32768, spill_rows=4096, passes=3, tokens=32 * 512)
     assert models.dispatch_path("sdar_30b_a3b", 32, 512, held=16) == cell       # the tests' CPU
     assert models.dispatch_path("ouro_2p6b", 16, 512) is None                   # no router
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert models.dispatch_path("sdar_30b_a3b", 32, 512, held=16) == dict(cell, dispatch="kernels")
     # the whole layer holds every assignment in one pass
     assert models.dispatch_path("sdar_30b_a3b", 32, 512) == dict(
-        dispatch="kernels", rows=32 * 512 * 8, spill_rows=0, passes=0)
+        dispatch="kernels", rows=32 * 512 * 8, spill_rows=0, passes=0, tokens=32 * 512)
     assert models.dispatch_path("sdar_tiny", 8, 16, held=4)["dispatch"] == "xla"
     calls = []
 

@@ -130,6 +130,170 @@ def test_a_skewed_router_takes_more_passes_and_drops_nothing():
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-4 * float(jnp.abs(b).max()))
 
 
+# the router's two rules, each at its family's test sizes
+RULE_ARCH = {"softmax": "sdar_tiny", "sigmoid_shared": "kanana_tiny"}
+
+
+def _rule_layer(rule, held, skewed, tokens=128):
+    """`Experts` under `rule` with `held` of the family's experts, its
+    parameters and `tokens` rows; `skewed`: every token sends its choices to
+    the held experts, so the passes after the first run."""
+    z = models.token_sizes(RULE_ARCH[rule])
+    extra = {} if rule == "softmax" else dict(rule="sigmoid", scale=z["routed_scale"],
+                                              shared=z["shared_width"])
+    module = sdar.Experts(z["experts"], held, z["top_k"], z["expert_width"], **extra)
+    u = jax.random.normal(jax.random.key(5), (tokens, z["hidden"]))
+    p = module.init(jax.random.key(6), u)["params"]
+    if skewed:
+        lean = jnp.sign(u.mean(0))
+        p["router"]["kernel"] = p["router"]["kernel"].at[:, :held].add(50.0 * lean[:, None])
+        u = u + 2.0 * lean
+    return module, p, u, sdar.pass_sizes(tokens, z["top_k"], z["experts"], held)
+
+
+@jax.custom_vjp
+def _nan_from(x, count):
+    """`x` with its rows from `count` on NaN; the cotangent passes unchanged."""
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < count, x, jnp.nan).astype(x.dtype)
+
+
+_nan_from.defvjp(lambda x, count: (_nan_from(x, count), None), lambda _, dx: (dx, None))
+
+
+def _run_clean_and_poisoned(monkeypatch, module, p, u, path):
+    """Twice, clean and then poisoned: the layer's result, its assignments,
+    the gradients of `u` and of the parameters, and the (sum of the group
+    sizes, buffer rows) of every grouped product the forward pass ran.
+    Poisoned, the buffer rows past each pass's last assignment are NaN, and so
+    are the rows outside every group of each product's result and `dx`, as
+    the chip's product leaves them unwritten. The rows move by
+    `ops/pallas_dispatch.py`'s kernels (interpreted, at tiles the test's
+    passes fill) or, `xla`, by the XLA body's gather and scatter-add."""
+    from moco_tpu.ops import pallas_dispatch as pd
+
+    tokens, seen, product, poison = u.shape[0], [], jax.lax.ragged_dot, []
+
+    def dispatch(src, token, count, lst, *, dtype):
+        if path == "kernels":
+            x = pd.dispatch(src, token, count, lst, dtype=dtype, interpret=True)
+        else:
+            x = jnp.where(jnp.arange(token.shape[0])[:, None] < count, src[token], 0).astype(dtype)
+        return _nan_from(x, count) if poison else x
+
+    def combine(y, w, token, count, lst):
+        if path == "kernels":
+            return pd.combine(y, w, token, count, lst, interpret=True)
+        return jnp.zeros((tokens, y.shape[1]), jnp.float32).at[token].add(y.astype(jnp.float32) * w)
+
+    def ragged_dot(lhs, rhs, group_sizes, **kw):
+        jax.debug.callback(lambda s, n=lhs.shape[0]: seen.append((int(s), n)), jnp.sum(group_sizes))
+        if not poison:
+            return product(lhs, rhs, group_sizes, **kw)
+
+        @jax.custom_vjp
+        def leaves_nan(lhs, rhs, sizes):
+            # as the chip's product: nothing written outside every group, here NaN
+            return _nan_from(product(lhs, rhs, sizes, **kw), jnp.sum(sizes))
+
+        def transposed(res, ct):
+            lhs, rhs, sizes = res
+            dlhs, drhs = jax.vjp(lambda a, b: product(a, b, sizes, **kw), lhs, rhs)[1](ct)
+            return _nan_from(dlhs, jnp.sum(sizes)), drhs, None
+
+        leaves_nan.defvjp(lambda *a: (leaves_nan(*a), a), transposed)
+        return leaves_nan(lhs, rhs, group_sizes)
+
+    jitted, runs = (pd._gather, pd._sum_by_token, pd.listing), []
+    with monkeypatch.context() as patch:
+        for name, value in (("ROW_TILE", 16), ("CHUNK", 16), ("LANES", u.shape[1])):
+            patch.setattr(pd, name, value)
+        patch.setattr(sdar, "dispatch_plan", lambda *a, **k: "kernels")
+        patch.setattr(sdar, "dispatch", dispatch)
+        patch.setattr(sdar, "combine", combine)
+        patch.setattr(jax.lax, "ragged_dot", ragged_dot)
+        for f in jitted:
+            f.clear_cache()
+        for poisoned in (False, True):
+            poison[:], seen[:] = [True] * poisoned, []
+            out, stats = jax.jit(lambda p, u: module.apply(
+                {"params": p}, u, mutable=[sdar.MOE_STATS]))(p, u)
+            forward = sorted(seen)
+            grad = jax.jit(jax.grad(lambda p, u: jnp.sum(module.apply({"params": p}, u) ** 2),
+                                    argnums=(0, 1)))(p, u)
+            runs.append((out, int(stats[sdar.MOE_STATS]["held_counts"].sum()), grad, forward))
+    for f in jitted:
+        f.clear_cache()
+    return runs
+
+
+@pytest.mark.parametrize("rule, held, skewed, path", [
+    ("softmax", 4, False, "xla"), ("sigmoid_shared", 4, False, "xla"),
+    ("softmax", 2, True, "xla"), ("sigmoid_shared", 2, True, "xla"),
+    ("softmax", 4, False, "kernels")])
+def test_rows_past_the_last_assignment_reach_nothing(monkeypatch, rule, held, skewed, path):
+    """The grouped products stop at each pass's last assignment: the group
+    sizes handed to `lax.ragged_dot` sum to `clip(assigned - first, 0, n)` in
+    the first pass, in the spill pass and in a whole pass the skewed router
+    half fills, and a whole pass past the last assignment runs no product at
+    all. The buffer rows past it, NaN, and the rows a product leaves outside
+    its groups, NaN, reach neither the layer's result nor any gradient: both
+    equal the clean run's, bit for bit."""
+    module, p, u, (rows, spill, passes) = _rule_layer(rule, held, skewed)
+    (out, assigned, grad, forward), bad = _run_clean_and_poisoned(monkeypatch, module, p, u, path)
+    firsts = [(0, rows), (rows, spill)] + [(rows + spill + i * rows, rows) for i in range(passes)]
+    ran = [(f, n) for f, n in firsts[:1] + firsts[1:] * (assigned > rows) if f == 0 or f < assigned]
+    assert forward == sorted([(int(np.clip(assigned - f, 0, n)), n) for f, n in ran] * 3)
+    if skewed:     # a spill pass, a whole pass with a tail, and a whole pass skipped
+        assert len(ran) >= 3 and 0 < assigned - ran[-1][0] < rows and len(ran) < len(firsts)
+    else:          # the first pass alone, with a tail
+        assert ran == [(0, rows)] and 0 < assigned < rows
+    assert bad[1] == assigned and bad[3] == forward
+    assert np.isfinite(bad[0]).all()
+    np.testing.assert_array_equal(bad[0], out)
+    assert set(grad[0]) >= {"gate", "up", "down"} and np.any(grad[1])
+    for a, b in zip(jax.tree.leaves(bad[2]), jax.tree.leaves(grad)):
+        assert np.isfinite(a).all()
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="the chip's grouped product; the CPU's is a masked dense product")
+def test_the_tpu_grouped_product_leaves_the_rows_outside_its_groups_and_reads_none():
+    """What `Experts.one_pass` rests on since its groups stop at the last
+    assignment: on the chip `lax.ragged_dot`'s rows in the groups, in the
+    product and in `dx`, and the weights' gradient are what the rows in the
+    groups alone give, whatever the rows outside every group of the operand
+    and of the cotangent hold (NaN here). What it leaves in the rows outside
+    the groups is not asked: it writes nothing there (the layer masks them).
+    SDAR's shape: a buffer of 32 768 rows of 2 048, 16 held experts of 768,
+    in bfloat16."""
+    rows, hidden, width, held = 32768, 2048, 768, 16
+    kx, kw, kd = jax.random.split(jax.random.key(0), 3)
+    x0 = jax.random.normal(kx, (rows, hidden), jnp.bfloat16)
+    w = jax.random.normal(kw, (held, hidden, width), jnp.bfloat16)
+    dy0 = jax.random.normal(kd, (rows, width), jnp.bfloat16)
+
+    @jax.jit
+    def run(x, w, sizes, dy):
+        y, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, sizes), x, w)
+        return (y, *vjp(dy))
+
+    skew = np.full(held, 211, np.int32)
+    skew[3] = 12001
+    uneven = np.arange(held, dtype=np.int32) * 127 + 5
+    uneven[6] = 0                                        # an expert nobody chose
+    for sizes in (uneven, skew):
+        count = int(sizes.sum())
+        tail = jnp.arange(rows)[:, None] >= count
+        x, dy = jnp.where(tail, jnp.nan, x0), jnp.where(tail, jnp.nan, dy0)
+        y, dx, dw = (np.asarray(a, np.float32) for a in run(x, w, jnp.asarray(sizes), dy))
+        alone = [np.asarray(a, np.float32)
+                 for a in run(x0[:count], w, jnp.asarray(sizes), dy0[:count])]
+        for got, want in zip((y[:count], dx[:count], dw), alone):
+            assert np.isfinite(got).all()
+            np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2 * np.abs(want).max())
+
+
 def test_build_backbone_gives_the_third_family():
     from moco_tpu.models import build_backbone
 

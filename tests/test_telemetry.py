@@ -324,8 +324,9 @@ def test_the_report_prints_the_attention_path_on_its_setup_line(path, holds):
     assert ("attention" not in lines[0]) if holds is None else lines[0].endswith(holds)
 
 
-MOE_PATHS = {"kernels": {"dispatch": "kernels", "rows": 32768, "spill_rows": 4096, "passes": 3},
-             "xla": {"dispatch": "xla", "rows": 256, "spill_rows": 32, "passes": 1}}
+MOE_PATHS = {"kernels": {"dispatch": "kernels", "rows": 32768, "spill_rows": 4096, "passes": 3,
+                         "tokens": 16384},
+             "xla": {"dispatch": "xla", "rows": 256, "spill_rows": 32, "passes": 1, "tokens": 128}}
 
 
 @pytest.mark.parametrize("path", [None, "kernels", "xla"])
@@ -377,6 +378,32 @@ def test_the_report_prints_the_moe_block_at_the_end_of_its_setup_line(path, hold
     lines = [t for t in report.render(report.summarize(records)).splitlines() if "set-up:" in t]
     assert len(lines) == 1 and lines[0].endswith(holds)
     assert ("expert rows" in lines[0]) == (path is not None)
+
+
+@pytest.mark.parametrize("moe, assign, holds", [
+    # the routed cell's block: 32 768 buffer rows for 16 384 tokens a call
+    ("kernels", 0.97, " · 48.5 % of the first pass's 32768 buffer rows assigned"),
+    ("kernels", 2.5, " · 125.0 % of the first pass's 32768 buffer rows assigned, the rest "
+                     "spills into the passes after it"),
+    # a record from before the `moe` block carried its tokens: no share to print
+    ("without_tokens", 0.97, "fullest over mean load 11.000"),
+    (None, 0.97, "fullest over mean load 11.000"),
+])
+def test_the_report_prints_the_first_passs_fill_on_its_experts_line(moe, assign, holds):
+    """The grouped products stop at the last assignment, so the share of the
+    first pass's buffer the router filled is the share they work on: the
+    step records' `moe_assign_per_token` times the `moe` block's `tokens` over
+    its `rows`."""
+    setup = {"kind": "event", "event": "setup", "spans": {"model_init": 3.5}}
+    if moe:
+        setup["moe"] = {k: v for k, v in MOE_PATHS["kernels"].items()
+                        if k != "tokens" or moe == "kernels"}
+    records = [setup, {"kind": "step", "step": 1, "step_s": 0.5,
+                       "health": {"moe_assign_per_token": assign,
+                                  "moe_load_max_over_mean": 11.0}}]
+    lines = [t for t in report.render(report.summarize(records)).splitlines() if "experts:" in t]
+    assert len(lines) == 1 and lines[0].endswith(holds)
+    assert lines[0].startswith(f"  experts: {assign:.3f} assignment(s) a token")
 
 
 # ---------------------------------------------------------------------------

@@ -1257,11 +1257,19 @@ def render(summary: dict) -> str:
             )
         if "moe_assign_per_token" in last:
             # a routed token encoder (models/sdar.py): what its router sent
-            # to the experts this chip holds
+            # to the experts this chip holds, and the share of the first
+            # pass's buffer that those rows fill: the grouped products stop
+            # at the last assignment, so they work on that share alone
+            moe = summary.get("moe") or {}
+            fill = (last["moe_assign_per_token"] * moe["tokens"] / moe["rows"]
+                    if moe.get("tokens") and moe.get("rows") else None)
             lines.append(
                 f"  experts: {last['moe_assign_per_token']:.3f} assignment(s) "
                 f"a token to held experts · fullest over mean load "
                 f"{last.get('moe_load_max_over_mean', 0):.3f}"
+                + (f" · {100 * fill:.1f} % of the first pass's {moe['rows']} buffer rows "
+                   f"assigned" + (", the rest spills into the passes after it" if fill > 1 else "")
+                   if fill is not None else "")
             )
         if "ut_pass_delta" in last:
             # a looped token encoder (models/ouro.py): how often the shared
